@@ -224,6 +224,19 @@ def _stream_worker(backend: str, chunk_size: int, k: int,
     print(json.dumps(rec))
 
 
+def _parent_holds_device() -> bool:
+    """Whether this process has already opened a non-CPU jax backend.
+
+    An accelerator serves one process at a time, so a child that needs it
+    would then fail or hang.
+    """
+    import jax
+    from jax._src import xla_bridge
+
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() != "cpu")
+
+
 def _run_stream_worker(backend: str, chunk_size: int, k: int,
                        hw_name: str, grid: str = "1m") -> dict:
     import json
@@ -232,6 +245,11 @@ def _run_stream_worker(backend: str, chunk_size: int, k: int,
     import subprocess
     import sys
 
+    if backend == "jax-jit" and _parent_holds_device():
+        raise RuntimeError(
+            "this process already holds the accelerator, so the jax-jit "
+            "stream worker could not open it; run the streaming benchmarks "
+            "before anything that touches jax in this process")
     root = pathlib.Path(__file__).resolve().parents[1]
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(

@@ -59,6 +59,7 @@ from repro.core.hbm import TpuParams
 from repro.core.lsu import Lsu, LsuType, make_global_access
 from repro.hw import DEFAULT_BOARD, DEFAULT_CHIP, Hardware
 from repro.hw import get as _hw_get
+from repro.hw import preset_for_device_kind as _hw_preset_for
 
 #: Supported Session compute backends, in increasing batch-friendliness.
 BACKENDS = ("scalar", "numpy-batch", "jax-jit")
@@ -908,9 +909,9 @@ class Session:
         chunk = chunk_size if chunk_size is not None else space.chunk_size
         chunk = int(chunk) if chunk is not None else DEFAULT_CHUNK
         if self.backend == "jax-jit":
-            from repro import compat as _compat
+            import jax
 
-            ndev = _compat.local_device_count()
+            ndev = jax.local_device_count()
             if ndev > 1:
                 # fixed shapes must tile the device mesh exactly
                 chunk = -(-chunk // ndev) * ndev
@@ -999,6 +1000,16 @@ class Session:
                     "the scalar backend (the reference loop is GIL-bound); "
                     "use executor='processes' to fan out across process "
                     "workers")
+        if executor == "processes" and self.backend == "jax-jit":
+            import jax
+
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    "executor='processes' with the jax-jit backend would "
+                    "start worker processes that each open this host's "
+                    "TPU, and a TPU chip serves one process at a time; use "
+                    "executor='threads' (one process drives every local "
+                    "chip) or a host backend for the process pool")
         chunk = chunk_size if chunk_size is not None else space.chunk_size
         if chunk is None and (reducers is not None or workers is not None
                               or executor == "processes"):
@@ -1136,10 +1147,11 @@ class Session:
             if self.backend == "jax-jit" and not plan.constraints:
                 from repro.core import device_stream as _dev
 
-                outcome = _dev.try_outcome(plan, reducers, profile=prof)
-            if outcome is None:
-                if prof:
+                outcome, why = _dev.try_outcome(plan, reducers, profile=prof)
+                if outcome is None and prof is not None:
                     prof.clear()     # drop a failed device attempt's stages
+                    prof["host_reason"] = why
+            if outcome is None:
                 w = workers
                 if w is None and self.backend == "numpy-batch":
                     import os
@@ -1189,8 +1201,7 @@ class Session:
         Pareto mode, a Pareto local search walks ±1-step neighbors of the
         running front — all through the same streaming evaluator a full
         sweep would use, so every reported number is bit-comparable to
-        the exhaustive grid.  Requires jax for the descent phase; without
-        it the screen/refine phases still run.
+        the exhaustive grid.
 
         Returns an :class:`repro.search.OptimizeReport` carrying the best
         point, the evaluated front, per-phase trajectory and the
@@ -1237,12 +1248,22 @@ class Session:
         With ``calibrate=False`` predictions come from this session's own
         ``dram`` parameters alone — no measured wall-clock enters the
         prediction side, so repeated runs predict identically.
+
+        On a TPU backend a session without its own ``hardware`` predicts
+        with the preset of the chip it runs on
+        (:func:`repro.hw.preset_for_device_kind`; an unknown chip raises).
         """
+        import jax
+
         from repro.core import validate as _validate
 
+        dram = self.dram
+        if self.hardware is None and jax.default_backend() == "tpu":
+            kind = jax.devices()[0].device_kind
+            dram = _hw_get(_hw_preset_for(kind)).dram_params()
         rep = _validate._validate(
             cases, iters=iters, warmup=warmup,
-            dram=None if calibrate else self.dram, base=self.dram,
+            dram=None if calibrate else dram, base=dram,
             fit_host_factor=calibrate)
         return ValidateReport(rep)
 
@@ -1281,6 +1302,9 @@ class Session:
             return "hlo", {"step": model}
         if isinstance(model, Mapping):
             return "hlo", {str(k): str(v) for k, v in model.items()}
+        from repro import compat as _compat
+
+        _compat.enable_compilation_cache()      # the lowerings below compile
         if hasattr(model, "block_pattern"):     # models.config.ModelConfig
             from repro.workload import steps as _steps
 
@@ -1478,6 +1502,30 @@ class Session:
 _JAX_FN = None
 
 
+def _jax_estimator_fn():
+    """The jit-compiled batched estimator core (built once per process):
+    ``GroupBatch`` of device arrays -> dict of estimate columns."""
+    global _JAX_FN
+    if _JAX_FN is None:
+        import jax
+        import jax.numpy as jnp
+
+        from repro import compat as _compat
+
+        _mb.enable_jax()
+        _compat.enable_compilation_cache()
+
+        def _run(b):
+            est = _mb.estimate_batch(b, xp=jnp)
+            return {"t_exe": est.t_exe, "t_ideal": est.t_ideal,
+                    "t_ovh": est.t_ovh, "bound_ratio": est.bound_ratio,
+                    "memory_bound": est.memory_bound,
+                    "total_bytes": est.total_bytes, "n_lsu": est.n_lsu,
+                    "groups": est.groups}
+        _JAX_FN = jax.jit(_run)
+    return _JAX_FN
+
+
 def _jax_estimate_batch(batch: _mb.GroupBatch,
                         sharding=None,
                         stage_times: dict | None = None) -> _mb.BatchEstimate:
@@ -1493,25 +1541,17 @@ def _jax_estimate_batch(batch: _mb.GroupBatch,
 
     With ``stage_times``, the host->device upload and the device->host
     result pull are accumulated into ``stage_times["transfer_s"]`` (the
-    compute between them lands in the caller's score bucket).
+    compute between them lands in the caller's score bucket), and
+    ``stage_times["devices"]`` records how many devices the batch spans.
     """
-    global _JAX_FN
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    _mb.enable_jax()
-    if _JAX_FN is None:
-        def _run(b):
-            est = _mb.estimate_batch(b, xp=jnp)
-            return {"t_exe": est.t_exe, "t_ideal": est.t_ideal,
-                    "t_ovh": est.t_ovh, "bound_ratio": est.bound_ratio,
-                    "memory_bound": est.memory_bound,
-                    "total_bytes": est.total_bytes, "n_lsu": est.n_lsu,
-                    "groups": est.groups}
-        _JAX_FN = jax.jit(_run)
+    from repro import compat as _compat
+
+    fn = _jax_estimator_fn()
     timed = stage_times is not None
-    with enable_x64():
+    with _compat.enable_x64():
         t0 = _perf_counter() if timed else 0.0
         jb = _mb.GroupBatch(**{
             f.name: (batch.n_kernels if f.name == "n_kernels"
@@ -1523,7 +1563,9 @@ def _jax_estimate_batch(batch: _mb.GroupBatch,
             jax.block_until_ready(jb.count)
             stage_times["transfer_s"] = (stage_times.get("transfer_s", 0.0)
                                          + _perf_counter() - t0)
-        dev = _JAX_FN(jb)
+            # how many devices each chunk's arrays really span
+            stage_times["devices"] = len(jb.count.sharding.device_set)
+        dev = fn(jb)
         if timed:
             jax.block_until_ready(dev)
             t0 = _perf_counter()

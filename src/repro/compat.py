@@ -1,96 +1,44 @@
-"""Version-adaptive jax/Pallas compatibility layer.
+"""The jax helpers every layer of this repo shares.
 
-Every jax API this repo uses that has drifted across released versions is
-centralized here, so kernels, models, launch code, and test subprocess
-snippets all import the *same* resolution instead of scattering per-file
-``try/except ImportError`` shims:
+One jax release is supported (the one ``pyproject.toml`` pins as its
+floor); if a later release renames something, the fix goes here rather
+than into a kernel or model file:
 
-* ``tpu_compiler_params(**kw)`` — ``pltpu.CompilerParams`` (new name) vs
-  ``pltpu.TPUCompilerParams`` (jax 0.4.x), with unknown-field dropping so a
-  kwarg added in a newer jax does not break an older one.
-* ``prefetch_scalar_grid_spec(**kw)`` — ``pltpu.PrefetchScalarGridSpec``
-  under whichever module layout this jax ships.
-* ``make_mesh(shape, axes)`` — ``jax.sharding.AxisType`` landed in jax 0.5;
-  older versions build implicitly-Auto meshes without the kwarg.
-* ``optimization_barrier(x)`` — jax < 0.5 has no differentiation rule for
-  the ``optimization_barrier`` primitive; this wrapper substitutes a
-  ``custom_jvp`` identity-tangent barrier there so remat'd training still
-  differentiates (the barrier only pins scheduling, it is mathematically
-  the identity).
+* ``enable_x64()`` — the scope every float64/int64 jax computation of the
+  estimator runs in (jit-compiled scoring, the device-fused sweep step,
+  the optimizer's descent).
+* ``make_mesh(shape, axes)`` — ``jax.make_mesh`` with explicit
+  ``AxisType``s.
 * ``default_interpret(flag)`` — one place deciding when Pallas kernels run
   in interpret mode (everywhere except a real TPU backend).
-* ``local_device_count()`` / ``data_sharding(n)`` — device discovery and a
-  1-D leading-axis ``NamedSharding`` (built through ``make_mesh`` so the
-  AxisType drift stays here); the streaming sweep engine shards each
-  fixed-shape chunk batch with it.
-* ``enable_compilation_cache(dir)`` — jax's persistent compilation cache
-  under whichever config spelling this jax ships; the device-resident
-  streaming step (:mod:`repro.core.device_stream`) is recompiled per
-  (chunk size, reducer config) and every cache hit saves a full XLA
-  compile in fresh processes (benchmarks, distributed workers).
+* ``data_sharding(n)`` — a 1-D leading-axis ``NamedSharding``; the
+  streaming sweep engine shards each fixed-shape chunk batch with it.
+* ``enable_compilation_cache()`` — jax's persistent compilation cache, in
+  ``$JAX_COMPILATION_CACHE_DIR`` when set and otherwise in one fixed
+  directory of the checkout, so fresh processes (benchmarks, distributed
+  workers, ``chip_smoke.py``) skip recompiling the same executables.
 
 The module imports jax but never touches device state at import time, so it
 is safe to import before ``XLA_FLAGS`` tricks (dry-run, subprocess tests).
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
+import os
+import pathlib
 
 import jax
+from jax.sharding import AxisType
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
-try:  # pure-python import; present on all backends
-    from jax.experimental.pallas import tpu as _pltpu
-except ImportError:  # pragma: no cover - ancient jax without pallas
-    _pltpu = None
-
-try:  # AxisType landed in jax 0.5; older jax means implicitly-Auto axes.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+#: Where the persistent compilation cache lives when
+#: ``$JAX_COMPILATION_CACHE_DIR`` is unset: a fixed, git-ignored directory
+#: at the root of the checkout (the path is part of jax's cache key, so it
+#: must not move between runs).
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-# ---------------------------------------------------------------------------
-# Pallas TPU
-# ---------------------------------------------------------------------------
-
-def tpu_compiler_params_cls():
-    """The TPU compiler-params class under whichever name this jax ships."""
-    if _pltpu is None:  # pragma: no cover
-        return None
-    return (getattr(_pltpu, "CompilerParams", None)
-            or getattr(_pltpu, "TPUCompilerParams", None))
-
-
-def tpu_compiler_params(**kwargs):
-    """Build TPU ``compiler_params`` for ``pl.pallas_call`` portably.
-
-    Unknown fields are dropped rather than raising, so a parameter that only
-    exists in newer jax degrades to the compiler default on older jax.
-    Returns ``None`` (pallas_call accepts it) when no params class exists.
-    """
-    cls = tpu_compiler_params_cls()
-    if cls is None:  # pragma: no cover
-        return None
-    try:
-        return cls(**kwargs)
-    except TypeError:
-        if dataclasses.is_dataclass(cls):
-            known = {f.name for f in dataclasses.fields(cls)}
-            return cls(**{k: v for k, v in kwargs.items() if k in known})
-        raise
-
-
-def prefetch_scalar_grid_spec(**kwargs):
-    """``pltpu.PrefetchScalarGridSpec`` across module layouts."""
-    if _pltpu is None or not hasattr(_pltpu, "PrefetchScalarGridSpec"):
-        raise NotImplementedError(
-            "this jax has no PrefetchScalarGridSpec; scalar-prefetch kernels "
-            "need jax >= 0.4.20")
-    return _pltpu.PrefetchScalarGridSpec(**kwargs)
+def enable_x64():
+    """Context manager scoping 64-bit jax types (``jax.enable_x64``)."""
+    return jax.enable_x64(True)
 
 
 def default_interpret(interpret: bool | None = None, *,
@@ -110,37 +58,22 @@ def default_interpret(interpret: bool | None = None, *,
 # ---------------------------------------------------------------------------
 
 def make_mesh(shape, axes, *, explicit: bool = False):
-    """``jax.make_mesh`` with AxisType when available, without it otherwise."""
-    if AxisType is not None:
-        kind = AxisType.Explicit if explicit else AxisType.Auto
-        return jax.make_mesh(shape, axes, axis_types=(kind,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def local_device_count(backend: str | None = None) -> int:
-    """Visible local device count; 1 when the backend cannot initialize.
-
-    The streaming sweep engine uses this to decide whether chunks are worth
-    sharding — a RuntimeError (e.g. a TPU backend requested on a CPU host)
-    must degrade to single-device, not crash a sweep.
-    """
-    try:
-        return jax.local_device_count(backend)
-    except RuntimeError:
-        return 1
+    """``jax.make_mesh`` with every axis Auto (or Explicit)."""
+    kind = AxisType.Explicit if explicit else AxisType.Auto
+    return jax.make_mesh(shape, axes, axis_types=(kind,) * len(axes))
 
 
 def data_sharding(n: int | None = None):
     """``NamedSharding`` splitting a leading axis across ``n`` local devices.
 
-    Built on a 1-D ``("data",)`` mesh through :func:`make_mesh`, so the
-    AxisType drift is handled in one place.  This is the sharding the
-    streaming sweep applies to each fixed-shape chunk batch (the leading
-    axis is the LSU-group dimension, ``2 * chunk_size`` entries).
+    Built on a 1-D ``("data",)`` mesh through :func:`make_mesh`.  This is
+    the sharding the streaming sweep applies to each fixed-shape chunk
+    batch (the leading axis is the LSU-group dimension, ``2 * chunk_size``
+    entries).
     """
     from jax.sharding import NamedSharding, PartitionSpec
 
-    n = int(n if n is not None else local_device_count())
+    n = int(n if n is not None else jax.local_device_count())
     mesh = make_mesh((n,), ("data",))
     return NamedSharding(mesh, PartitionSpec("data"))
 
@@ -149,87 +82,24 @@ def data_sharding(n: int | None = None):
 # persistent compilation cache
 # ---------------------------------------------------------------------------
 
-_COMPILATION_CACHE_ON = False
+_CACHE_DIR: str | None = None
 
 
-def enable_compilation_cache(cache_dir: str | None = None) -> bool:
+def enable_compilation_cache() -> str:
     """Turn on jax's persistent (on-disk) compilation cache. Idempotent.
 
-    ``cache_dir`` defaults to ``$JAX_COMPILATION_CACHE_DIR`` or
-    ``~/.cache/repro/jax_cache``.  The min-compile-time / min-entry-size
-    thresholds are lowered where this jax supports them so even fast
-    compiles (the per-chunk-size streaming step) are cached.  Returns False
-    — never raises — when this jax has no usable cache config or the
-    directory cannot be created, so callers can treat the cache as a pure
-    optimization.
+    The directory is ``$JAX_COMPILATION_CACHE_DIR`` when that is set, and
+    :data:`DEFAULT_CACHE_DIR` otherwise.  The min-compile-time /
+    min-entry-size thresholds are lowered so even fast compiles (the
+    per-chunk-size streaming step) are cached.  Returns the directory.
     """
-    global _COMPILATION_CACHE_ON
-    if _COMPILATION_CACHE_ON:
-        return True
-    import os
-    path = (cache_dir
-            or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                            "jax_cache"))
-    try:
+    global _CACHE_DIR
+    if _CACHE_DIR is None:
+        path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                or str(DEFAULT_CACHE_DIR))
         os.makedirs(path, exist_ok=True)
-    except OSError:  # pragma: no cover - unwritable home
-        return False
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except (AttributeError, ValueError):  # pragma: no cover - ancient jax
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc)
-            _cc.set_cache_dir(str(path))
-        except Exception:
-            return False
-    for flag, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(flag, value)
-        except (AttributeError, ValueError):  # pragma: no cover - old jax
-            pass
-    _COMPILATION_CACHE_ON = True
-    return True
-
-
-# ---------------------------------------------------------------------------
-# optimization_barrier
-# ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=1)
-def barrier_is_differentiable() -> bool:
-    """Whether this jax ships a differentiation rule for the barrier."""
-    try:
-        jax.grad(lambda x: jax.lax.optimization_barrier(x) * 1.0)(0.0)
-        return True
-    except NotImplementedError:
-        return False
-
-
-@jax.custom_vjp
-def _barrier_custom(x):
-    return jax.lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return _barrier_custom(x), None
-
-
-def _barrier_bwd(_, g):
-    # The barrier is the identity; barrier the cotangent too so the backward
-    # pass keeps the same scheduling pin as the forward (custom_vjp rather
-    # than custom_jvp: the tangent-side barrier would need the primitive's
-    # transpose rule, which old jax also lacks).
-    return (jax.lax.optimization_barrier(g),)
-
-
-_barrier_custom.defvjp(_barrier_fwd, _barrier_bwd)
-
-
-def optimization_barrier(x):
-    """Differentiable ``jax.lax.optimization_barrier`` on every jax version."""
-    if barrier_is_differentiable():
-        return jax.lax.optimization_barrier(x)
-    return _barrier_custom(x)
+        jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        _CACHE_DIR = path
+    return _CACHE_DIR

@@ -25,15 +25,14 @@ very end:
   through the shared position-deterministic tree sum
   (:func:`stream._tree_sum`), which is what makes the fixed-shape
   zero-masked device fold *bit-equal* to the host fold under any chunk
-  partition.  Selection reducers never comparator-sort the full chunk:
-  every sort key becomes an order-isomorphic int64 (:func:`_f64_key` for
-  floats, the value itself for ints), candidate lanes are picked with
-  single-operand integer sorts — a threshold cut for top-k, an exact
-  in-chunk dominance prefilter (rank / scatter-min / prefix-min) for the
-  Pareto front — and only those few lanes are re-scored (elementwise, so
-  bit-equal) and merged with the carry by a tiny exact sort.  On XLA:CPU
-  a single-operand int64 sort is ~16x faster than the multi-operand
-  float comparator sort it replaces.
+  partition.  The selection reducers sort nothing: top-k takes ``k``
+  rounds of a masked lexicographic minimum over (value, id), and the
+  Pareto front is a staircase walk of the same minimum, one round per
+  front point; only the selected rows' columns are gathered.  On the TPU
+  every float64 op is emulated and the compiler spends minutes on a
+  float64 sort (and tens of seconds on each copy of the Eqs. 1-10 graph,
+  which is therefore traced once per step); masked reductions in a loop
+  compile in seconds.
 * **Overlapped dispatch** — the chunk loop enqueues step N+1 while N
   computes (jax async dispatch; the carry is donated off-CPU so state
   ping-pongs between two buffers), and the step executable is keyed only
@@ -51,11 +50,11 @@ flags checked before any reducer is touched; an overflow raises
 host path — never a silently truncated result.
 
 Everything jax lives inside functions: importing this module is
-numpy-only, and :meth:`DeviceSweep.build` returns ``None`` (host path)
-whenever jax is missing, the plan is constrained, several local devices
-are visible (the host path shards chunks across them), or the plan's axis
+numpy-only.  :meth:`DeviceSweep.build` raises :class:`DeviceIneligible`,
+naming the reason, when the plan is constrained, several local devices are
+visible (the host path shards chunks across them), or the plan's axis
 values fall outside the integer/bool domain the device tables mirror
-bit-exactly.
+bit-exactly; callers record that reason where the path taken is reported.
 """
 from __future__ import annotations
 
@@ -84,20 +83,15 @@ _BSP_FIELDS = ("burst_cnt", "max_th")
 #: Chunk-column order (must cover everything the host evaluator emits).
 COLUMNS = ("id",) + _sweep.AXES + _stream.ESTIMATE_COLUMNS + ("resource",)
 
-_SENT_ID = np.int64(1) << 62          # sorts after every real point id
-
-_I64MAX = np.int64(np.iinfo(np.int64).max)
-
-#: ``_f64_key(+inf)`` — the masked-lane / empty-slot sentinel for
-#: float-keyed selection, so dead lanes behave exactly like the host
-#: fold's ``+inf`` padding.
-_INFKEY = np.int64(0x7FF0000000000000)
-
 _STEP_CACHE: dict = {}
 
 
 class DeviceFoldOverflow(RuntimeError):
     """A fixed-shape device carry ran out of capacity; refold on the host."""
+
+
+class DeviceIneligible(ValueError):
+    """The plan cannot take the fused device path; the message says why."""
 
 
 # ---------------------------------------------------------------------------
@@ -149,38 +143,18 @@ def _exact_add(parts, cnt, x):
     return new_parts, jnp.minimum(i + jnp.int32(1), n_slots), overflow
 
 
-def _f64_key(x):
-    """Order-isomorphic int64 key of a float64 array.
-
-    ``x + 0.0`` collapses ``-0.0`` into ``+0.0`` (bit-distinct but
-    numerically equal), then the sign-aware flip makes the raw IEEE-754
-    pattern totally ordered as a signed int64: ``key(a) < key(b)`` iff
-    ``a < b`` and ``key(a) == key(b)`` iff ``a == b`` for every non-NaN
-    pair — so sorting keys is sorting values, with identical ties.
-    """
-    import jax
+def _lexmin(live, keys):
+    """Index of the lexicographically smallest ``live`` entry by ``keys``
+    (a tuple of arrays; the first index breaks a full tie), and whether any
+    entry is live.  Plain masked reductions: no sort, no bitcast."""
     import jax.numpy as jnp
 
-    b = jax.lax.bitcast_convert_type(x + 0.0, jnp.int64)
-    return b ^ ((b >> 63) & jnp.int64(0x7FFFFFFFFFFFFFFF))
-
-
-def _col_key(v, mask):
-    """``(monotonic int64 sort key, sentinel)`` for one column.
-
-    Float columns map through :func:`_f64_key` (sentinel ``_INFKEY``,
-    the +inf key); integer/bool columns are exact as int64 (sentinel
-    ``_I64MAX``).  Key order and key ties match the host's native-dtype
-    comparisons — tighter than a float64 cast, which would round int64
-    columns above 2**53.  Masked lanes get the sentinel.
-    """
-    import jax.numpy as jnp
-
-    if jnp.issubdtype(v.dtype, jnp.floating):
-        key, sent = _f64_key(v.astype(jnp.float64)), _INFKEY
-    else:
-        key, sent = v.astype(jnp.int64), _I64MAX
-    return jnp.where(mask, key, jnp.int64(sent)), jnp.int64(sent)
+    cand = live
+    for k in keys:
+        fill = (jnp.inf if jnp.issubdtype(k.dtype, jnp.floating)
+                else jnp.iinfo(k.dtype).max)
+        cand = cand & (k == jnp.min(jnp.where(cand, k, fill)))
+    return jnp.argmax(cand), jnp.any(cand)
 
 
 def _score_ids(tables, ids):
@@ -191,17 +165,17 @@ def _score_ids(tables, ids):
     hardware resolution, and runs :func:`model_batch.estimate_batch` with
     ``xp=jnp`` (``paired_kernel`` replaces each scatter-based segment sum
     with its bit-equal two-term split add) — so every column is bit-equal
-    to the host evaluator's for the same ids.  Unused columns cost
-    nothing: callers consume what they need and XLA dead-code-eliminates
-    the rest, which is what lets the selection folds re-score only their
-    few candidate lanes.
+    to the host evaluator's for the same ids.
     """
     import jax.numpy as jnp
 
     chunk = ids.shape[0]
     iota = jnp.arange(chunk, dtype=jnp.int64)
     strides, mods = tables["strides"], tables["mods"]
-    code = {name: (ids // strides[i]) % mods[i]
+    # decode in the tables' integer width: int32 whenever the grid fits it
+    # (see DeviceSweep.build), since 64-bit division is emulated on the TPU
+    dec = ids.astype(strides.dtype)
+    code = {name: (dec // strides[i]) % mods[i]
             for i, name in enumerate(_sweep.AXES)}
     num = {k: tables["num_" + k][code[k]] for k in _NUM_AXES}
 
@@ -224,7 +198,11 @@ def _score_ids(tables, ids):
     g1_type = jnp.where(is_ack, _mb.ALIGNED, type_codes)
     g1_count = jnp.where(is_atomic | is_ack, n_ga, n_ga + include_write)
     g1_width = jnp.where(is_atomic, elem_bytes, simd * elem_bytes)
-    g1_acc = jnp.where(is_atomic, n_elems, n_elems // simd)
+    # n_elems // simd from a host-built table: 64-bit division is emulated
+    # on the TPU
+    per_simd = tables["acc_per_simd"][code["n_elems"] * tables["len_simd"]
+                                      + code["simd"]]
+    g1_acc = jnp.where(is_atomic, n_elems, per_simd)
     g2_count = jnp.where(is_ack & include_write, simd, 0)
 
     vec = lambda a, b: jnp.concatenate([a, b])  # noqa: E731
@@ -237,7 +215,7 @@ def _score_ids(tables, ids):
         lsu_type=vec(g1_type, jnp.full(chunk, _mb.WRITE_ACK,
                                        dtype=jnp.int64)),
         ls_width=vec(g1_width, elem_bytes),
-        ls_acc=vec(g1_acc, n_elems // simd),
+        ls_acc=vec(g1_acc, per_simd),
         ls_bytes=vec(g1_width, elem_bytes),
         delta=vec(delta, jnp.ones(chunk, dtype=jnp.int64)),
         val_constant=vec(val_constant, jnp.zeros(chunk, dtype=bool)),
@@ -255,11 +233,12 @@ def _score_ids(tables, ids):
 
     cols = {
         "id": ids,
-        "lsu_type": code["lsu_type"],
+        "lsu_type": code["lsu_type"].astype(jnp.int64),
         "n_ga": n_ga, "simd": simd, "n_elems": n_elems, "delta": delta,
         "elem_bytes": elem_bytes,
         "include_write": include_write, "val_constant": val_constant,
-        "dram": d_code, "bsp": b_code, "hardware": code["hardware"],
+        "dram": d_code.astype(jnp.int64), "bsp": b_code.astype(jnp.int64),
+        "hardware": code["hardware"].astype(jnp.int64),
     }
     for name in _stream.ESTIMATE_COLUMNS:
         v = getattr(est, name)
@@ -310,12 +289,17 @@ def _fold_stats(st, cols, valid, mask, chunk: int):
     mf = valid.astype(jnp.float64)
     cmean = s / mf
     cm2 = _tree_sum_dev(jnp.where(mask, (t - cmean) ** 2, 0.0), chunk)
-    # _chan_merge(n_points, mean, m2, valid, cmean, cm2), same op order
+    # _chan_merge(n_points, mean, m2, valid, cmean, cm2), same op order.
+    # XLA:CPU contracts a product feeding an add into one FMA, rounding
+    # once where the host rounds twice; a select between them (always
+    # true: every step folds at least one point) keeps the two roundings.
     n_new = st["n"] + valid
     nf = n_new.astype(jnp.float64)
     d = cmean - st["mean"]
-    mean = st["mean"] + d * (mf / nf)
-    m2 = st["m2"] + cm2 + d * d * (st["n"].astype(jnp.float64) / nf * mf)
+    live = n_new > 0
+    mean = st["mean"] + jnp.where(live, d * (mf / nf), 0.0)
+    m2 = st["m2"] + cm2 + jnp.where(
+        live, d * d * (st["n"].astype(jnp.float64) / nf * mf), 0.0)
 
     vals = jnp.where(mask, t, jnp.inf)
     i = jnp.argmin(vals)                     # first occurrence, like numpy
@@ -333,143 +317,84 @@ def _fold_stats(st, cols, valid, mask, chunk: int):
     }
 
 
-def _fold_topk(st, cols, valid, mask, k: int, key: str, chunk: int, tables):
+def _fold_topk(st, cols, valid, mask, k: int, key: str):
     """Traced twin of :meth:`stream.TopKReducer.update` for one chunk.
 
-    Selection is by (value, id) — exactly the host's stable lexsort
-    tie-breaking — but never comparator-sorts the chunk.  Three cheap
-    passes instead:
-
-    1. a single-operand sort of the int64 keys yields the k-th smallest
-       key ``thr``;
-    2. a second single-operand sort over ``where(key < thr, lane - chunk,
-       where(key == thr, lane, big))`` packs every lane strictly below
-       the threshold (at most k-1 by the order-statistic definition)
-       ahead of the tied lanes in ascending lane (= ascending id) order,
-       so the first ``2k`` entries always contain the exact top-k —
-       arbitrary ties need no capacity flag;
-    3. the 2k candidates are re-scored (every column is an elementwise
-       function of a lane's own axis values, so re-scoring is bit-equal)
-       and merged with the carry by a tiny exact (key, id) sort.
-
-    Empty carry slots and masked lanes carry (sentinel-key, sentinel-id)
-    pairs that sort after every real row.
+    The held rows and the chunk's live lanes form one candidate set; ``k``
+    rounds of :func:`_lexmin` over its (value, id) pairs, as float64 like
+    the host's keys, pick the next row each — exactly the host's stable
+    (value, id) order.  The picked rows' columns are gathered in rank
+    order.  No sort: the TPU's compiler spends minutes on a float64 sort
+    comparator, and ``k`` masked reductions cost next to nothing.
     """
     import jax
     import jax.numpy as jnp
 
-    kkey, sent = _col_key(cols[key], mask)
-    ids = cols["id"]
-    if k >= chunk:
-        b = chunk
-        lanes = jnp.arange(chunk, dtype=jnp.int64)
-        real = mask
-    else:
-        b = 2 * k
-        iota = jnp.arange(chunk, dtype=jnp.int64)
-        (skey,) = jax.lax.sort((kkey,), num_keys=1)
-        thr = skey[k - 1]
-        big = jnp.int64(2 * chunk)
-        ckey = jnp.where(kkey < thr, iota - chunk,
-                         jnp.where(kkey == thr, iota, big))
-        (sc,) = jax.lax.sort((ckey,), num_keys=1)
-        ent = sc[:b]
-        lanes = jnp.where(ent < 0, ent + chunk,
-                          jnp.minimum(ent, chunk - 1))
-        real = (ent < big) & mask[lanes]
-    ckk = jnp.where(real, kkey[lanes], sent)
-    cid = jnp.where(real, ids[lanes], _SENT_ID)
-    cols2 = _score_ids(tables, ids[lanes])
-    mk = jnp.concatenate([st["sortkey"], ckk])
-    mi = jnp.concatenate([st["sortid"], cid])
-    pos = jnp.arange(k + b, dtype=jnp.int64)
-    sk, si, sp = jax.lax.sort((mk, mi, pos), num_keys=2)
-    perm = sp[:k]
-    new_cols = {c: jnp.concatenate([st["cols"][c], cols2[c]])[perm]
-                for c in COLUMNS}
-    return {"cols": new_cols, "sortkey": sk[:k], "sortid": si[:k],
+    held = jnp.minimum(st["n_seen"], k)
+    vals = jnp.concatenate([st["cols"][key], cols[key]]).astype(jnp.float64)
+    ids = jnp.concatenate([st["cols"]["id"], cols["id"]])
+    live = jnp.concatenate([jnp.arange(k) < held, mask])
+    pos = jnp.arange(live.shape[0])
+
+    def pick(j, carry):
+        live, picks = carry
+        p, found = _lexmin(live, (vals, ids))
+        return (live & ~(found & (pos == p)),
+                picks.at[j].set(jnp.where(found, p, 0)))
+
+    _, picks = jax.lax.fori_loop(0, k, pick,
+                                 (live, jnp.zeros(k, dtype=pos.dtype)))
+    return {"cols": {c: jnp.concatenate([st["cols"][c], cols[c]])[picks]
+                     for c in COLUMNS},
             "n_seen": st["n_seen"] + valid}
 
 
-def _fold_pareto(st, cols, valid, mask, cap: int, objectives, chunk: int,
-                 tables):
+def _fold_pareto(st, cols, valid, mask, cap: int, objectives):
     """Traced twin of :meth:`stream.ParetoReducer.update` (2 objectives).
 
-    An exact in-chunk dominance prefilter replaces the old 3-operand
-    comparator sort over (cap + chunk) lanes: rank the v0 keys with a
-    single-operand sort + ``searchsorted``, scatter-min the v1 keys per
-    v0 group, prefix-min across groups, and drop every lane those minima
-    dominate.  The predicate is :func:`sweep._pareto_2d`'s mask
-    restricted to the chunk, and chunk-dominated implies union-dominated
-    (adding carry rows can only lower the group minima), so dropped
-    lanes can never reach the merged front; conversely every dropped
-    lane's dominator chain ends in a surviving lane (dominance is a
-    strict partial order), so the merge still flags exactly the rows the
-    host fold flags.  Survivors are compacted in ascending lane
-    (= ascending id) order, re-scored at width S (elementwise, so
-    bit-equal), and merged with the carry by `_pareto_2d` in key space
-    over (cap + S) lanes — carry rows first, which preserves the host's
-    ascending-id held order.  Empty carry slots and masked lanes hold
-    (sentinel, sentinel) keys: the host's ``+inf`` padding role.  More
-    than S chunk survivors or more than ``cap`` merged survivors sets
-    the overflow flag.
+    The held front and the chunk's live lanes form one candidate set,
+    keyed by the objectives as float64 like the host's.  A staircase walk
+    finds its front: each round takes the live rows at the lexicographic
+    minimum (v0, v1) — non-dominated, and kept with all their duplicates,
+    as :func:`sweep._pareto_2d` keeps them — and retires every row with
+    v1 >= that minimum, which it dominates.  Rounds equal the number of
+    distinct front points.  Survivors keep their position order (held
+    rows first, then lanes by ascending id), the host's held order, and
+    are compacted into the ``cap`` slots with a prefix count.  More than
+    ``cap`` survivors sets the overflow flag.  No sort: see
+    :func:`_fold_topk`.
     """
     import jax
     import jax.numpy as jnp
 
     o0, o1 = objectives
-    k0, sent0 = _col_key(cols[o0], mask)
-    k1, sent1 = _col_key(cols[o1], mask)
-    iota = jnp.arange(chunk, dtype=jnp.int64)
+    v0 = jnp.concatenate([st["cols"][o0], cols[o0]]).astype(jnp.float64)
+    v1 = jnp.concatenate([st["cols"][o1], cols[o1]]).astype(jnp.float64)
+    live = jnp.concatenate([jnp.arange(cap) < st["count"], mask])
+    m = live.shape[0]
 
-    (s0,) = jax.lax.sort((k0,), num_keys=1)
-    g = jnp.searchsorted(s0, k0, side="left")
-    gm = jnp.full(chunk, _I64MAX, dtype=jnp.int64).at[g].min(k1)
-    cm = jax.lax.cummin(gm)
-    m_strict = jnp.where(g > 0, cm[jnp.maximum(g - 1, 0)], sent1)
-    keep = mask & ~((m_strict <= k1) | (gm[g] < k1))
-    s_count = jnp.sum(keep.astype(jnp.int64))
+    def more(carry):
+        alive, _, rounds = carry
+        return jnp.any(alive) & (rounds <= cap)
 
-    s_cap = min(cap, chunk)
-    big = jnp.int64(2 * chunk)
-    ckey = jnp.where(keep, iota, big)
-    (sc,) = jax.lax.sort((ckey,), num_keys=1)
-    ent = sc[:s_cap]
-    lanes = jnp.minimum(ent, chunk - 1)
-    cand = ent < big
-    cv0 = jnp.where(cand, k0[lanes], sent0)
-    cv1 = jnp.where(cand, k1[lanes], sent1)
-    cols2 = _score_ids(tables, cols["id"][lanes])
+    def walk(carry):
+        alive, front, rounds = carry
+        p, _ = _lexmin(alive, (v0, v1))
+        at_min = alive & (v0 == v0[p]) & (v1 == v1[p])
+        return alive & (v1 < v1[p]), front | at_min, rounds + 1
 
-    m = cap + s_cap
-    v0 = jnp.concatenate([st["v0k"], cv0])
-    v1 = jnp.concatenate([st["v1k"], cv1])
-    midx = jnp.arange(m, dtype=jnp.int64)
-    sm0, sm1, sidx = jax.lax.sort((v0, v1, midx), num_keys=3)
-
-    new_group = jnp.concatenate(
-        [jnp.ones(1, dtype=bool), sm0[1:] != sm0[:-1]])
-    group_start = jax.lax.cummax(jnp.where(new_group, midx, 0))
-    gmin = sm1[group_start]
-    cmm = jax.lax.cummin(sm1)
-    prev_end = group_start - 1
-    m_str = jnp.where(prev_end >= 0, cmm[jnp.maximum(prev_end, 0)], sent1)
-    dominated = (m_str <= sm1) | (gmin < sm1)
-
-    survives = ~dominated
-    count = jnp.sum(survives.astype(jnp.int64))
-    keep_key = jnp.where(survives, sidx, _SENT_ID)
-    (ordered,) = jax.lax.sort((keep_key,), num_keys=1)
-    perm = jnp.minimum(ordered[:cap], m - 1)      # clamp sentinels: gather-safe
-    live = jnp.arange(cap, dtype=jnp.int64) < jnp.minimum(count, cap)
-    new_cols = {c: jnp.concatenate([st["cols"][c], cols2[c]])[perm]
-                for c in COLUMNS}
+    alive, front, _ = jax.lax.while_loop(
+        more, walk, (live, jnp.zeros(m, dtype=bool), jnp.int32(0)))
+    count = jnp.sum(front.astype(jnp.int32))
+    slot = jnp.cumsum(front.astype(jnp.int32)) - 1
+    slot = jnp.where(front & (slot < cap), slot, cap)
+    perm = jnp.zeros(cap + 1, dtype=jnp.int32).at[slot].set(
+        jnp.arange(m, dtype=jnp.int32))[:cap]
     return {
-        "cols": new_cols,
-        "v0k": jnp.where(live, v0[perm], sent0),
-        "v1k": jnp.where(live, v1[perm], sent1),
-        "count": jnp.minimum(count, cap),
-        "ovf": st["ovf"] | (count > cap) | (s_count > s_cap),
+        "cols": {c: jnp.concatenate([st["cols"][c], cols[c]])[perm]
+                 for c in COLUMNS},
+        "count": jnp.minimum(count, cap).astype(jnp.int64),
+        "ovf": st["ovf"] | (count > cap) | jnp.any(alive),
     }
 
 
@@ -496,10 +421,10 @@ def _get_step(chunk: int, sig: tuple):
                 out.append(_fold_stats(st, cols, valid, mask, chunk))
             elif spec[0] == "topk":
                 out.append(_fold_topk(st, cols, valid, mask,
-                                      spec[1], spec[2], chunk, tables))
+                                      spec[1], spec[2]))
             else:
                 out.append(_fold_pareto(st, cols, valid, mask,
-                                        spec[1], spec[2], chunk, tables))
+                                        spec[1], spec[2]))
         return tuple(out)
 
     donate = (0,) if jax.default_backend() != "cpu" else ()
@@ -549,37 +474,40 @@ class DeviceSweep:
     # -- eligibility --------------------------------------------------------
 
     @classmethod
-    def build(cls, plan: "_stream.SweepPlan") -> "DeviceSweep | None":
-        """A driver for ``plan``, or ``None`` when the host path must run.
+    def build(cls, plan: "_stream.SweepPlan") -> "DeviceSweep":
+        """A driver for ``plan``.
 
-        Ineligible: jax missing, non-jax backend, constrained plan,
-        several visible devices (the host path shards chunks across them),
-        an empty grid, non-integer/bool numeric axis values (the device
-        tables mirror the host's gathered dtypes exactly), or axis values
-        the host evaluator itself would reject.
+        Raises :class:`DeviceIneligible`, naming the reason, when the host
+        path must run instead: non-jax backend, constrained plan, several
+        visible devices (the host path shards chunks across them), an
+        empty grid, non-integer/bool numeric axis values (the device tables
+        mirror the host's gathered dtypes exactly), or axis values the host
+        evaluator itself would reject.
         """
-        try:
-            import jax  # noqa: F401
-        except ImportError:  # pragma: no cover - jax-less install
-            return None
+        import jax
+
         from repro import compat as _compat
 
-        if plan.backend != "jax-jit" or plan.constraints:
-            return None
-        if _compat.local_device_count() > 1:
-            return None
+        if plan.backend != "jax-jit":
+            raise DeviceIneligible(f"backend {plan.backend!r}")
+        if plan.constraints:
+            raise DeviceIneligible("constrained plan")
+        ndev = jax.local_device_count()
+        if ndev > 1:
+            raise DeviceIneligible(
+                f"{ndev} local devices: chunks are sharded on the host path")
         lists = {k: list(v) for k, v in plan.lists.items()}
         enum = _stream.GridEnumerator(lists)
         if enum.n == 0:
-            return None
+            raise DeviceIneligible("empty grid")
 
+        idt = np.int32 if enum.n < 2 ** 31 else np.int64
         tables: dict = {
-            "strides": enum.strides.copy(),
-            "mods": enum._mod.copy(),
+            "strides": enum.strides.astype(idt),
+            "mods": enum._mod.astype(idt),
             "n": np.int64(enum.n),
             "calib": np.float64(plan.calibration_factor),
         }
-        int_axes = ("n_ga", "simd", "n_elems", "delta", "elem_bytes")
         for k in _NUM_AXES:
             arr = np.asarray(lists[k])
             want = np.bool_ if k in ("include_write",
@@ -587,27 +515,25 @@ class DeviceSweep:
             if arr.dtype == object or not (
                     np.issubdtype(arr.dtype, np.integer)
                     or np.issubdtype(arr.dtype, np.bool_)):
-                return None
+                raise DeviceIneligible(f"non-integer values on axis {k!r}")
             tables["num_" + k] = _pad_table(arr.astype(want))
-        for k in int_axes[:4]:      # host _score raises on these; let it
-            pass
-        if (tables["num_n_ga"][:len(lists["n_ga"])].min(initial=1) < 1
-                or tables["num_simd"][:len(lists["simd"])].min(
-                    initial=1) < 1
-                or tables["num_delta"][:len(lists["delta"])].min(
-                    initial=1) < 1):
-            return None
+        for k in ("n_ga", "simd", "delta"):
+            if tables["num_" + k][:len(lists[k])].min(initial=1) < 1:
+                raise DeviceIneligible(f"axis {k!r} has values < 1")
         ne = np.asarray(lists["n_elems"], dtype=np.int64)
         sd = np.asarray(lists["simd"], dtype=np.int64)
         if np.any(ne[:, None] % sd[None, :]):
-            return None
+            raise DeviceIneligible("a simd value does not divide n_elems")
+        tables["acc_per_simd"] = _pad_table((ne[:, None] // sd[None, :])
+                                            .ravel())
+        tables["len_simd"] = idt(len(sd))
 
         try:
             lsu_codes = np.asarray([_mb.TYPE_CODE[t]
                                     for t in lists["lsu_type"]],
                                    dtype=np.int64)
         except (KeyError, TypeError):
-            return None
+            raise DeviceIneligible("unknown lsu_type value") from None
         tables["lsu_code"] = _pad_table(lsu_codes)
 
         hw_table = lists["hardware"]
@@ -628,7 +554,8 @@ class DeviceSweep:
                     [getattr(b, k) if b is not None else 0
                      for b in b_table]))
         except (AttributeError, TypeError):
-            return None
+            raise DeviceIneligible("unreadable dram/bsp/hardware axis "
+                                   "values") from None
         tables["hw_own"] = _pad_table(np.asarray(is_none, dtype=bool))
         tables["hw_hf"] = _pad_table(np.asarray(hf, dtype=np.float64))
         tables["len_d"] = np.int64(len(lists["dram"]))
@@ -674,25 +601,15 @@ class DeviceSweep:
                     "ovf": jnp.bool_(False),
                 })
             elif spec[0] == "topk":
-                k = spec[1]
-                sent = (_INFKEY if _COL_DTYPES[spec[2]] is np.float64
-                        else _I64MAX)
                 carry.append({
-                    "cols": {c: jnp.zeros(k, dtype=_COL_DTYPES[c])
+                    "cols": {c: jnp.zeros(spec[1], dtype=_COL_DTYPES[c])
                              for c in COLUMNS},
-                    "sortkey": jnp.full(k, sent, dtype=jnp.int64),
-                    "sortid": jnp.full(k, _SENT_ID, dtype=jnp.int64),
                     "n_seen": jnp.int64(0),
                 })
             else:
-                cap = spec[1]
-                s0, s1 = (_INFKEY if _COL_DTYPES[o] is np.float64
-                          else _I64MAX for o in spec[2])
                 carry.append({
-                    "cols": {c: jnp.zeros(cap, dtype=_COL_DTYPES[c])
+                    "cols": {c: jnp.zeros(spec[1], dtype=_COL_DTYPES[c])
                              for c in COLUMNS},
-                    "v0k": jnp.full(cap, s0, dtype=jnp.int64),
-                    "v1k": jnp.full(cap, s1, dtype=jnp.int64),
                     "count": jnp.int64(0),
                     "ovf": jnp.bool_(False),
                 })
@@ -719,7 +636,8 @@ class DeviceSweep:
         import time
 
         import jax
-        from jax.experimental import enable_x64
+
+        from repro import compat as _compat
 
         n, chunk = self.n, self.chunk
         lo, hi = int(lo), min(int(hi), n)
@@ -739,7 +657,7 @@ class DeviceSweep:
                              "check supports() first")
         step = _get_step(chunk, sig)
 
-        with enable_x64():
+        with _compat.enable_x64():
             t0 = time.perf_counter()
             if self._tables_dev is None:
                 self._tables_dev = jax.device_put(self._tables_host)
@@ -809,24 +727,29 @@ class DeviceSweep:
 
 
 def try_outcome(plan: "_stream.SweepPlan", reducers,
-                profile: dict | None = None) -> "_stream.StreamOutcome | None":
-    """Run the whole grid device-resident, or ``None`` for the host path.
+                profile: dict | None = None,
+                ) -> "tuple[_stream.StreamOutcome | None, str]":
+    """Run the whole grid device-resident: ``(outcome, "")``, or
+    ``(None, reason)`` when the host path must run instead.
 
     Folds ``[0, n)`` into ``reducers`` (which are only touched on success
     — a capacity overflow returns ``None`` with the reducers pristine) and
     returns the same :class:`stream.StreamOutcome` ``run_stream`` would.
+    Device, compile and lowering errors propagate: only a declared
+    ineligibility or a capacity overflow selects the host path.
     """
-    dev = DeviceSweep.build(plan)
-    if dev is None:
-        return None
+    try:
+        dev = DeviceSweep.build(plan)
+    except DeviceIneligible as e:
+        return None, str(e)
     reducers = tuple(reducers)
     if not dev.supports(reducers):
-        return None
+        return None, "custom reducer set"
     n = dev.n
     try:
         dev.fold_range(0, n, reducers, profile=profile)
-    except DeviceFoldOverflow:
-        return None
+    except DeviceFoldOverflow as e:
+        return None, f"device fold overflow: {e}"
     return _stream.StreamOutcome(
         reducers=reducers, n_points=n,
-        n_chunks=-(-n // plan.chunk_size), chunk_size=plan.chunk_size)
+        n_chunks=-(-n // plan.chunk_size), chunk_size=plan.chunk_size), ""

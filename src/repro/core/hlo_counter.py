@@ -59,7 +59,13 @@ _ELEMENTWISE_FLOPS = {
 _NO_TRAFFIC = {"parameter", "constant", "tuple", "get-tuple-element",
                "bitcast", "after-all", "iota", "partition-id", "replica-id",
                "rng-bit-generator", "rng-get-and-update-state", "domain",
-               "opt-barrier", "custom-call"}
+               "opt-barrier"}
+#: A Pallas TPU kernel's ``cost_estimate`` inside its custom call's backend
+#: config (the kernel's DMAs are block copies: counted as stream traffic).
+_COST_ESTIMATE = re.compile(
+    r'"cost_estimate":\s*\{\s*"flops":\s*"?(?P<flops>\d+)"?,\s*'
+    r'"transcendentals":\s*"?(?P<trans>\d+)"?,\s*"bytes_accessed":\s*'
+    r'"?(?P<bytes>\d+)')
 # NOTE: dynamic-slice / dynamic-update-slice are *contiguous block* accesses
 # (scan-counter offsets) — the paper's burst-coalesced-aligned class — so they
 # stay in "stream".  Only data-dependent gather/scatter carry the per-row
@@ -426,9 +432,18 @@ class Analyzer:
         c = HloCost()
         op = ins.opcode
         base = op[:-6] if op.endswith("-start") else op
-        if op in _NO_TRAFFIC or op.endswith("-done"):
-            if op == "custom-call":
+        if op == "custom-call":
+            # A compiled Pallas kernel is opaque here; its pallas_call
+            # cost estimate (kept in the backend config) says what it moves.
+            m = _COST_ESTIMATE.search(ins.rest)
+            if m is None:
                 c.warnings.append(f"custom-call {ins.name} uncounted")
+                return c
+            c.flops = float(m.group("flops"))
+            c.transcendentals = float(m.group("trans"))
+            c.bytes_by_class["stream"] = float(m.group("bytes"))
+            return c
+        if op in _NO_TRAFFIC or op.endswith("-done"):
             return c
 
         result_b = shape_bytes(ins.shape)

@@ -802,10 +802,12 @@ class SweepPlan:
 
         estimator = None
         if backend == "jax-jit":
+            import jax
+
             from repro import api as _api
             from repro import compat as _compat
 
-            ndev = _compat.local_device_count()
+            ndev = jax.local_device_count()
             sharding = (_compat.data_sharding(ndev)
                         if ndev > 1 and self.chunk_size % ndev == 0 else None)
             estimator = (lambda b: _api._jax_estimate_batch(
@@ -997,13 +999,13 @@ def make_range_folder(plan: SweepPlan) -> Callable:
     for it.
     """
     device = None
-    if plan.backend == "jax-jit" and not plan.constraints:
+    if plan.backend == "jax-jit":
+        from repro.core import device_stream as _dev
+
         try:
-            from repro.core import device_stream as _dev
-        except ImportError:  # pragma: no cover - jax-less install
-            _dev = None
-        if _dev is not None:
             device = _dev.DeviceSweep.build(plan)
+        except _dev.DeviceIneligible:
+            pass            # e.g. constraints or several devices: host path
 
     evaluator = None
 

@@ -71,7 +71,10 @@ def default_cases(*, small: bool = True) -> list[ValidationCase]:
     """The five Pallas kernels + the three membench access classes.
 
     ``small=True`` keeps interpret-mode wall time in seconds (CI); pass
-    False on a real accelerator for measurement-grade shapes.
+    False on a real accelerator for measurement-grade shapes.  Block and
+    head sizes follow the TPU's tiling at both sizes: 1-D f32 blocks are
+    multiples of 1024 elements, the decode head dim and the mLSTM chunk
+    are 128 lanes wide.
     """
     import functools
 
@@ -95,14 +98,14 @@ def default_cases(*, small: bool = True) -> list[ValidationCase]:
     def strided():
         xs = tuple(jax.random.normal(jax.random.PRNGKey(i), (n,), jnp.float32)
                    for i in range(2))
-        return (jax.jit(functools.partial(MB.strided_sum, delta=4, block=512)),
-                (xs,))
+        return (jax.jit(functools.partial(MB.strided_sum, delta=4,
+                                          block=1024)), (xs,))
 
     def gather():
         xs = tuple(jax.random.normal(jax.random.PRNGKey(i), (n,), jnp.float32)
                    for i in range(2))
-        idx = jax.random.randint(jax.random.PRNGKey(9), (16,), 0, n // 512)
-        return (jax.jit(functools.partial(MB.gather_sum, block=512)),
+        idx = jax.random.randint(jax.random.PRNGKey(9), (16,), 0, n // 1024)
+        return (jax.jit(functools.partial(MB.gather_sum, block=1024)),
                 (xs, idx))
 
     def flash():
@@ -115,9 +118,9 @@ def default_cases(*, small: bool = True) -> list[ValidationCase]:
 
     def decode():
         ks = jax.random.split(jax.random.PRNGKey(1), 3)
-        q = jax.random.normal(ks[0], (2, 1, 8, 32), jnp.float32)
-        kc = jax.random.normal(ks[1], (2, S, 2, 32), jnp.float32)
-        vc = jax.random.normal(ks[2], (2, S, 2, 32), jnp.float32)
+        q = jax.random.normal(ks[0], (2, 1, 8, 128), jnp.float32)
+        kc = jax.random.normal(ks[1], (2, S, 2, 128), jnp.float32)
+        vc = jax.random.normal(ks[2], (2, S, 2, 128), jnp.float32)
         ln = jnp.asarray(S, jnp.int32)
         return (jax.jit(functools.partial(gqa_decode, block_s=64)),
                 (q, kc, vc, ln))
@@ -136,7 +139,7 @@ def default_cases(*, small: bool = True) -> list[ValidationCase]:
         v = jax.random.normal(ks[2], (1, S, 2, 32), jnp.float32)
         li = jax.nn.log_sigmoid(jax.random.normal(ks[3], (1, S, 2)))
         lf = jax.nn.log_sigmoid(jax.random.normal(ks[4], (1, S, 2)) + 2.0)
-        return (jax.jit(functools.partial(chunked_mlstm, chunk=64)),
+        return (jax.jit(functools.partial(chunked_mlstm, chunk=128)),
                 (q, k, v, li, lf))
 
     return [
@@ -293,6 +296,7 @@ def _validate(cases: Sequence[ValidationCase] | None = None, *,
 
     from repro import compat
 
+    compat.enable_compilation_cache()
     base = base if base is not None else _default_dram()
     backend = jax.default_backend()
     interpret = compat.default_interpret()

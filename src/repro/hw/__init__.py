@@ -27,7 +27,7 @@ from repro.hw.spec import (
     enable_jax,
 )
 from repro.hw import presets  # populates the registry
-from repro.hw.presets import DEFAULT_BOARD, DEFAULT_CHIP
+from repro.hw.presets import DEFAULT_BOARD, DEFAULT_CHIP, preset_for_device_kind
 
 
 def resolve(spec: "Hardware | str | None") -> "Hardware | None":
@@ -45,5 +45,5 @@ def resolve(spec: "Hardware | str | None") -> "Hardware | None":
 __all__ = [
     "Hardware", "MemorySystem", "DramOrganization", "ClockDomain",
     "get", "register", "unregister", "names", "enable_jax", "resolve",
-    "DEFAULT_BOARD", "DEFAULT_CHIP", "SCHEMA_VERSION", "presets",
+    "preset_for_device_kind", "DEFAULT_BOARD", "DEFAULT_CHIP", "SCHEMA_VERSION", "presets",
 ]

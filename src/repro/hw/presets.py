@@ -24,6 +24,24 @@ from repro.search.envelope import ResourceEnvelope
 DEFAULT_BOARD = "stratix10_ddr4_1866"
 DEFAULT_CHIP = "tpu_v5e"
 
+#: ``jax.Device.device_kind`` -> preset name, for measurements taken on the
+#: chip the process runs on.  A kind missing here is an error, never a
+#: default: a prediction for the wrong memory system is not a prediction.
+DEVICE_KINDS = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+}
+
+
+def preset_for_device_kind(kind: str) -> str:
+    """The preset name of a jax device kind (``jax.devices()[0].device_kind``)."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no repro.hw preset for device kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_KINDS)}") from None
+
 # -- the paper's FPGA board (Stratix 10 GX devkit, one DDR4 DIMM) -----------
 
 _S10_CLOCK = ClockDomain(

@@ -9,7 +9,10 @@ Grid: ``(B, Hkv, n_s_blocks)`` with the cache-block dimension innermost and
 sequential; online-softmax state for the G grouped q heads lives in VMEM
 scratch.  The cache keeps the model's native (B, S, Hkv, D) layout so decode
 reads are contiguous (burst-coalesced-aligned class); positions ``>= kv_len``
-are masked via the scalar-prefetch length.
+are masked via the scalar-prefetch length.  The kernel reads the cache through
+its free (B, S, Hkv * D) view, so one grid step's block is a (block_s, D)
+tile: on the TPU that needs ``D`` to be a multiple of 128 (or ``Hkv == 1``)
+and ``block_s`` a multiple of 8.
 """
 from __future__ import annotations
 
@@ -21,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -43,8 +45,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)               # (bs, D)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap:
@@ -86,15 +88,13 @@ def decode_attention(
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_s=block_s,
                                n_s=n_s, softcap=softcap)
-    grid_spec = compat.prefetch_scalar_grid_spec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, Hkv, n_s),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, j, len_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, D),
-                         lambda b, h, j, len_ref: (b, j, h, 0)),
-            pl.BlockSpec((1, block_s, 1, D),
-                         lambda b, h, j, len_ref: (b, j, h, 0)),
+            pl.BlockSpec((1, block_s, D), lambda b, h, j, len_ref: (b, j, h)),
+            pl.BlockSpec((1, block_s, D), lambda b, h, j, len_ref: (b, j, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, h, j, len_ref: (b, h, 0, 0)),
@@ -108,7 +108,14 @@ def decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        # the whole cache streams once; q and o are one row per head
+        cost_estimate=pl.CostEstimate(
+            flops=4 * B * Hkv * G * S * D,
+            transcendentals=B * Hkv * G * S,
+            bytes_accessed=k_cache.dtype.itemsize * B * Hkv * D * (
+                2 * n_s * block_s) + 2 * q.dtype.itemsize * B * Hkv * G * D),
         interpret=interpret,
-    )(jnp.asarray(kv_len, jnp.int32).reshape(1), q, k_cache, v_cache)
+    )(jnp.asarray(kv_len, jnp.int32).reshape(1), q,
+      k_cache.reshape(B, S, Hkv * D), v_cache.reshape(B, S, Hkv * D))

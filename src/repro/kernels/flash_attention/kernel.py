@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -126,8 +125,14 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        # q and o move once; every q block re-reads its head's k and v
+        cost_estimate=pl.CostEstimate(
+            flops=4 * B * Hq * Sq * Skv * D,
+            transcendentals=B * Hq * Sq * Skv,
+            bytes_accessed=q.dtype.itemsize * B * Hq * D * (
+                2 * Sq + 2 * n_q * n_kv * block_kv)),
         interpret=interpret,
     )(q, k, v)

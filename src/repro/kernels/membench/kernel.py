@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _sum_kernel(*refs):
     o_ref = refs[-1]
@@ -38,6 +36,14 @@ def _sum_kernel(*refs):
 def _sum_kernel_prefetch(idx_ref, *refs):
     del idx_ref  # consumed by the index maps
     _sum_kernel(*refs)
+
+
+def _sum_cost(xs: list[jax.Array], n_out: int) -> pl.CostEstimate:
+    """What one sum kernel moves: ``n_out`` elements of every input read
+    and one output written — the bytes the compiled kernel's HLO reports."""
+    itemsize = xs[0].dtype.itemsize
+    return pl.CostEstimate(flops=(len(xs) - 1) * n_out, transcendentals=0,
+                           bytes_accessed=(len(xs) + 1) * n_out * itemsize)
 
 
 def aligned_sum(xs: list[jax.Array], *, block: int = 2048,
@@ -53,6 +59,7 @@ def aligned_sum(xs: list[jax.Array], *, block: int = 2048,
         in_specs=[spec] * len(xs),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n,), xs[0].dtype),
+        cost_estimate=_sum_cost(xs, n),
         interpret=interpret,
     )(*xs)
 
@@ -72,6 +79,7 @@ def strided_sum(xs: list[jax.Array], *, delta: int, block: int = 2048,
         in_specs=[in_spec] * len(xs),
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n_out,), xs[0].dtype),
+        cost_estimate=_sum_cost(xs, n_out),
         interpret=interpret,
     )(*xs)
 
@@ -81,7 +89,7 @@ def gather_sum(xs: list[jax.Array], idx: jax.Array, *, block: int = 2048,
     """z[i-th block] = sum of x_g[idx[i]-th block] — data-dependent block
     indirection via scalar prefetch."""
     n_blocks = idx.shape[0]
-    grid_spec = compat.prefetch_scalar_grid_spec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((block,), lambda i, idx_ref: (idx_ref[i],))
@@ -92,5 +100,6 @@ def gather_sum(xs: list[jax.Array], idx: jax.Array, *, block: int = 2048,
         _sum_kernel_prefetch,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks * block,), xs[0].dtype),
+        cost_estimate=_sum_cost(xs, n_blocks * block),
         interpret=interpret,
     )(idx.astype(jnp.int32), *xs)

@@ -8,7 +8,9 @@ while marching over sequence blocks:
 
 Grid: ``(B, n_w_blocks, n_s_blocks)`` (sequence innermost, sequential).
 Within a block the time loop runs over rows of the (block_s, block_w) VMEM
-tile — sequential in time but vectorized across the 128-lane channel tile,
+tile, loading and storing one row per step straight from the block refs
+(a value cannot be sliced at a traced index on the TPU) — sequential in time
+but vectorized across the 128-lane channel tile,
 which is how the TPU wants an elementwise recurrence (DESIGN.md S2
 hardware-adaptation note: no warp-scan analogue; lane-parallel time-marching
 instead).
@@ -22,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _rglru_kernel(a_ref, b_ref, o_ref, h_ref, *, block_s: int):
     j = pl.program_id(2)
@@ -32,17 +32,14 @@ def _rglru_kernel(a_ref, b_ref, o_ref, h_ref, *, block_s: int):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0].astype(jnp.float32)        # (block_s, block_w)
-    b = b_ref[0].astype(jnp.float32)
-
-    def body(t, carry):
-        h = carry
-        h = a[t] * h + b[t]
-        o_ref[0, t, :] = h.astype(o_ref.dtype)
+    def body(t, h):                          # h: (1, block_w) f32
+        row = (0, pl.ds(t, 1), slice(None))
+        h = (a_ref[row].astype(jnp.float32) * h
+             + b_ref[row].astype(jnp.float32))
+        o_ref[row] = h.astype(o_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_s, body, h_ref[...])
-    h_ref[...] = h
+    h_ref[...] = jax.lax.fori_loop(0, block_s, body, h_ref[...])
 
 
 def rglru_scan(
@@ -70,8 +67,11 @@ def rglru_scan(
         out_specs=pl.BlockSpec((1, block_s, block_w),
                                lambda b_, w, j: (b_, j, w)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * B * S * W, transcendentals=0,
+            bytes_accessed=3 * B * S * W * a.dtype.itemsize),
         interpret=interpret,
     )(a, b)

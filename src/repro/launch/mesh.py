@@ -10,8 +10,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh
 
-from repro.compat import AxisType, make_mesh as _make_mesh  # noqa: F401
-# AxisType is re-exported for callers that used the old shim location.
+from repro.compat import make_mesh as _make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -14,7 +14,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import attention as ATT
 from repro.models import layers as L
 from repro.models import mlp as MLP
@@ -217,9 +216,8 @@ def forward_hidden(params: Params, cfg: ModelConfig, x: jax.Array
             # loop: without it XLA hoists the first f32 upcast (the norm)
             # out of the loop and bulk-converts the whole (L, B, S, d)
             # residual stack to f32 — a 2x memory pessimization measured at
-            # +26 GB/chip on qwen2-7b.  compat supplies a differentiable
-            # barrier on jax versions lacking the primitive's grad rule.
-            x = compat.optimization_barrier(x)
+            # +26 GB/chip on qwen2-7b.
+            x = jax.lax.optimization_barrier(x)
             return _block_forward(p, cfg, kind, x)
         if cfg.remat:
             fn = jax.checkpoint(fn, prevent_cse=False)

@@ -28,8 +28,7 @@ Eqs. 1-10 the sweep engine scores.  The search runs in phases:
 
 Everything is budgeted: ``max_evals`` (default ``max(1024, n // 128)`` —
 under 1% of any large grid) caps scored rows across all phases, jax
-padding included, and the report carries the exact telemetry.  Without
-jax the descent phase is skipped and screen/refine still run.
+padding included, and the report carries the exact telemetry.
 """
 from __future__ import annotations
 
@@ -207,8 +206,8 @@ def _descend(log: _EvalLog, seeds: np.ndarray, objective: str,
     """Relax the wide numeric axes and descend all seed lanes at once.
 
     Returns (candidate grid ids near the continuous optima, phase record).
-    Gracefully returns no candidates when jax is unavailable, there is
-    nothing to relax, or no seeds survived screening.
+    Returns no candidates, with the reason under ``skipped``, when there
+    is nothing to relax or no seeds survived screening.
     """
     enum, lists = log.enum, log.lists
     relaxed = [a for a in _sweep._NUMERIC
@@ -216,21 +215,16 @@ def _descend(log: _EvalLog, seeds: np.ndarray, objective: str,
     record: dict[str, Any] = {"phase": "descend", "lanes": 0, "steps": 0,
                               "relaxed_axes": relaxed}
     if not len(seeds) or not relaxed or steps < 1:
-        record["skipped"] = "no seeds" if not len(seeds) else "no relaxed axes"
+        record["skipped"] = ("no seeds" if not len(seeds) else
+                             "no relaxed axes" if not relaxed else "steps < 1")
         return np.empty(0, dtype=np.int64), record
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
+    import jax
+    import jax.numpy as jnp
 
-        from repro.optim.adamw import (
-            OptimizerConfig,
-            adamw_init,
-            adamw_update,
-        )
-    except ImportError:                      # pragma: no cover — jax baked in
-        record["skipped"] = "jax unavailable"
-        return np.empty(0, dtype=np.int64), record
+    from repro import compat as _compat
+    from repro.optim.adamw import OptimizerConfig, adamw_init, adamw_update
+
+    _compat.enable_compilation_cache()
 
     S = len(seeds)
     codes = enum.codes(seeds)
@@ -323,7 +317,7 @@ def _descend(log: _EvalLog, seeds: np.ndarray, objective: str,
                           state_dtype="float32")
     kmax = {a: float(len(svals[a]) - 1) for a in relaxed}
 
-    with enable_x64():
+    with _compat.enable_x64():
         params = {a: jnp.asarray(inv[a][codes[a]], dtype=jnp.float64)
                   for a in relaxed}
         state = adamw_init(params, cfg)

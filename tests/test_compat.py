@@ -1,11 +1,4 @@
-"""Unit tests for the version-adaptive compat layer.
-
-The old/new jax namespaces are simulated by monkeypatching, so both branches
-of every shim are exercised regardless of which jax is installed.
-"""
-import dataclasses
-import types
-
+"""Unit tests for the shared jax helpers in ``repro.compat``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,62 +7,10 @@ import pytest
 from repro import compat
 
 
-@dataclasses.dataclass
-class _FakeParams:
-    dimension_semantics: tuple = ()
-
-
-class TestCompilerParams:
-    def test_real_jax_builds_params(self):
-        p = compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"))
-        assert p is not None
-        assert tuple(p.dimension_semantics) == ("parallel", "arbitrary")
-
-    def test_new_namespace(self, monkeypatch):
-        fake = types.SimpleNamespace(CompilerParams=_FakeParams)
-        monkeypatch.setattr(compat, "_pltpu", fake)
-        p = compat.tpu_compiler_params(dimension_semantics=("parallel",))
-        assert isinstance(p, _FakeParams)
-
-    def test_old_namespace(self, monkeypatch):
-        fake = types.SimpleNamespace(TPUCompilerParams=_FakeParams)
-        monkeypatch.setattr(compat, "_pltpu", fake)
-        p = compat.tpu_compiler_params(dimension_semantics=("parallel",))
-        assert isinstance(p, _FakeParams)
-
-    def test_unknown_fields_dropped(self, monkeypatch):
-        fake = types.SimpleNamespace(CompilerParams=_FakeParams)
-        monkeypatch.setattr(compat, "_pltpu", fake)
-        p = compat.tpu_compiler_params(dimension_semantics=("parallel",),
-                                       field_from_the_future=123)
-        assert isinstance(p, _FakeParams)
-        assert not hasattr(p, "field_from_the_future")
-
-
-class TestPrefetchGridSpec:
-    def test_missing_raises_not_implemented(self, monkeypatch):
-        monkeypatch.setattr(compat, "_pltpu", types.SimpleNamespace())
-        with pytest.raises(NotImplementedError):
-            compat.prefetch_scalar_grid_spec(num_scalar_prefetch=1, grid=(1,))
-
-
 class TestMakeMesh:
     def test_builds_mesh_on_installed_jax(self):
         mesh = compat.make_mesh((len(jax.devices()),), ("d",))
         assert tuple(mesh.axis_names) == ("d",)
-
-    def test_old_jax_branch_omits_axis_types(self, monkeypatch):
-        calls = {}
-
-        def fake_make_mesh(shape, axes, **kw):
-            calls.update(kw)
-            return "mesh"
-
-        monkeypatch.setattr(compat, "AxisType", None)
-        monkeypatch.setattr(compat.jax, "make_mesh", fake_make_mesh)
-        assert compat.make_mesh((2,), ("d",)) == "mesh"
-        assert "axis_types" not in calls
 
     def test_new_jax_branch_passes_axis_types(self, monkeypatch):
         calls = {}
@@ -101,37 +42,34 @@ class TestDefaultInterpret:
         assert compat.default_interpret(backend="gpu") is True
 
 
+class TestEnableX64:
+    def test_scopes_64_bit_types(self):
+        assert jnp.asarray(1.0).dtype == jnp.float32
+        with compat.enable_x64():
+            assert jnp.asarray(1.0).dtype == jnp.float64
+            assert jnp.asarray(1).dtype == jnp.int64
+        assert jnp.asarray(1.0).dtype == jnp.float32
+
+    def test_jit_traces_under_the_scope(self):
+        with compat.enable_x64():
+            y = jax.jit(lambda x: x * 3)(np.float64(1) / 3)
+        assert y.dtype == jnp.float64 and float(y) == (1 / 3) * 3
+
+
 class TestOptimizationBarrier:
+    """The model stack calls ``jax.lax.optimization_barrier`` directly: the
+    installed jax differentiates it natively."""
+
     def test_identity_forward(self):
         x = jnp.arange(6.0).reshape(2, 3)
         np.testing.assert_array_equal(
-            np.asarray(compat.optimization_barrier(x)), np.asarray(x))
+            np.asarray(jax.lax.optimization_barrier(x)), np.asarray(x))
 
     def test_differentiates_on_this_jax(self):
-        g = jax.grad(lambda x: (compat.optimization_barrier(x) ** 2).sum())(
-            jnp.ones(4))
+        g = jax.grad(
+            lambda x: (jax.lax.optimization_barrier(x) ** 2).sum())(
+                jnp.ones(4))
         np.testing.assert_allclose(np.asarray(g), 2.0 * np.ones(4))
-
-    def test_custom_jvp_fallback_path(self, monkeypatch):
-        """Force the no-native-rule branch and check grad still works."""
-        monkeypatch.setattr(compat, "barrier_is_differentiable", lambda: False)
-        g = jax.grad(lambda x: (compat.optimization_barrier(x) * 3.0).sum())(
-            jnp.ones(3))
-        np.testing.assert_allclose(np.asarray(g), 3.0 * np.ones(3))
-
-    def test_under_checkpoint_and_scan(self, monkeypatch):
-        monkeypatch.setattr(compat, "barrier_is_differentiable", lambda: False)
-
-        def f(x):
-            def body(c, _):
-                return compat.optimization_barrier(c) * 1.5, None
-            body = jax.checkpoint(body, prevent_cse=False)
-            y, _ = jax.lax.scan(body, x, None, length=3)
-            return y.sum()
-
-        g = jax.grad(f)(jnp.ones(2))
-        np.testing.assert_allclose(np.asarray(g), 1.5 ** 3 * np.ones(2),
-                                   rtol=1e-6)
 
 
 class TestAutotuneFailureHandling:

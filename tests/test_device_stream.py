@@ -3,6 +3,7 @@ host merge/state_dict protocol under arbitrary chunk partitions across all
 three backends, capacity-overflow fallback, per-stage profile attribution,
 and the persistent compilation cache."""
 import math
+import pathlib
 import random
 
 import numpy as np
@@ -162,6 +163,9 @@ class TestPartitionProperty:
     def test_degenerate_partitions(self):
         _check_partition([0, N])                    # single range
         _check_partition([0, *range(100, N, 100), N])   # every chunk alone
+        # a multiply-add XLA:CPU contracted into an FMA once put the
+        # merged m2 one ulp off on this partition
+        _check_partition([0, 400, 600, 864])
 
 
 class TestOverflowFallback:
@@ -184,7 +188,9 @@ class TestOverflowFallback:
         monkeypatch.setattr(dev, "FRONT_CAP", 2)
         st = Session(backend="jax-jit").sweep(Space.grid(**GRID),
                                               chunk_size=100, profile=True)
-        assert st.summary()["profile"]["path"] == "host-stream"
+        prof = st.summary()["profile"]
+        assert prof["path"] == "host-stream"
+        assert prof["host_reason"].startswith("device fold overflow")
         np.testing.assert_array_equal(
             np.sort(np.asarray(st.point_ids)[st.pareto()]),
             np.asarray(materialized.pareto()))
@@ -193,14 +199,16 @@ class TestOverflowFallback:
 
 class TestEligibility:
     def test_non_jax_backend_is_ineligible(self):
-        assert dev.DeviceSweep.build(_plan("numpy-batch", 100)) is None
+        with pytest.raises(dev.DeviceIneligible, match="backend"):
+            dev.DeviceSweep.build(_plan("numpy-batch", 100))
 
     @multi_device
     def test_constrained_plan_is_ineligible(self):
         plan = Session(backend="jax-jit").plan(
             Space.grid(**GRID), chunk_size=100,
             constraints=(lambda cols: np.asarray(cols["n_ga"]) > 1,))
-        assert dev.DeviceSweep.build(plan) is None
+        with pytest.raises(dev.DeviceIneligible, match="constrained"):
+            dev.DeviceSweep.build(plan)
 
     @multi_device
     def test_custom_reducer_is_unsupported(self):
@@ -238,5 +246,25 @@ class TestProfileAndCache:
 
         first = compat.enable_compilation_cache()
         assert compat.enable_compilation_cache() == first
-        if first:       # directory really configured, never raises
-            assert jax.config.jax_compilation_cache_dir
+        assert jax.config.jax_compilation_cache_dir == first
+
+    @pytest.mark.parametrize("env", [None, "set"])
+    def test_compilation_cache_directory(self, env, monkeypatch, tmp_path):
+        """$JAX_COMPILATION_CACHE_DIR wins; otherwise one fixed directory
+        at the root of the checkout, never the home directory."""
+        from repro import compat
+
+        seen = {}
+        monkeypatch.setattr(compat, "_CACHE_DIR", None)
+        monkeypatch.setattr(compat.jax.config, "update",
+                            lambda k, v: seen.__setitem__(k, v))
+        if env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            want = str(tmp_path)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(compat.DEFAULT_CACHE_DIR)
+            assert compat.DEFAULT_CACHE_DIR.parent == \
+                pathlib.Path(compat.__file__).resolve().parents[2]
+        assert compat.enable_compilation_cache() == want
+        assert seen["jax_compilation_cache_dir"] == want

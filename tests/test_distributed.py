@@ -268,6 +268,20 @@ class TestExecutorErrorMatrix:
             Session().sweep(Space.random(4, seed=0, n_ga=(1, 8)),
                             executor="processes")
 
+    def test_processes_on_jax_jit_refused_on_a_tpu_host(self, monkeypatch):
+        """Each process worker would open the chip, which serves one
+        process at a time."""
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="one process at a time"):
+            Session(backend="jax-jit").sweep(Space.grid(n_ga=[1]),
+                                             executor="processes")
+        # the host backends' process pools open no device
+        rep = Session().sweep(Space.grid(n_ga=[1, 2]), chunk_size=1,
+                              executor="processes", workers=1)
+        assert rep.n_points == 2
+
     @pytest.mark.parametrize("backend", ["numpy-batch", "scalar", "jax-jit"])
     def test_processes_accepts_every_backend_plan(self, backend):
         """executor='processes' is legal on all three backends (the plan

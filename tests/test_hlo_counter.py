@@ -172,3 +172,31 @@ class TestScaled:
         a.bytes_by_class["strided"] = 10.0
         s = a.scaled(3.0)
         assert dict(s.bytes_by_class) == {"strided": 30.0}
+
+
+_PALLAS_MODULE = """HloModule kernel
+
+ENTRY %main (p0: f32[1024], p1: f32[1024]) -> f32[1024] {
+  %p0 = f32[1024]{0} parameter(0)
+  %p1 = f32[1024]{0} parameter(1)
+  ROOT %k = f32[1024]{0:T(1024)} custom-call(%p0, %p1), custom_call_target="tpu_custom_call", backend_config={"custom_call_config":{"body":"AAAA"%COST%,"needs_layout_passes":true}}
+}
+"""
+
+
+class TestCompiledPallasKernel:
+    """On the TPU a Pallas kernel is one opaque custom call; its
+    pallas_call cost estimate carries the traffic it moves."""
+
+    def test_cost_estimate_is_counted(self):
+        cost = (',"cost_estimate":{"flops":"1024","transcendentals":"8",'
+                '"bytes_accessed":"12288","remote_bytes_transferred":"0"}')
+        hc = HC.analyze(_PALLAS_MODULE.replace("%COST%", cost))
+        assert hc.bytes_by_class == {"stream": 12288.0}
+        assert hc.flops == 1024.0 and hc.transcendentals == 8.0
+        assert not hc.warnings
+
+    def test_without_estimate_it_is_uncounted_and_says_so(self):
+        hc = HC.analyze(_PALLAS_MODULE.replace("%COST%", ""))
+        assert hc.total_bytes == 0.0
+        assert any("uncounted" in w for w in hc.warnings)

@@ -410,3 +410,46 @@ class TestPytree:
 
         got = float(stream_time(spec, 1e9))
         assert got == pytest.approx(1e9 / (1228e9 * 0.92), rel=1e-6)
+
+
+class TestDeviceKindPresets:
+    @pytest.mark.parametrize("kind,preset", [("TPU v5 lite", "tpu_v5e"),
+                                             ("TPU v4", "tpu_v4")])
+    def test_known_kinds(self, kind, preset):
+        assert hw.preset_for_device_kind(kind) == preset
+        assert hw.get(hw.preset_for_device_kind(kind)).name == preset
+
+    def test_unknown_kind_is_an_error(self):
+        with pytest.raises(ValueError, match="no repro.hw preset"):
+            hw.preset_for_device_kind("TPU v9000")
+
+    def _on_fake_tpu(self, monkeypatch, kind):
+        import jax
+
+        seen = {}
+
+        def fake_validate(cases, **kw):
+            seen.update(kw)
+            return V.ValidationReport([], [], kw["base"], float("nan"))
+
+        class _Dev:
+            device_kind = kind
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+        monkeypatch.setattr(V, "_validate", fake_validate)
+        return seen
+
+    def test_validate_on_tpu_predicts_with_the_chip_preset(self,
+                                                           monkeypatch):
+        seen = self._on_fake_tpu(monkeypatch, "TPU v5 lite")
+        Session().validate([])
+        assert seen["base"] == hw.get("tpu_v5e").dram_params()
+        # an explicit hardware spec still wins
+        Session().with_hardware(hw.get("tpu_v4")).validate([])
+        assert seen["base"] == hw.get("tpu_v4").dram_params()
+
+    def test_validate_on_unknown_chip_raises(self, monkeypatch):
+        self._on_fake_tpu(monkeypatch, "TPU v9000")
+        with pytest.raises(ValueError, match="no repro.hw preset"):
+            Session().validate([])

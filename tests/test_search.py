@@ -357,3 +357,27 @@ def test_optimize_rejects_bad_objective():
         Session().optimize(GRID, objective="latency")
     with pytest.raises(ValueError, match="one column or a pair"):
         Session().optimize(GRID, objective=("t_exe", "resource", "t_ovh"))
+
+
+@pytest.mark.parametrize("backend", ["numpy-batch", "jax-jit"])
+def test_optimize_descent_phase_runs(backend):
+    """The relaxed descent really runs on every backend: lanes descended
+    for every step, its evaluations billed, its loss recorded."""
+    rep = Session(backend=backend).optimize(BIG, max_evals=2000, seed=0)
+    (d,) = [t for t in rep.trajectory if t["phase"] == "descend"]
+    assert "skipped" not in d
+    assert d["steps"] == 16 and d["lanes"] > 0
+    assert rep.n_relaxed_evals == d["lanes"] * d["steps"]
+    assert np.isfinite(d["loss_first"]) and np.isfinite(d["loss_last"])
+
+
+def test_optimize_descent_failure_propagates(monkeypatch):
+    """A failing descent is an error, never a silently skipped phase."""
+    from repro.optim import adamw
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("descent step failed")
+
+    monkeypatch.setattr(adamw, "adamw_update", boom)
+    with pytest.raises(RuntimeError, match="descent step failed"):
+        Session().optimize(BIG, max_evals=2000, seed=0)

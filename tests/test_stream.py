@@ -277,10 +277,11 @@ class TestMultiDevice:
         code = textwrap.dedent("""
             import json
             import numpy as np
-            from repro import Session, Space, compat
+            import jax
+            from repro import Session, Space
             from repro.core import LsuType
 
-            assert compat.local_device_count() == 4
+            assert jax.local_device_count() == 4
             sp = Space.grid(
                 lsu_type=[LsuType.BC_ALIGNED, LsuType.BC_WRITE_ACK,
                           LsuType.ATOMIC_PIPELINED],
@@ -291,7 +292,11 @@ class TestMultiDevice:
             front_mat = np.asarray(mat.pareto()).tolist()
             front_st = np.sort(
                 np.asarray(st.point_ids)[st.pareto()]).tolist()
+            prof = Session(backend="jax-jit").sweep(
+                sp, chunk_size=50, profile=True).profile
             print(json.dumps({
+                "path": prof["path"], "devices": prof["devices"],
+                "host_reason": prof["host_reason"],
                 "front_mat": front_mat, "front_st": front_st,
                 "topk_equal": st.top_k(5) == mat.top_k(5),
                 "summary_equal": st.summary()["t_exe_min_ms"]
@@ -309,3 +314,6 @@ class TestMultiDevice:
         res = json.loads(out.stdout.strip().splitlines()[-1])
         assert res["front_st"] == res["front_mat"]
         assert res["topk_equal"] and res["summary_equal"]
+        # the chunks really spread over all four devices
+        assert res["path"] == "host-stream" and res["devices"] == 4
+        assert res["host_reason"].startswith("4 local devices")
