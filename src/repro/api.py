@@ -43,8 +43,8 @@ bit-comparable with NumPy (tests/test_api.py).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
-from time import perf_counter as _perf_counter
 from typing import Any, Callable, Iterable, Mapping, Sequence
 
 import numpy as np
@@ -57,6 +57,7 @@ from repro.core.fpga import BspParams, DramParams
 from repro.core.stream import SweepPlan
 from repro.core.hbm import TpuParams
 from repro.core.lsu import Lsu, LsuType, make_global_access
+from repro.core.spans import count, span
 from repro.hw import DEFAULT_BOARD, DEFAULT_CHIP, Hardware
 from repro.hw import get as _hw_get
 from repro.hw import preset_for_device_kind as _hw_preset_for
@@ -76,6 +77,10 @@ __all__ = [
 #: Supported Session.sweep executors: the in-process chunk pipeline and the
 #: coordinator/worker process pool (repro.core.distributed).
 EXECUTORS = ("threads", "processes")
+
+#: Per-process id of each ``Session.sweep``, an arg of its ``repro.sweep``
+#: span.
+_SWEEP_IDS = itertools.count(1)
 
 #: LSU types whose stride axis is live (mirrors apps.microbench semantics).
 _STRIDE_TYPES = (LsuType.BC_ALIGNED, LsuType.BC_NON_ALIGNED, LsuType.BC_CACHE)
@@ -564,10 +569,23 @@ class SweepReport(_sweep.SweepResult, Report):
         return out
 
 
+def _derive_legacy_keys(prof: dict) -> None:
+    """The profile keys that predate the spans, as sums of span keys (see
+    ``Session.sweep``)."""
+    def total(*keys):
+        return sum(prof.get(k, 0.0) for k in keys)
+
+    if prof.get("path") in ("host-stream", "device-fused"):
+        prof["enumerate_s"] = total("decode_s")
+        prof["reduce_s"] = total("fold_s")
+        prof["score_s"] = total("pack_s", "dispatch_s")
+    if prof.get("path") != "distributed":
+        prof["transfer_s"] = total("upload_s", "pull_s")
+
+
 def _stream_report(outcome, tables: Mapping[str, list], *,
                    backend: str,
-                   n_candidates: int | None = None,
-                   profile: Mapping[str, Any] | None = None) -> SweepReport:
+                   n_candidates: int | None = None) -> SweepReport:
     """Fold a :class:`repro.core.stream.StreamOutcome` into a SweepReport.
 
     Survivors = union of the Pareto reducer's front and the top-k rows,
@@ -632,7 +650,7 @@ def _stream_report(outcome, tables: Mapping[str, list], *,
         topk_idx=(np.searchsorted(ids, topk.ids)
                   if topk is not None else None),
         topk_key=topk.key if topk is not None else None,
-        reducers=outcome.reducers, profile=profile)
+        reducers=outcome.reducers)
 
 
 class AutotuneReport(Report):
@@ -965,13 +983,35 @@ class Session:
         the report's ``summary()`` carries the feasible/candidate split.
         Results are bit-equal to post-filtering the unconstrained sweep.
 
-        ``profile=True`` records a per-stage wall-time breakdown
-        (``enumerate``/``transfer``/``score``/``reduce`` seconds, plus the
-        pipeline path taken) on ``report.profile`` and in
-        ``report.summary()["profile"]`` — the numbers that make a
-        points/sec regression attributable to a stage.  Profiling
-        serializes the chunk pipeline (per-stage walls need sync points),
-        so profiled throughput is a lower bound on the unprofiled run.
+        Every sweep runs inside program spans (:mod:`repro.core.spans`):
+        ``repro.sweep`` (its trace args: a per-process sweep ``id``,
+        ``path``, ``points``, ``chunk`` and the counters below) and, as
+        its children, ``repro.sweep.plan`` then, on the fused device path,
+        ``repro.sweep.open`` / ``.compile`` (the first step call, inside
+        ``.dispatch``) / ``.dispatch`` / ``.wait`` / ``.close``; on the
+        host stream ``repro.chunk.mask`` / ``.decode`` / ``.pack`` /
+        ``.upload`` / ``.dispatch`` / ``.pull`` / ``.fold`` per chunk,
+        then ``repro.sweep.close``; materialized, ``repro.sweep.enumerate``
+        / ``.mask`` / ``.score``.  A ``jax.profiler`` trace holds them on
+        the device's clock.
+
+        ``profile=True`` returns their sums on ``report.profile`` (and in
+        ``report.summary()["profile"]``): ``<span>_s`` host seconds per
+        span name (``plan_s``, ``open_s``, ``pull_s``, ...), the counters
+        ``chunks``, ``lanes`` (points scored, padding included),
+        ``feasible`` (points kept by the constraints), ``uploads`` /
+        ``upload_bytes`` / ``pulls`` / ``pull_bytes`` (arrays moved each
+        way) and ``device_calls`` (jitted calls, plus each carry leaf made
+        on the device), and ``path``, ``devices`` and ``host_reason``.
+        The older keys are sums of spans: ``enumerate_s`` = ``decode_s``
+        (materialized: the enumeration), ``reduce_s`` = ``fold_s``,
+        ``transfer_s`` = ``upload_s + pull_s``, ``score_s`` = ``pack_s +
+        dispatch_s`` on the host stream and ``dispatch_s`` fused,
+        ``compile_s`` the first fused step's call, ``total_s`` the whole
+        sweep.  Profiling adds no synchronization: a span is host time as
+        the host experiences it (a pull includes the device's wait), and
+        device time is the trace's.  A profiled sweep runs the same
+        program as an unprofiled one.
         """
         space = self._as_space(space, axes)
         if constraints:
@@ -1014,18 +1054,31 @@ class Session:
         if chunk is None and (reducers is not None or workers is not None
                               or executor == "processes"):
             chunk = DEFAULT_CHUNK      # these options all imply streaming
-        if chunk is not None:
-            if not space.is_grid:
-                raise TypeError("streaming sweeps need a grid space; "
-                                "Space.random materializes its draws")
-            return self._sweep_stream(space, int(chunk), reducers, workers,
-                                      executor, constraints, profile)
-        prof = {"path": "materialized"} if profile else None
-        t0 = _perf_counter() if profile else 0.0
-        points, n, cats = space.points(dram=self.dram, bsp=self.bsp,
-                                       constraints=constraints)
-        if profile:
-            prof["enumerate_s"] = _perf_counter() - t0
+        if chunk is not None and not space.is_grid:
+            raise TypeError("streaming sweeps need a grid space; "
+                            "Space.random materializes its draws")
+        prof: dict = {}
+        with span("sweep", prof, id=next(_SWEEP_IDS)) as sp:
+            if chunk is not None:
+                report = self._sweep_stream(space, int(chunk), reducers,
+                                            workers, executor, constraints,
+                                            prof)
+            else:
+                report = self._sweep_materialized(space, constraints, prof)
+            _derive_legacy_keys(prof)
+            sp.annotate(points=(report.n_points if report.n_candidates is None
+                                else report.n_candidates),
+                        **{k: v for k, v in prof.items()
+                           if not k.endswith("_s")})
+        prof["total_s"] = prof.pop("sweep_s")
+        return dataclasses.replace(report, profile=prof if profile else None)
+
+    def _sweep_materialized(self, space: "Space", constraints: tuple,
+                            prof: dict) -> SweepReport:
+        prof["path"] = "materialized"
+        with span("sweep.enumerate", prof):
+            points, n, cats = space.points(dram=self.dram, bsp=self.bsp,
+                                           constraints=constraints)
         n_candidates = None
         if constraints and space.is_grid:
             # Mask the enumerated grid before anything is scored; scoring
@@ -1036,23 +1089,26 @@ class Session:
                 feasibility_mask,
             )
 
-            mask = feasibility_mask(
-                constraints, columns_from_parts(points, cats, n))
-            n_candidates = n
-            points = {k: np.asarray(v)[mask] for k, v in points.items()}
-            cats = {k: (t, np.asarray(idx)[mask])
-                    for k, (t, idx) in cats.items()}
-            n = int(np.count_nonzero(mask))
+            with span("sweep.mask", prof):
+                mask = feasibility_mask(
+                    constraints, columns_from_parts(points, cats, n))
+                n_candidates = n
+                points = {k: np.asarray(v)[mask] for k, v in points.items()}
+                cats = {k: (t, np.asarray(idx)[mask])
+                        for k, (t, idx) in cats.items()}
+                n = int(np.count_nonzero(mask))
             if n == 0:
                 return self._empty_report(cats, n_candidates)
-        t0 = _perf_counter() if profile else 0.0
-        if self.backend == "scalar":
-            result = self._sweep_scalar(points, n, cats)
-        else:
-            result = _sweep._build(points, n, cats,
-                                   estimator=self._estimator())
-        if profile:
-            prof["score_s"] = _perf_counter() - t0
+        count(prof, "chunks")
+        count(prof, "lanes", n)
+        count(prof, "feasible", n)
+        with span("sweep.score", prof):
+            if self.backend == "scalar":
+                result = self._sweep_scalar(points, n, cats)
+            else:
+                result = _sweep._build(points, n, cats,
+                                       estimator=self._estimator(prof),
+                                       prof=prof)
         est = result.estimate
         if self.calibration_factor != 1.0:
             # The session factor belongs to the *session's* hardware; points
@@ -1068,7 +1124,7 @@ class Session:
                 t_ovh=np.asarray(est.t_ovh) * c)
         return SweepReport(points=result.points, estimate=est,
                            resource=result.resource, backend=self.backend,
-                           n_candidates=n_candidates, profile=prof)
+                           n_candidates=n_candidates)
 
     def _empty_report(self, cats: dict,
                       n_candidates: int | None) -> SweepReport:
@@ -1095,9 +1151,8 @@ class Session:
     # -- streaming sweep ----------------------------------------------------
 
     def _sweep_stream(self, space: "Space", chunk_size: int, reducers,
-                      workers: int | None, executor: str = "threads",
-                      constraints: tuple = (),
-                      profile: bool = False) -> SweepReport:
+                      workers: int | None, executor: str,
+                      constraints: tuple, prof: dict) -> SweepReport:
         """Chunked, reducer-folded evaluation of a grid space.
 
         A thin consumer of :class:`SweepPlan`: the plan carries the
@@ -1120,36 +1175,37 @@ class Session:
 
         from repro.core import stream as _stream
 
-        plan = self.plan(space, chunk_size=chunk_size,
-                         constraints=constraints)
-        if reducers is None:
-            reducers = _stream.default_reducers()
-        else:
-            # Reducers accumulate state in place; folding a second sweep
-            # into instances that already hold the first one's points would
-            # silently mix the spaces, so each sweep folds into copies.
-            reducers = tuple(copy.deepcopy(r) for r in reducers)
-        if not any(isinstance(r, _stream.StatsReducer) for r in reducers):
-            reducers += (_stream.StatsReducer(),)
+        with span("sweep.plan", prof):
+            plan = self.plan(space, chunk_size=chunk_size,
+                             constraints=constraints)
+            prof["chunk"] = plan.chunk_size
+            if reducers is None:
+                reducers = _stream.default_reducers()
+            else:
+                # Reducers accumulate state in place; folding a second
+                # sweep into instances that already hold the first one's
+                # points would silently mix the spaces, so each sweep
+                # folds into copies.
+                reducers = tuple(copy.deepcopy(r) for r in reducers)
+            if not any(isinstance(r, _stream.StatsReducer)
+                       for r in reducers):
+                reducers += (_stream.StatsReducer(),)
 
-        prof: dict | None = {} if profile else None
-        t0 = _perf_counter() if profile else 0.0
         outcome = None
         if executor == "processes":
             from repro.core import distributed as _dist
 
+            # per-stage spans live in the worker processes
+            prof["path"] = "distributed"
             outcome = _dist.run_distributed(plan, reducers, workers=workers)
-            if prof is not None:
-                # per-stage walls live in the worker processes; only the
-                # end-to-end wall is observable here
-                prof["path"] = "distributed"
         else:
             if self.backend == "jax-jit" and not plan.constraints:
                 from repro.core import device_stream as _dev
 
+                # a device attempt that overflowed leaves its spans and
+                # counters in the profile: its time was spent
                 outcome, why = _dev.try_outcome(plan, reducers, profile=prof)
-                if outcome is None and prof is not None:
-                    prof.clear()     # drop a failed device attempt's stages
+                if outcome is None:
                     prof["host_reason"] = why
             if outcome is None:
                 w = workers
@@ -1157,23 +1213,16 @@ class Session:
                     import os
 
                     w = min(4, os.cpu_count() or 1)
-                if prof is not None:
-                    prof["path"] = "host-stream"
-                    # stage walls need a serial pipeline; see sweep(profile=)
-                    outcome = _stream.run_stream(
-                        plan.n, plan.chunk_size,
-                        plan.evaluator(stage_times=prof), reducers,
-                        stage_times=prof)
-                else:
-                    outcome = _stream.run_stream(
-                        plan.n, plan.chunk_size, plan.evaluator(), reducers,
-                        workers=w if self.backend == "numpy-batch" else None)
-        if prof is not None:
-            prof["total_s"] = _perf_counter() - t0
-        return _stream_report(
-            outcome, plan.tables(), backend=self.backend,
-            n_candidates=plan.n if plan.constraints else None,
-            profile=prof)
+                prof["path"] = "host-stream"
+                outcome = _stream.run_stream(
+                    plan.n, plan.chunk_size,
+                    plan.evaluator(stage_times=prof), reducers,
+                    workers=w if self.backend == "numpy-batch" else None,
+                    stage_times=prof)
+        with span("sweep.close", prof):
+            return _stream_report(
+                outcome, plan.tables(), backend=self.backend,
+                n_candidates=plan.n if plan.constraints else None)
 
     # -- optimizer-driven search -------------------------------------------
 
@@ -1217,10 +1266,17 @@ class Session:
 
     # -- backend plumbing ---------------------------------------------------
 
-    def _estimator(self) -> Callable[[_mb.GroupBatch], _mb.BatchEstimate]:
+    def _estimator(self, prof: dict | None = None,
+                   ) -> Callable[[_mb.GroupBatch], _mb.BatchEstimate]:
+        """The backend's batch estimator; ``prof`` receives its spans and
+        counters (see :func:`_jax_estimate_batch`)."""
         if self.backend == "jax-jit":
-            return _jax_estimate_batch
-        return _mb.estimate_batch
+            return lambda b: _jax_estimate_batch(b, stage_times=prof)
+
+        def estimate(b):
+            with span("chunk.dispatch", prof):
+                return _mb.estimate_batch(b)
+        return estimate
 
     # -- the rest of the pipeline ------------------------------------------
 
@@ -1515,14 +1571,16 @@ def _jax_estimator_fn():
         _mb.enable_jax()
         _compat.enable_compilation_cache()
 
-        def _run(b):
-            est = _mb.estimate_batch(b, xp=jnp)
+        def estimate_core(b):
+            with jax.named_scope("score"):
+                est = _mb.estimate_batch(b, xp=jnp)
             return {"t_exe": est.t_exe, "t_ideal": est.t_ideal,
                     "t_ovh": est.t_ovh, "bound_ratio": est.bound_ratio,
                     "memory_bound": est.memory_bound,
                     "total_bytes": est.total_bytes, "n_lsu": est.n_lsu,
                     "groups": est.groups}
-        _JAX_FN = jax.jit(_run)
+        # the trace's XLA Modules line names it jit_estimate_core
+        _JAX_FN = jax.jit(estimate_core)
     return _JAX_FN
 
 
@@ -1539,10 +1597,14 @@ def _jax_estimate_batch(batch: _mb.GroupBatch,
     is compiled once per input shape, so fixed-shape streaming chunks reuse
     a single executable for the whole sweep.
 
-    With ``stage_times``, the host->device upload and the device->host
-    result pull are accumulated into ``stage_times["transfer_s"]`` (the
-    compute between them lands in the caller's score bucket), and
-    ``stage_times["devices"]`` records how many devices the batch spans.
+    Three spans (:mod:`repro.core.spans`) go to ``stage_times`` when it
+    is a dict: ``chunk.upload`` (the batch's arrays to the device),
+    ``chunk.dispatch`` (the jitted call, which returns once it is
+    enqueued) and ``chunk.pull`` (the columns back: host time blocked on
+    them, the device's wait included).  So do the counters ``uploads``,
+    ``upload_bytes``, ``pulls``, ``pull_bytes`` and ``device_calls``, and
+    ``devices``, the number of devices the batch spans.  Nothing waits
+    for the device beyond what the pull itself needs.
     """
     import jax
     import jax.numpy as jnp
@@ -1550,28 +1612,30 @@ def _jax_estimate_batch(batch: _mb.GroupBatch,
     from repro import compat as _compat
 
     fn = _jax_estimator_fn()
-    timed = stage_times is not None
+    prof = stage_times
     with _compat.enable_x64():
-        t0 = _perf_counter() if timed else 0.0
-        jb = _mb.GroupBatch(**{
-            f.name: (batch.n_kernels if f.name == "n_kernels"
-                     else jnp.asarray(getattr(batch, f.name)))
-            for f in dataclasses.fields(_mb.GroupBatch)})
-        if sharding is not None:
-            jb = jax.device_put(jb, sharding)
-        if timed:
-            jax.block_until_ready(jb.count)
-            stage_times["transfer_s"] = (stage_times.get("transfer_s", 0.0)
-                                         + _perf_counter() - t0)
-            # how many devices each chunk's arrays really span
-            stage_times["devices"] = len(jb.count.sharding.device_set)
-        dev = fn(jb)
-        if timed:
-            jax.block_until_ready(dev)
-            t0 = _perf_counter()
-        out = jax.tree_util.tree_map(np.asarray, dev)
-        if timed:
-            stage_times["transfer_s"] += _perf_counter() - t0
+        with span("chunk.upload", prof):
+            jb = _mb.GroupBatch(**{
+                f.name: (batch.n_kernels if f.name == "n_kernels"
+                         else jnp.asarray(getattr(batch, f.name)))
+                for f in dataclasses.fields(_mb.GroupBatch)})
+            if sharding is not None:
+                jb = jax.device_put(jb, sharding)
+        with span("chunk.dispatch", prof):
+            dev = fn(jb)
+        with span("chunk.pull", prof):
+            out = jax.tree_util.tree_map(np.asarray, dev)
+    if prof is not None:
+        sent = jax.tree_util.tree_leaves(jb)
+        got = jax.tree_util.tree_leaves(out)
+        for key, n in (("uploads", len(sent)),
+                       ("upload_bytes", sum(x.nbytes for x in sent)),
+                       ("device_calls", 1),
+                       ("pulls", len(got)),
+                       ("pull_bytes", sum(x.nbytes for x in got))):
+            count(prof, key, n)
+        # how many devices each chunk's arrays really span
+        prof["devices"] = len(jb.count.sharding.device_set)
     groups = out.pop("groups")
     return _mb.BatchEstimate(**out, groups=groups)
 

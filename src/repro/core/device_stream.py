@@ -63,6 +63,7 @@ import numpy as np
 from repro.core import model_batch as _mb
 from repro.core import stream as _stream
 from repro.core import sweep as _sweep
+from repro.core.spans import count, span
 
 #: Pareto front capacity of the fixed-shape device carry.  A front larger
 #: than this overflows to the host path (flagged, never truncated).
@@ -157,20 +158,8 @@ def _lexmin(live, keys):
     return jnp.argmax(cand), jnp.any(cand)
 
 
-def _score_ids(tables, ids):
-    """The in-jit twin of ``plan.evaluator()``'s ``score_ids``.
-
-    Gathers axis values from the device tables for an arbitrary id
-    vector, replicates :func:`sweep._score`'s two-group construction and
-    hardware resolution, and runs :func:`model_batch.estimate_batch` with
-    ``xp=jnp`` (``paired_kernel`` replaces each scatter-based segment sum
-    with its bit-equal two-term split add) — so every column is bit-equal
-    to the host evaluator's for the same ids.
-    """
-    import jax.numpy as jnp
-
-    chunk = ids.shape[0]
-    iota = jnp.arange(chunk, dtype=jnp.int64)
+def _decode(tables, ids):
+    """Axis codes and numeric axis values of ``ids``, from the tables."""
     strides, mods = tables["strides"], tables["mods"]
     # decode in the tables' integer width: int32 whenever the grid fits it
     # (see DeviceSweep.build), since 64-bit division is emulated on the TPU
@@ -178,6 +167,24 @@ def _score_ids(tables, ids):
     code = {name: (dec // strides[i]) % mods[i]
             for i, name in enumerate(_sweep.AXES)}
     num = {k: tables["num_" + k][code[k]] for k in _NUM_AXES}
+    return code, num
+
+
+def _score_ids(tables, ids, code, num):
+    """The in-jit twin of ``plan.evaluator()``'s ``score_ids``.
+
+    Takes an arbitrary id vector with its axis codes and values
+    (:func:`_decode`), replicates :func:`sweep._score`'s two-group
+    construction and hardware resolution, and runs
+    :func:`model_batch.estimate_batch` with ``xp=jnp`` (``paired_kernel``
+    replaces each scatter-based segment sum with its bit-equal two-term
+    split add) — so every column is bit-equal to the host evaluator's for
+    the same ids.
+    """
+    import jax.numpy as jnp
+
+    chunk = ids.shape[0]
+    iota = jnp.arange(chunk, dtype=jnp.int64)
 
     type_codes = tables["lsu_code"][code["lsu_type"]]
     own = tables["hw_own"][code["hardware"]]
@@ -261,14 +268,19 @@ def _score_chunk(tables, start, chunk: int):
     The padded-tail rule reproduces :func:`stream._chunk_ids` exactly:
     ``ids = min(start + iota, n - 1)``.  Returns ``(cols, valid, mask)``.
     """
+    import jax
     import jax.numpy as jnp
 
     n = tables["n"]
-    iota = jnp.arange(chunk, dtype=jnp.int64)
-    ids = jnp.minimum(start + iota, n - 1)
-    valid = jnp.minimum(jnp.int64(chunk), n - start)
-    mask = iota < valid
-    return _score_ids(tables, ids), valid, mask
+    with jax.named_scope("decode"):
+        iota = jnp.arange(chunk, dtype=jnp.int64)
+        ids = jnp.minimum(start + iota, n - 1)
+        valid = jnp.minimum(jnp.int64(chunk), n - start)
+        mask = iota < valid
+        code, num = _decode(tables, ids)
+    with jax.named_scope("score"):
+        cols = _score_ids(tables, ids, code, num)
+    return cols, valid, mask
 
 
 def _fold_stats(st, cols, valid, mask, chunk: int):
@@ -413,22 +425,24 @@ def _get_step(chunk: int, sig: tuple):
     if step is not None:
         return step
 
-    def _step(carry, tables, start):
+    def sweep_step(carry, tables, start):
         cols, valid, mask = _score_chunk(tables, start, chunk)
         out = []
-        for spec, st in zip(sig, carry):
-            if spec[0] == "stats":
-                out.append(_fold_stats(st, cols, valid, mask, chunk))
-            elif spec[0] == "topk":
-                out.append(_fold_topk(st, cols, valid, mask,
-                                      spec[1], spec[2]))
-            else:
-                out.append(_fold_pareto(st, cols, valid, mask,
-                                        spec[1], spec[2]))
+        with jax.named_scope("fold"):
+            for spec, st in zip(sig, carry):
+                if spec[0] == "stats":
+                    out.append(_fold_stats(st, cols, valid, mask, chunk))
+                elif spec[0] == "topk":
+                    out.append(_fold_topk(st, cols, valid, mask,
+                                          spec[1], spec[2]))
+                else:
+                    out.append(_fold_pareto(st, cols, valid, mask,
+                                            spec[1], spec[2]))
         return tuple(out)
 
+    # the trace's XLA Modules line names it jit_sweep_step
     donate = (0,) if jax.default_backend() != "cpu" else ()
-    step = jax.jit(_step, donate_argnums=donate)
+    step = jax.jit(sweep_step, donate_argnums=donate)
     _STEP_CACHE[key] = step
     return step
 
@@ -628,13 +642,17 @@ class DeviceSweep:
         on :class:`DeviceFoldOverflow` the reducers are untouched and the
         caller can refold the identical range on the host path.
 
-        With ``profile``, each step is synchronized for honest attribution
-        (``compile_s`` first step, ``score_s`` the rest, ``transfer_s``
-        table upload + final state pull) — profiling serializes the
-        overlap on purpose.
+        Its spans (:mod:`repro.core.spans`), added to ``profile`` when it
+        is a dict: ``sweep.open`` (the table upload, ``sweep.upload``, and
+        the carry's creation), ``sweep.dispatch`` (the step enqueue loop;
+        its first call is ``sweep.compile``), ``sweep.wait`` (the host
+        blocked on the queued steps, while it pulls the first carry leaf)
+        and ``sweep.close`` (the rest of the pull, ``sweep.pull``, the
+        overflow checks and the reducer merge).  Counters: ``chunks``,
+        ``lanes``, ``feasible``, ``uploads``/``upload_bytes`` (tables and
+        each step's start), ``pulls``/``pull_bytes`` (carry leaves) and
+        ``device_calls`` (steps, plus each carry leaf created).
         """
-        import time
-
         import jax
 
         from repro import compat as _compat
@@ -656,36 +674,48 @@ class DeviceSweep:
             raise ValueError("unsupported reducer set for the device fold; "
                              "check supports() first")
         step = _get_step(chunk, sig)
+        if profile is not None:
+            profile.setdefault("path", "device-fused")
+        starts = range(lo, hi, chunk)
 
         with _compat.enable_x64():
-            t0 = time.perf_counter()
-            if self._tables_dev is None:
-                self._tables_dev = jax.device_put(self._tables_host)
-            tables = self._tables_dev
-            carry = self._init_carry(sig)
-            if profile is not None:
-                profile.setdefault("path", "device-fused")
-                profile["transfer_s"] = (profile.get("transfer_s", 0.0)
-                                         + time.perf_counter() - t0)
-                first = True
-                for s in range(lo, hi, chunk):
-                    t0 = time.perf_counter()
+            with span("sweep.open", profile):
+                if self._tables_dev is None:
+                    with span("sweep.upload", profile):
+                        self._tables_dev = jax.device_put(self._tables_host)
+                    host = jax.tree_util.tree_leaves(self._tables_host)
+                    count(profile, "uploads", len(host))
+                    count(profile, "upload_bytes",
+                          sum(np.asarray(t).nbytes for t in host))
+                tables = self._tables_dev
+                carry = self._init_carry(sig)
+            with span("sweep.dispatch", profile):
+                with span("sweep.compile", profile):
+                    carry = step(carry, tables, np.int64(lo))
+                for s in starts[1:]:
                     carry = step(carry, tables, np.int64(s))
-                    jax.block_until_ready(carry)
-                    stage = "compile_s" if first else "score_s"
-                    profile[stage] = (profile.get(stage, 0.0)
-                                      + time.perf_counter() - t0)
-                    first = False
-                profile.setdefault("enumerate_s", 0.0)   # fused in-jit
-                profile.setdefault("reduce_s", 0.0)      # fused in-jit
-                t0 = time.perf_counter()
-            else:
-                for s in range(lo, hi, chunk):
-                    carry = step(carry, tables, np.int64(s))
-            state = jax.tree_util.tree_map(np.asarray, carry)
-            if profile is not None:
-                profile["transfer_s"] += time.perf_counter() - t0
+            leaves, treedef = jax.tree_util.tree_flatten(carry)
+            with span("sweep.wait", profile):
+                # every leaf is an output of the last step
+                first = np.asarray(leaves[0])
+            with span("sweep.close", profile):
+                with span("sweep.pull", profile):
+                    state = [first] + [np.asarray(x) for x in leaves[1:]]
+                for key, n_add in (
+                        ("chunks", len(starts)),
+                        ("lanes", len(starts) * chunk),
+                        ("feasible", hi - lo),
+                        ("uploads", len(starts)),       # each step's start
+                        ("upload_bytes", 8 * len(starts)),
+                        ("pulls", len(state)),
+                        ("pull_bytes", sum(x.nbytes for x in state)),
+                        # the steps, and the carry init's leaf each
+                        ("device_calls", len(starts) + len(state))):
+                    count(profile, key, n_add)
+                self._merge(reducers, sig,
+                            jax.tree_util.tree_unflatten(treedef, state))
 
+    def _merge(self, reducers, sig, state) -> None:
         # Validate every capacity flag before touching any reducer — a
         # partial merge would double-count when the host refolds the range.
         for spec, st in zip(sig, state):
@@ -737,9 +767,12 @@ def try_outcome(plan: "_stream.SweepPlan", reducers,
     returns the same :class:`stream.StreamOutcome` ``run_stream`` would.
     Device, compile and lowering errors propagate: only a declared
     ineligibility or a capacity overflow selects the host path.
+    ``profile`` receives the spans of :meth:`DeviceSweep.fold_range`, and
+    ``sweep.plan`` around the host tables' build.
     """
     try:
-        dev = DeviceSweep.build(plan)
+        with span("sweep.plan", profile):
+            dev = DeviceSweep.build(plan)
     except DeviceIneligible as e:
         return None, str(e)
     reducers = tuple(reducers)

@@ -29,7 +29,8 @@ service:
   designs *in flight* coalesce onto one future, so a miss storm for one hot
   design costs one batch slot.
 * **Operability** — ``stats()`` exposes hit/miss/latency counters (p50/p99
-  over a sliding window), ``close(drain=True)`` performs a graceful drain,
+  over a sliding window, and the queue wait before a batch takes a
+  request), ``close(drain=True)`` performs a graceful drain,
   per-request deadlines fast-fail expired work before scoring, and a full
   queue fast-fails new submissions with :class:`ServerOverloaded` instead
   of building unbounded backlog.
@@ -53,6 +54,7 @@ import numpy as np
 
 from repro.core import model_batch as _mb
 from repro.core.cache import LruCache, config_hash
+from repro.core.spans import span
 
 if TYPE_CHECKING:  # pragma: no cover — import cycle is runtime-lazy
     from repro.api import Design, Estimate, Session
@@ -195,6 +197,7 @@ class Server:
         self._key_memo: dict[int, tuple] = {}
         self._closed = False
         self._latencies: deque[float] = deque(maxlen=int(latency_window))
+        self._queue_waits: deque[float] = deque(maxlen=int(latency_window))
         self._counters = {
             "submitted": 0, "served": 0, "errors": 0, "coalesced": 0,
             "rejected_overload": 0, "expired": 0, "batches": 0,
@@ -417,14 +420,20 @@ class Server:
 
         ``latency_ms`` summarizes the last ``latency_window`` completed
         requests (submit -> result, cache hits included): p50/p99/mean.
+        ``queue_wait_ms`` summarizes the last ``latency_window`` requests
+        a batch took (submit -> the start of that batch): n/p50/p99.
         """
         with self._lock:
             lat = sorted(self._latencies)
+            waits = sorted(self._queue_waits)
             counters = dict(self._counters)
             cache = self._cache.stats()
         n = len(lat)
-        pct = lambda q: (lat[min(n - 1, int(q * (n - 1) + 0.999999))] * 1e3  # noqa: E731
-                         if n else 0.0)
+
+        def pct(xs, q):
+            return (xs[min(len(xs) - 1, int(q * (len(xs) - 1) + 0.999999))]
+                    * 1e3 if xs else 0.0)
+
         served = max(1, counters["served"])
         return {
             **counters,
@@ -438,8 +447,13 @@ class Server:
             "latency_ms": {
                 "n": n,
                 "p50": statistics.median(lat) * 1e3 if n else 0.0,
-                "p99": pct(0.99),
+                "p99": pct(lat, 0.99),
                 "mean": sum(lat) / n * 1e3 if n else 0.0,
+            },
+            "queue_wait_ms": {
+                "n": len(waits),
+                "p50": statistics.median(waits) * 1e3 if waits else 0.0,
+                "p99": pct(waits, 0.99),
             },
             "served_per_batch": counters["served"] / max(
                 1, counters["batches"]) if counters["batches"] else 0.0,
@@ -484,10 +498,13 @@ class Server:
 
     def _batcher(self) -> None:
         while True:
-            batch = self._collect()
+            with span("serve.collect"):
+                batch = self._collect()
             if batch is None:
                 return
             now = time.monotonic()
+            with self._lock:
+                self._queue_waits.extend(now - r.t_enqueue for r in batch)
             live: list[_Request] = []
             for req in batch:
                 if req.deadline is not None and now > req.deadline:
@@ -505,19 +522,20 @@ class Server:
                 for req in live:
                     self._fail(req, exc)
                 continue
-            now = time.monotonic()
-            with self._lock:
-                self._counters["batches"] += 1
-                self._counters["batched_requests"] += len(live)
-                self._counters["max_batch_seen"] = max(
-                    self._counters["max_batch_seen"], len(live))
+            with span("serve.scatter"):
+                now = time.monotonic()
+                with self._lock:
+                    self._counters["batches"] += 1
+                    self._counters["batched_requests"] += len(live)
+                    self._counters["max_batch_seen"] = max(
+                        self._counters["max_batch_seen"], len(live))
+                    for req, est in zip(live, results):
+                        self._cache.put(req.key, est)
+                        self._inflight.pop(req.key, None)
+                        self._latencies.append(now - req.t_enqueue)
+                        self._counters["served"] += 1
                 for req, est in zip(live, results):
-                    self._cache.put(req.key, est)
-                    self._inflight.pop(req.key, None)
-                    self._latencies.append(now - req.t_enqueue)
-                    self._counters["served"] += 1
-            for req, est in zip(live, results):
-                req.future.set_result(est)
+                    req.future.set_result(est)
 
     def _score(self, designs: "Sequence[Design]") -> "list[Estimate]":
         """One batched scoring pass (the only caller of the estimator).
@@ -525,18 +543,25 @@ class Server:
         On the jax-jit backend the ragged design batch is padded to a fixed
         ``(max_batch, group-bucket)`` shape first so the jit core compiles
         once per bucket, like the streaming engine's fixed-shape chunks.
+        Spans (:mod:`repro.core.spans`): ``serve.assemble`` (the
+        ``GroupBatch`` and its padding), ``serve.score`` and
+        ``serve.scatter`` (the rows back out); the batcher adds
+        ``serve.collect`` and the futures' ``serve.scatter``.
         """
-        if self.session.backend != "jax-jit":
-            return self.session.estimate_many(list(designs))
-        from repro import api as _api
-
-        batch = self.session._batch_for(designs)
-        m = len(np.asarray(batch.kernel))
-        padded = pad_group_batch(
-            batch, self.max_batch + 1,     # +1: a home for padding groups
-            _next_pow2(max(m, self.max_batch)))
-        est = _api._jax_estimate_batch(padded)
-        return self.session._rows_from(est, designs)
+        if self.session.backend == "scalar":
+            with span("serve.score"):
+                return self.session.estimate_many(list(designs))
+        with span("serve.assemble"):
+            batch = self.session._batch_for(designs)
+            if self.session.backend == "jax-jit":
+                m = len(np.asarray(batch.kernel))
+                batch = pad_group_batch(
+                    batch, self.max_batch + 1,  # +1: a home for padding groups
+                    _next_pow2(max(m, self.max_batch)))
+        with span("serve.score"):
+            est = self.session._estimator()(batch)
+        with span("serve.scatter"):
+            return self.session._rows_from(est, designs)
 
     # -- helpers ------------------------------------------------------------
 

@@ -48,10 +48,11 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-from time import perf_counter as _perf_counter
 from typing import Callable, Iterable, Mapping, Sequence
 
 import numpy as np
+
+from repro.core.spans import count, span
 
 #: Estimate columns every evaluator must provide per chunk.
 ESTIMATE_COLUMNS = ("t_exe", "t_ideal", "t_ovh", "bound_ratio",
@@ -556,12 +557,10 @@ def run_stream(
     ``chunk_order`` permutes which chunk is evaluated when (testing hook
     for the order-invariance property); folding follows that order.
 
-    ``stage_times`` (a mutable dict) accumulates the per-stage wall-time
-    breakdown ``Session.sweep(profile=True)`` reports: ``score_s`` (chunk
-    evaluation, which on jax includes the host<->device ``transfer_s`` the
-    evaluator itself accounts) and ``reduce_s`` (reducer folds).  Only the
-    serial loop is instrumented — the threaded path overlaps stages, so
-    per-stage attribution would be meaningless there.
+    ``stage_times`` (a mutable dict, the sweep's profile) receives the
+    ``fold_s`` span of every chunk and the counters ``chunks`` and
+    ``feasible`` (rows folded); the evaluator adds its own stages.  It
+    changes nothing about how the loop runs.
     """
     if chunk_size < 1:
         raise ValueError("chunk_size must be >= 1")
@@ -577,10 +576,13 @@ def run_stream(
         # needed exactly when the columns still have the fixed shape.
         if valid != chunk_size and len(cols["id"]) == chunk_size:
             cols = {k: np.asarray(v)[:valid] for k, v in cols.items()}
+        count(stage_times, "chunks")
+        count(stage_times, "feasible", len(cols["id"]))
         if len(cols["id"]) == 0:
             return
-        for r in reducers:
-            r.update(cols)
+        with span("chunk.fold", stage_times):
+            for r in reducers:
+                r.update(cols)
 
     if workers and workers > 1 and len(starts) > 1:
         from collections import deque
@@ -601,20 +603,6 @@ def run_stream(
             while pending:
                 fut, v = pending.popleft()
                 fold(fut.result(), v)
-    elif stage_times is not None:
-        import time as _time
-
-        stage_times.setdefault("score_s", 0.0)
-        stage_times.setdefault("reduce_s", 0.0)
-        for s in starts:
-            ids, valid = _chunk_ids(s, n, chunk_size)
-            t0 = _time.perf_counter()
-            cols = eval_chunk(ids)
-            t1 = _time.perf_counter()
-            fold(cols, valid)
-            t2 = _time.perf_counter()
-            stage_times["score_s"] += t1 - t0
-            stage_times["reduce_s"] += t2 - t1
     else:
         for s in starts:
             ids, valid = _chunk_ids(s, n, chunk_size)
@@ -787,9 +775,14 @@ class SweepPlan:
         shape for scoring and sliced back down after — so constraints never
         trigger recompilation.
 
-        ``stage_times`` (see :func:`run_stream`) accumulates ``enumerate_s``
-        (mixed-radix decode + axis gathers) here and, on the jax-jit
-        backend, ``transfer_s`` inside the estimator.
+        ``stage_times`` (the sweep's profile, see :func:`run_stream`)
+        receives the spans of each chunk: ``chunk.mask`` (constraints),
+        ``chunk.decode`` (mixed-radix decode + axis gathers),
+        ``chunk.pack`` (expansion into a ``GroupBatch``), then, on the
+        jax-jit backend, ``chunk.upload``, ``chunk.dispatch`` and
+        ``chunk.pull`` inside the estimator (the call into the estimator
+        core is ``chunk.dispatch`` on every backend), and the counter
+        ``lanes`` (points scored, padding included).
         """
         from repro.core import sweep as _sweep
 
@@ -815,26 +808,27 @@ class SweepPlan:
         elif backend == "numpy-batch":
             from repro.core import model_batch as _mb
 
-            estimator = _mb.estimate_batch
+            def estimator(b):
+                with span("chunk.dispatch", stage_times):
+                    return _mb.estimate_batch(b)
 
         def score_ids(ids: np.ndarray) -> dict[str, np.ndarray]:
             m = len(ids)
-            t0 = _perf_counter() if stage_times is not None else 0.0
-            codes = enum.codes(ids)
-            numeric = {k: np.asarray(lists[k])[codes[k]] for k in num_names}
-            cats = {k: (lists[k], codes[k]) for k in cat_names}
-            if stage_times is not None:
-                stage_times["enumerate_s"] = (
-                    stage_times.get("enumerate_s", 0.0)
-                    + _perf_counter() - t0)
+            count(stage_times, "lanes", m)
+            with span("chunk.decode", stage_times):
+                codes = enum.codes(ids)
+                numeric = {k: np.asarray(lists[k])[codes[k]]
+                           for k in num_names}
+                cats = {k: (lists[k], codes[k]) for k in cat_names}
             if backend == "scalar":
-                result = _sweep._score_scalar(dict(numeric), m, cats)
+                with span("chunk.dispatch", stage_times):
+                    result = _sweep._score_scalar(dict(numeric), m, cats)
                 est, resource = result.estimate, result.resource
                 numeric = {k: result.points[k] for k in num_names}
                 cats, _, own = _sweep._resolve_hardware_codes(cats, m)
             else:
                 est, resource, cats, numeric, own = _sweep._score(
-                    numeric, cats, m, estimator)
+                    numeric, cats, m, estimator, stage_times)
             cols: dict[str, np.ndarray] = {
                 "id": np.asarray(ids, dtype=np.int64)}
             for k in num_names:
@@ -864,14 +858,16 @@ class SweepPlan:
 
         def eval_chunk(ids: np.ndarray) -> dict[str, np.ndarray]:
             ids = np.asarray(ids, dtype=np.int64)
-            # Chunk ids are strictly increasing until the padded tail
-            # repeats the last valid id, so the first occurrence of the
-            # final id marks the valid length.
-            valid = int(np.searchsorted(ids, ids[-1])) + 1 if len(ids) else 0
-            live = ids[:valid]
-            mask = feasibility_mask(
-                constraints, columns_from_lists(lists, enum.codes(live)))
-            feas = live[mask]
+            with span("chunk.mask", stage_times):
+                # Chunk ids are strictly increasing until the padded tail
+                # repeats the last valid id, so the first occurrence of the
+                # final id marks the valid length.
+                valid = (int(np.searchsorted(ids, ids[-1])) + 1
+                         if len(ids) else 0)
+                live = ids[:valid]
+                mask = feasibility_mask(
+                    constraints, columns_from_lists(lists, enum.codes(live)))
+                feas = live[mask]
             f = len(feas)
             if f == len(ids):
                 return score_ids(ids)

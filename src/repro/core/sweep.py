@@ -41,6 +41,7 @@ import numpy as np
 from repro.core import model_batch as _mb
 from repro.core.fpga import BspParams
 from repro.core.lsu import LsuType
+from repro.core.spans import span
 
 #: Sweepable axes, in canonical order.  ``lsu_type``/``dram``/``bsp``/
 #: ``hardware`` are categorical; the rest are numeric.  A ``hardware`` axis
@@ -357,6 +358,7 @@ def _normalize_inert_axes(points: dict[str, np.ndarray],
 def _score(numeric: dict[str, np.ndarray],
            cats: dict[str, tuple[list, np.ndarray]], n: int,
            estimator: Callable[[_mb.GroupBatch], _mb.BatchEstimate] | None = None,
+           prof: dict | None = None,
            ) -> tuple[_mb.BatchEstimate, np.ndarray, dict, dict, np.ndarray]:
     """Score ``n`` design points given numeric columns + coded categoricals.
 
@@ -377,9 +379,33 @@ def _score(numeric: dict[str, np.ndarray],
       scalar ACK stores (the compiler replicates the store LSU);
     * atomic: a group of ``n_ga`` atomic units (stride is always 1).
 
+    The expansion, up to the estimator call, is the ``chunk.pack`` span
+    (:mod:`repro.core.spans`), added to ``prof``.
+
     Returns ``(estimate, resource, resolved cats, normalized numeric,
     own-hardware mask)``.
     """
+    with span("chunk.pack", prof):
+        batch, cats, numeric, hw_scale, own = _pack(numeric, cats, n)
+    est = (estimator or _mb.estimate_batch)(batch)
+    if np.any(hw_scale != 1.0):
+        # apply each point's persisted hardware calibration (host_factor)
+        est = dataclasses.replace(
+            est, t_exe=np.asarray(est.t_exe) * hw_scale,
+            t_ideal=np.asarray(est.t_ideal) * hw_scale,
+            t_ovh=np.asarray(est.t_ovh) * hw_scale)
+    resource = np.bincount(batch.kernel,
+                           weights=np.asarray(batch.count * batch.ls_width,
+                                              dtype=np.float64),
+                           minlength=n)
+    return est, resource, cats, numeric, own
+
+
+def _pack(numeric: dict[str, np.ndarray],
+          cats: dict[str, tuple[list, np.ndarray]], n: int) -> tuple:
+    """:func:`_score`'s expansion of ``n`` points into a
+    :class:`model_batch.GroupBatch`: ``(batch, resolved cats, normalized
+    numeric, hardware scale, own-hardware mask)``."""
     cats, hw_scale, own = _resolve_hardware_codes(cats, n)
 
     type_table, type_idx = cats["lsu_type"]
@@ -438,18 +464,7 @@ def _score(numeric: dict[str, np.ndarray],
         f=vec([simd, simd]),
         **{k: vec([v, v]) for k, v in {**dram_f, **bsp_f}.items()},
     )
-    est = (estimator or _mb.estimate_batch)(batch)
-    if np.any(hw_scale != 1.0):
-        # apply each point's persisted hardware calibration (host_factor)
-        est = dataclasses.replace(
-            est, t_exe=np.asarray(est.t_exe) * hw_scale,
-            t_ideal=np.asarray(est.t_ideal) * hw_scale,
-            t_ovh=np.asarray(est.t_ovh) * hw_scale)
-    resource = np.bincount(kernel,
-                           weights=np.asarray(batch.count * batch.ls_width,
-                                              dtype=np.float64),
-                           minlength=n)
-    return est, resource, cats, numeric, own
+    return batch, cats, numeric, hw_scale, own
 
 
 def _score_scalar(points: dict, n: int,
@@ -535,7 +550,7 @@ def _materialize_points(numeric: dict[str, np.ndarray],
 def _build(points: dict[str, np.ndarray], n: int,
            cats: dict[str, tuple[list, np.ndarray]],
            estimator: Callable[[_mb.GroupBatch], _mb.BatchEstimate] | None = None,
-           ) -> SweepResult:
+           prof: dict | None = None) -> SweepResult:
     """Materialized scoring: every point's config + estimate held in memory.
 
     ``points`` carries the numeric per-point columns; ``cats`` must carry a
@@ -545,7 +560,8 @@ def _build(points: dict[str, np.ndarray], n: int,
     applied, inert axes normalized — exactly what was scored.
     """
     numeric = {k: points[k] for k in _NUMERIC}
-    est, resource, cats, numeric, _ = _score(numeric, cats, n, estimator)
+    est, resource, cats, numeric, _ = _score(numeric, cats, n, estimator,
+                                             prof)
     return SweepResult(points=_materialize_points(numeric, cats),
                        estimate=est, resource=resource)
 

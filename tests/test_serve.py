@@ -381,7 +381,33 @@ class TestStatsAndSurface:
         lat = s["latency_ms"]
         assert lat["n"] == 8
         assert 0.0 < lat["p50"] <= lat["p99"]
+        wait = s["queue_wait_ms"]
+        assert wait["n"] == 8 and 0.0 <= wait["p50"] <= wait["p99"]
         assert s["queue_depth"] == 0 and s["inflight"] == 0
+
+    def test_queue_wait_grows_behind_a_slow_estimator(self):
+        """One request a batch, each scored in 50 ms: the k-th request of
+        a burst waits about k batches in the queue, and its latency holds
+        that wait and its own scoring."""
+        sess = Session()
+        srv = sess.serve(max_batch=1, max_wait_ms=0.0, cache_size=0)
+        real_score = srv._score
+
+        def slow_score(batch):
+            time.sleep(0.05)
+            return real_score(batch)
+
+        srv._score = slow_score
+        with srv:
+            futs = [srv.submit(d) for d in _pool(4)]
+            for f in futs:
+                f.result(timeout=10)
+            s = srv.stats()
+        wait, lat = s["queue_wait_ms"], s["latency_ms"]
+        assert wait["n"] == lat["n"] == 4
+        assert wait["p50"] < wait["p99"]
+        assert wait["p99"] >= 3 * 50 * 0.9        # three batches ahead
+        assert lat["p99"] >= wait["p99"] + 50 * 0.9
 
     def test_public_surface(self):
         from repro import api
