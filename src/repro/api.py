@@ -1001,8 +1001,8 @@ class Session:
         ``chunks``, ``lanes`` (points scored, padding included),
         ``feasible`` (points kept by the constraints), ``uploads`` /
         ``upload_bytes`` / ``pulls`` / ``pull_bytes`` (arrays moved each
-        way) and ``device_calls`` (jitted calls, plus each carry leaf made
-        on the device), and ``path``, ``devices`` and ``host_reason``.
+        way) and ``device_calls`` (jitted calls), and ``path``,
+        ``devices`` and ``host_reason``.
         The older keys are sums of spans: ``enumerate_s`` = ``decode_s``
         (materialized: the enumeration), ``reduce_s`` = ``fold_s``,
         ``transfer_s`` = ``upload_s + pull_s``, ``score_s`` = ``pack_s +
@@ -1163,13 +1163,14 @@ class Session:
         (``processes``).  Peak memory is O(chunk + front + k); survivor
         rows (front + top-k) are the only points materialized.
 
-        On the jax-jit backend an unconstrained sweep with the standard
-        reducers takes the **device-resident fast path**
-        (:mod:`repro.core.device_stream`): enumeration, Eqs. 1-10 scoring
-        and the reducer folds fuse into one jit-compiled chunk step, with
-        reducer state pulled to the host once at the end — bit-equal to
-        this host pipeline, which remains the fallback (custom reducers,
-        constraints, multi-device sharding, capacity overflow).
+        On the jax-jit backend a sweep with the standard reducers takes
+        the **device-resident fast path** (:mod:`repro.core.device_stream`):
+        enumeration, the feasibility mask of envelope and bound
+        constraints, Eqs. 1-10 scoring and the reducer folds fuse into one
+        jit-compiled chunk step, with reducer state pulled to the host once
+        at the end — bit-equal to this host pipeline, which remains the
+        fallback (custom reducers, callable constraints, multi-device
+        sharding, capacity overflow).
         """
         import copy
 
@@ -1199,7 +1200,7 @@ class Session:
             prof["path"] = "distributed"
             outcome = _dist.run_distributed(plan, reducers, workers=workers)
         else:
-            if self.backend == "jax-jit" and not plan.constraints:
+            if self.backend == "jax-jit":
                 from repro.core import device_stream as _dev
 
                 # a device attempt that overflowed leaves its spans and

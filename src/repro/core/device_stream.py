@@ -13,6 +13,15 @@ very end:
   axis *value tables* (a few hundred numbers) live on device for the whole
   sweep.  The padded-tail rule reproduces :func:`stream._chunk_ids`
   exactly: ``ids = min(start + iota, n - 1)``.
+* **In-jit feasibility mask** — a plan constrained by envelopes, bounds
+  on the columns the device can read and conjunctions of those is masked
+  on device (:func:`_feasible`, the usage model of
+  :func:`repro.search.envelope.usage_from_axes` with ``xp=jnp``), and the
+  kept lanes are moved to the front of the chunk in id order before
+  scoring: exactly the compacted chunk ``plan.evaluator()`` scores on the
+  host, so every fold below is unchanged and bit-equal.  Caps and bounds
+  are traced data; only which columns and which comparisons join the
+  step's key.
 * **In-jit scoring** — the same two-group expansion as
   :func:`repro.core.sweep._score` (hardware-axis resolution, inert-axis
   normalization, Eqs. 1-10 via :func:`model_batch.estimate_batch` with
@@ -51,10 +60,12 @@ host path — never a silently truncated result.
 
 Everything jax lives inside functions: importing this module is
 numpy-only.  :meth:`DeviceSweep.build` raises :class:`DeviceIneligible`,
-naming the reason, when the plan is constrained, several local devices are
-visible (the host path shards chunks across them), or the plan's axis
-values fall outside the integer/bool domain the device tables mirror
-bit-exactly; callers record that reason where the path taken is reported.
+naming the reason, when the plan carries a constraint the device cannot
+evaluate (a callable, or a bound on a categorical column), several local
+devices are visible (the host path shards chunks across them), or the
+plan's axis values fall outside the integer/bool domain the device tables
+mirror bit-exactly; callers record that reason where the path taken is
+reported.
 """
 from __future__ import annotations
 
@@ -64,6 +75,11 @@ from repro.core import model_batch as _mb
 from repro.core import stream as _stream
 from repro.core import sweep as _sweep
 from repro.core.spans import count, span
+from repro.search.envelope import (
+    USAGE_COLUMNS,
+    max_transaction_bytes,
+    usage_from_axes,
+)
 
 #: Pareto front capacity of the fixed-shape device carry.  A front larger
 #: than this overflows to the host path (flagged, never truncated).
@@ -83,6 +99,13 @@ _BSP_FIELDS = ("burst_cnt", "max_th")
 
 #: Chunk-column order (must cover everything the host evaluator emits).
 COLUMNS = ("id",) + _sweep.AXES + _stream.ESTIMATE_COLUMNS + ("resource",)
+
+#: The columns a feasibility mask evaluated on device may read.
+MASK_COLUMNS = _NUM_AXES + ("lsu_type_code",) + USAGE_COLUMNS
+
+#: The axes the usage model reads (the hardware axis resolves dram/bsp).
+_USAGE_AXES = ("lsu_type", "n_ga", "simd", "elem_bytes", "include_write",
+               "dram", "bsp", "hardware")
 
 _STEP_CACHE: dict = {}
 
@@ -158,16 +181,91 @@ def _lexmin(live, keys):
     return jnp.argmax(cand), jnp.any(cand)
 
 
-def _decode(tables, ids):
-    """Axis codes and numeric axis values of ``ids``, from the tables."""
+def _decode(tables, ids, axes=_sweep.AXES):
+    """Axis codes and numeric axis values of ``ids`` on ``axes``, from the
+    tables."""
     strides, mods = tables["strides"], tables["mods"]
     # decode in the tables' integer width: int32 whenever the grid fits it
     # (see DeviceSweep.build), since 64-bit division is emulated on the TPU
     dec = ids.astype(strides.dtype)
     code = {name: (dec // strides[i]) % mods[i]
-            for i, name in enumerate(_sweep.AXES)}
-    num = {k: tables["num_" + k][code[k]] for k in _NUM_AXES}
+            for i, name in enumerate(_sweep.AXES) if name in axes}
+    num = {k: tables["num_" + k][code[k]] for k in _NUM_AXES if k in code}
     return code, num
+
+
+def _mask_axes(mask_sig: tuple) -> tuple:
+    """The axes the comparisons of ``mask_sig`` read."""
+    need = set()
+    for col, _ in mask_sig:
+        if col in USAGE_COLUMNS:
+            need.update(_USAGE_AXES)
+        elif col == "lsu_type_code":
+            need.add("lsu_type")
+        else:
+            need.add(col)
+    return tuple(a for a in _sweep.AXES if a in need)
+
+
+def _feasible(tables, code, num, mask_sig: tuple):
+    """The traced twin of the plan's feasibility mask.
+
+    ``mask_sig`` lists ``(column, op)`` comparisons, ANDed; their bounds are
+    ``tables["mask_bounds"]``.  Columns read as
+    :class:`repro.search.constraints.GridColumns` serves them: raw axis
+    values, the LSU type code, and the usage columns of
+    :func:`repro.search.envelope.usage_from_axes` against each point's
+    effective DRAM/BSP (the hardware axis resolved as in
+    :func:`_score_ids`), with the burst-buffer size from a host-built
+    table.  Every comparison is in float64, as on the host.
+    """
+    import jax.numpy as jnp
+
+    cols = dict(num)
+    need = {col for col, _ in mask_sig}
+    if need & {"lsu_type_code", *USAGE_COLUMNS}:
+        cols["lsu_type_code"] = tables["lsu_code"][code["lsu_type"]]
+    if need & set(USAGE_COLUMNS):
+        own = tables["hw_own"][code["hardware"]]
+        d_code = jnp.where(own, code["dram"],
+                           tables["len_d"] + code["hardware"])
+        b_code = jnp.where(own, code["bsp"],
+                           tables["len_b"] + code["hardware"])
+        cols.update(usage_from_axes(
+            type_codes=cols["lsu_type_code"], n_ga=num["n_ga"],
+            simd=num["simd"], elem_bytes=num["elem_bytes"],
+            include_write=num["include_write"],
+            max_txn=tables["max_txn"][d_code * tables["len_bx"] + b_code],
+            xp=jnp))
+    keep = None
+    for i, (col, op) in enumerate(mask_sig):
+        v = cols[col].astype(jnp.float64)
+        bound = tables["mask_bounds"][i]
+        ok = v <= bound if op == "<=" else v >= bound
+        keep = ok if keep is None else keep & ok
+    return keep
+
+
+def _compact(tables, start, chunk: int, mask_sig: tuple):
+    """The chunk's lanes that the plan keeps, first and in lane order, then
+    lanes past the chunk (which read as an in-range id); and their count.
+
+    This is the chunk ``plan.evaluator()`` scores on the host: its feasible
+    ids, re-padded.  Folding it under the tail rule ``iota < count`` adds
+    the kept rows at the positions the host's fold gives them, which the
+    position-paired :func:`_tree_sum_dev` needs for equal bits.
+    """
+    import jax.numpy as jnp
+
+    n = tables["n"]
+    iota = jnp.arange(chunk, dtype=jnp.int64)
+    ids = jnp.minimum(start + iota, n - 1)
+    valid = jnp.minimum(jnp.int64(chunk), n - start)
+    code, num = _decode(tables, ids, _mask_axes(mask_sig))
+    keep = _feasible(tables, code, num, mask_sig) & (iota < valid)
+    lane = jnp.arange(chunk, dtype=jnp.int32)
+    lanes = jnp.sort(jnp.where(keep, lane, jnp.int32(chunk)))
+    return lanes.astype(jnp.int64), jnp.sum(keep.astype(jnp.int64))
 
 
 def _score_ids(tables, ids, code, num):
@@ -276,6 +374,24 @@ def _score_chunk(tables, start, chunk: int):
         iota = jnp.arange(chunk, dtype=jnp.int64)
         ids = jnp.minimum(start + iota, n - 1)
         valid = jnp.minimum(jnp.int64(chunk), n - start)
+        mask = iota < valid
+        code, num = _decode(tables, ids)
+    with jax.named_scope("score"):
+        cols = _score_ids(tables, ids, code, num)
+    return cols, valid, mask
+
+
+def _score_kept(tables, start, chunk: int, mask_sig: tuple):
+    """:func:`_score_chunk` under a feasibility mask: the chunk's kept ids,
+    compacted (:func:`_compact`), scored; ``valid`` is their count."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("mask"):
+        lanes, valid = _compact(tables, start, chunk, mask_sig)
+    with jax.named_scope("decode"):
+        iota = jnp.arange(chunk, dtype=jnp.int64)
+        ids = jnp.minimum(start + lanes, tables["n"] - 1)
         mask = iota < valid
         code, num = _decode(tables, ids)
     with jax.named_scope("score"):
@@ -414,30 +530,45 @@ def _get_step(chunk: int, sig: tuple):
     """The jit-compiled fused chunk step for (chunk size, reducer config).
 
     ``step(carry, tables, start) -> carry`` — everything else (grid
-    geometry, axis tables, calibration) is traced data, so one executable
-    serves every grid whose tables fit the same padded buckets.  The carry
-    is donated off-CPU (CPU donation is a no-op that warns).
+    geometry, axis tables, calibration, caps and bounds) is traced data, so
+    one executable serves every grid whose tables fit the same padded
+    buckets.  A feasibility mask is the last entry of ``sig``, ``("mask",
+    ((column, op), ...))``: its structure is part of the key, and its
+    carry is the count of points kept.  The carry is donated off-CPU (CPU
+    donation is a no-op that warns).
     """
     import jax
+    import jax.numpy as jnp
 
     key = (chunk, sig, jax.default_backend())
     step = _STEP_CACHE.get(key)
     if step is not None:
         return step
+    mask_sig = next((spec[1] for spec in sig if spec[0] == "mask"), ())
 
     def sweep_step(carry, tables, start):
-        cols, valid, mask = _score_chunk(tables, start, chunk)
+        if mask_sig:
+            cols, valid, mask = _score_kept(tables, start, chunk, mask_sig)
+        else:
+            cols, valid, mask = _score_chunk(tables, start, chunk)
         out = []
         with jax.named_scope("fold"):
             for spec, st in zip(sig, carry):
                 if spec[0] == "stats":
-                    out.append(_fold_stats(st, cols, valid, mask, chunk))
+                    new = _fold_stats(st, cols, valid, mask, chunk)
                 elif spec[0] == "topk":
-                    out.append(_fold_topk(st, cols, valid, mask,
-                                          spec[1], spec[2]))
-                else:
-                    out.append(_fold_pareto(st, cols, valid, mask,
-                                            spec[1], spec[2]))
+                    new = _fold_topk(st, cols, valid, mask, spec[1], spec[2])
+                elif spec[0] == "pareto":
+                    new = _fold_pareto(st, cols, valid, mask, spec[1],
+                                       spec[2])
+                else:                       # the mask's count of kept points
+                    new = st + valid
+                if mask_sig:
+                    # a chunk that keeps nothing folds nothing, as on the
+                    # host (the stats fold would divide by its zero count)
+                    new = jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(valid > 0, a, b), new, st)
+                out.append(new)
         return tuple(out)
 
     # the trace's XLA Modules line names it jit_sweep_step
@@ -461,6 +592,42 @@ def _pad_table(arr: np.ndarray) -> np.ndarray:
                                           axis=0)])
 
 
+def _mask_terms(constraints) -> list:
+    """The plan's constraints as ``(column, op, bound)`` comparisons, ANDed.
+
+    Raises :class:`DeviceIneligible`, naming the constraint, for any the
+    device cannot evaluate.
+    """
+    from repro.search.constraints import (
+        AllOf,
+        BoundConstraint,
+        EnvelopeConstraint,
+    )
+
+    terms: list = []
+
+    def visit(c) -> None:
+        if type(c) is AllOf:
+            for p in c.parts:
+                visit(p)
+        elif type(c) is EnvelopeConstraint:
+            terms.extend((col, "<=", cap)
+                         for col, cap in c.envelope.caps().items())
+        elif type(c) is BoundConstraint and c.column in MASK_COLUMNS:
+            terms.append((c.column, c.op, float(c.bound)))
+        elif type(c) is BoundConstraint:
+            raise DeviceIneligible(f"constrained plan: a bound on column "
+                                   f"{c.column!r} is evaluated on the host")
+        else:
+            raise DeviceIneligible(f"constrained plan: a "
+                                   f"{type(c).__name__} is evaluated on "
+                                   f"the host")
+
+    for c in constraints:
+        visit(c)
+    return terms
+
+
 _COL_DTYPES = {
     **{a: np.int64 for a in ("id", "lsu_type", "n_ga", "simd", "n_elems",
                              "delta", "elem_bytes", "dram", "bsp",
@@ -477,11 +644,14 @@ _COL_DTYPES = {
 class DeviceSweep:
     """One plan's device-resident fold driver (build via :meth:`build`)."""
 
-    def __init__(self, plan: "_stream.SweepPlan", tables: dict):
+    def __init__(self, plan: "_stream.SweepPlan", tables: dict,
+                 mask_sig: tuple = ()):
         self.plan = plan
         self.n = plan.enumerator().n
         self.chunk = plan.chunk_size
         self.front_cap = FRONT_CAP
+        #: ``(column, op)`` of each comparison of the feasibility mask
+        self.mask_sig = mask_sig
         self._tables_host = tables
         self._tables_dev = None
 
@@ -492,11 +662,14 @@ class DeviceSweep:
         """A driver for ``plan``.
 
         Raises :class:`DeviceIneligible`, naming the reason, when the host
-        path must run instead: non-jax backend, constrained plan, several
-        visible devices (the host path shards chunks across them), an
-        empty grid, non-integer/bool numeric axis values (the device tables
-        mirror the host's gathered dtypes exactly), or axis values the host
-        evaluator itself would reject.
+        path must run instead: non-jax backend, a constraint the device
+        cannot evaluate (a callable, or a bound on a column outside
+        :data:`MASK_COLUMNS`; envelopes, such bounds and their
+        conjunctions are masked on device), several visible devices (the
+        host path shards chunks across them), an empty grid,
+        non-integer/bool numeric axis values (the device tables mirror the
+        host's gathered dtypes exactly), or axis values the host evaluator
+        itself would reject.
         """
         import jax
 
@@ -504,8 +677,8 @@ class DeviceSweep:
 
         if plan.backend != "jax-jit":
             raise DeviceIneligible(f"backend {plan.backend!r}")
-        if plan.constraints:
-            raise DeviceIneligible("constrained plan")
+        terms = _mask_terms(plan.constraints)
+        mask_sig = tuple((col, op) for col, op, _ in terms)
         ndev = jax.local_device_count()
         if ndev > 1:
             raise DeviceIneligible(
@@ -567,6 +740,18 @@ class DeviceSweep:
                 tables["bsp_" + k] = _pad_table(np.asarray(
                     [getattr(b, k) if b is not None else 0
                      for b in b_table]))
+            if any(col in USAGE_COLUMNS for col, _ in mask_sig):
+                # max_transaction_bytes over every (dram, bsp) pair, built
+                # here as the host mask builds it: the TPU emulates float64,
+                # and a pow there could land an ulp off at a cap
+                gather = lambda table, attr: np.asarray(  # noqa: E731
+                    [getattr(o, attr) if o is not None else 0
+                     for o in table], dtype=np.float64)
+                tables["max_txn"] = _pad_table(max_transaction_bytes(
+                    gather(d_table, "dq")[:, None],
+                    gather(d_table, "bl")[:, None],
+                    gather(b_table, "burst_cnt")[None, :]).ravel())
+                tables["len_bx"] = np.int64(len(b_table))
         except (AttributeError, TypeError):
             raise DeviceIneligible("unreadable dram/bsp/hardware axis "
                                    "values") from None
@@ -574,9 +759,12 @@ class DeviceSweep:
         tables["hw_hf"] = _pad_table(np.asarray(hf, dtype=np.float64))
         tables["len_d"] = np.int64(len(lists["dram"]))
         tables["len_b"] = np.int64(len(lists["bsp"]))
+        if terms:
+            tables["mask_bounds"] = np.asarray([b for _, _, b in terms],
+                                               dtype=np.float64)
 
         _compat.enable_compilation_cache()
-        return cls(plan, tables)
+        return cls(plan, tables, mask_sig)
 
     def supports(self, reducers) -> bool:
         return self._sig(reducers) is not None
@@ -594,39 +782,44 @@ class DeviceSweep:
                 sig.append(("pareto", self.front_cap, tuple(r.objectives)))
             else:
                 return None
+        if self.mask_sig:
+            sig.append(("mask", self.mask_sig))
         return tuple(sig)
 
     # -- carries ------------------------------------------------------------
 
     def _init_carry(self, sig: tuple):
-        import jax.numpy as jnp
-
+        """The empty carry, on the host: one ``device_put`` of its leaves
+        costs a few milliseconds, where creating each on the device ran a
+        program per leaf."""
         carry = []
         for spec in sig:
             if spec[0] == "stats":
                 carry.append({
-                    "n": jnp.int64(0), "mb": jnp.int64(0),
-                    "vmin": jnp.float64(np.inf), "vid": jnp.int64(-1),
-                    "te_parts": jnp.zeros(N_PARTIALS, dtype=jnp.float64),
-                    "te_cnt": jnp.int32(0),
-                    "tb_parts": jnp.zeros(N_PARTIALS, dtype=jnp.float64),
-                    "tb_cnt": jnp.int32(0),
-                    "mean": jnp.float64(0.0), "m2": jnp.float64(0.0),
-                    "ovf": jnp.bool_(False),
+                    "n": np.int64(0), "mb": np.int64(0),
+                    "vmin": np.float64(np.inf), "vid": np.int64(-1),
+                    "te_parts": np.zeros(N_PARTIALS, dtype=np.float64),
+                    "te_cnt": np.int32(0),
+                    "tb_parts": np.zeros(N_PARTIALS, dtype=np.float64),
+                    "tb_cnt": np.int32(0),
+                    "mean": np.float64(0.0), "m2": np.float64(0.0),
+                    "ovf": np.bool_(False),
                 })
             elif spec[0] == "topk":
                 carry.append({
-                    "cols": {c: jnp.zeros(spec[1], dtype=_COL_DTYPES[c])
+                    "cols": {c: np.zeros(spec[1], dtype=_COL_DTYPES[c])
                              for c in COLUMNS},
-                    "n_seen": jnp.int64(0),
+                    "n_seen": np.int64(0),
+                })
+            elif spec[0] == "pareto":
+                carry.append({
+                    "cols": {c: np.zeros(spec[1], dtype=_COL_DTYPES[c])
+                             for c in COLUMNS},
+                    "count": np.int64(0),
+                    "ovf": np.bool_(False),
                 })
             else:
-                carry.append({
-                    "cols": {c: jnp.zeros(spec[1], dtype=_COL_DTYPES[c])
-                             for c in COLUMNS},
-                    "count": jnp.int64(0),
-                    "ovf": jnp.bool_(False),
-                })
+                carry.append(np.int64(0))           # points the mask kept
         return tuple(carry)
 
     # -- the fold -----------------------------------------------------------
@@ -643,15 +836,17 @@ class DeviceSweep:
         caller can refold the identical range on the host path.
 
         Its spans (:mod:`repro.core.spans`), added to ``profile`` when it
-        is a dict: ``sweep.open`` (the table upload, ``sweep.upload``, and
-        the carry's creation), ``sweep.dispatch`` (the step enqueue loop;
-        its first call is ``sweep.compile``), ``sweep.wait`` (the host
-        blocked on the queued steps, while it pulls the first carry leaf)
-        and ``sweep.close`` (the rest of the pull, ``sweep.pull``, the
-        overflow checks and the reducer merge).  Counters: ``chunks``,
-        ``lanes``, ``feasible``, ``uploads``/``upload_bytes`` (tables and
-        each step's start), ``pulls``/``pull_bytes`` (carry leaves) and
-        ``device_calls`` (steps, plus each carry leaf created).
+        is a dict: ``sweep.open`` (the upload of the carry and, once, of
+        the tables: ``sweep.upload``), ``sweep.dispatch`` (the step
+        enqueue loop; its first call is ``sweep.compile``), ``sweep.wait``
+        (the host blocked on the queued steps, while it pulls the first
+        carry leaf) and ``sweep.close`` (the rest of the pull,
+        ``sweep.pull``, the overflow checks and the reducer merge).
+        Counters: ``chunks``, ``lanes``, ``feasible`` (the points kept,
+        counted on device under a mask), ``uploads``/``upload_bytes``
+        (tables, the carry's leaves and each step's start),
+        ``pulls``/``pull_bytes`` (carry leaves) and ``device_calls``
+        (steps).
         """
         import jax
 
@@ -680,15 +875,17 @@ class DeviceSweep:
 
         with _compat.enable_x64():
             with span("sweep.open", profile):
-                if self._tables_dev is None:
-                    with span("sweep.upload", profile):
-                        self._tables_dev = jax.device_put(self._tables_host)
-                    host = jax.tree_util.tree_leaves(self._tables_host)
-                    count(profile, "uploads", len(host))
-                    count(profile, "upload_bytes",
-                          sum(np.asarray(t).nbytes for t in host))
-                tables = self._tables_dev
                 carry = self._init_carry(sig)
+                sent = jax.tree_util.tree_leaves(carry)
+                with span("sweep.upload", profile):
+                    if self._tables_dev is None:
+                        self._tables_dev = jax.device_put(self._tables_host)
+                        sent += jax.tree_util.tree_leaves(self._tables_host)
+                    carry = jax.device_put(carry)
+                tables = self._tables_dev
+                count(profile, "uploads", len(sent))
+                count(profile, "upload_bytes",
+                      sum(np.asarray(t).nbytes for t in sent))
             with span("sweep.dispatch", profile):
                 with span("sweep.compile", profile):
                     carry = step(carry, tables, np.int64(lo))
@@ -701,19 +898,20 @@ class DeviceSweep:
             with span("sweep.close", profile):
                 with span("sweep.pull", profile):
                     state = [first] + [np.asarray(x) for x in leaves[1:]]
+                tree = jax.tree_util.tree_unflatten(treedef, state)
                 for key, n_add in (
                         ("chunks", len(starts)),
                         ("lanes", len(starts) * chunk),
-                        ("feasible", hi - lo),
+                        # the mask's count is the carry's last entry
+                        ("feasible",
+                         int(tree[-1]) if self.mask_sig else hi - lo),
                         ("uploads", len(starts)),       # each step's start
                         ("upload_bytes", 8 * len(starts)),
                         ("pulls", len(state)),
                         ("pull_bytes", sum(x.nbytes for x in state)),
-                        # the steps, and the carry init's leaf each
-                        ("device_calls", len(starts) + len(state))):
+                        ("device_calls", len(starts))):
                     count(profile, key, n_add)
-                self._merge(reducers, sig,
-                            jax.tree_util.tree_unflatten(treedef, state))
+                self._merge(reducers, sig, tree)
 
     def _merge(self, reducers, sig, state) -> None:
         # Validate every capacity flag before touching any reducer — a
@@ -726,8 +924,12 @@ class DeviceSweep:
                 raise DeviceFoldOverflow(
                     f"pareto front exceeded the device cap {spec[1]}")
 
+        # A masked range that kept nothing leaves each reducer as the host
+        # leaves it: untouched.
         for r, spec, st in zip(reducers, sig, state):
             if spec[0] == "stats":
+                if not int(st["n"]):
+                    continue
                 r.merge(_stream.StatsReducer.from_state({
                     "n_points": int(st["n"]),
                     "memory_bound": int(st["mb"]),
@@ -744,12 +946,16 @@ class DeviceSweep:
                 }))
             elif spec[0] == "topk":
                 held = min(int(st["n_seen"]), spec[1])
+                if not held:
+                    continue
                 tmp = _stream.TopKReducer(spec[1], spec[2])
                 tmp.cols = {c: np.asarray(st["cols"][c][:held])
                             for c in COLUMNS}
                 r.merge(tmp)
             else:
                 cnt = int(st["count"])
+                if not cnt:
+                    continue
                 tmp = _stream.ParetoReducer(spec[2])
                 tmp.cols = {c: np.asarray(st["cols"][c][:cnt])
                             for c in COLUMNS}

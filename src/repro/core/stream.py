@@ -984,9 +984,9 @@ def make_range_folder(plan: SweepPlan) -> Callable:
     """``fold(lo, hi, reducers)`` for chunk-aligned ranges of ``plan``.
 
     The fastest eligible implementation is chosen once per folder: on the
-    unconstrained single-device jax-jit backend that is the fused
-    device-resident step (:mod:`repro.core.device_stream` — in-jit
-    enumeration + scoring + reducer folds, one host pull per range), with a
+    single-device jax-jit backend that is the fused device-resident step
+    (:mod:`repro.core.device_stream` — in-jit enumeration, feasibility
+    mask, scoring and reducer folds, one host pull per range), with a
     transparent fall-through to the host ``plan.run_range`` loop for
     unsupported reducer sets or a device-side capacity overflow.  Both
     paths are bit-equal by the reducer merge contract, so callers (the
@@ -1001,7 +1001,7 @@ def make_range_folder(plan: SweepPlan) -> Callable:
         try:
             device = _dev.DeviceSweep.build(plan)
         except _dev.DeviceIneligible:
-            pass            # e.g. constraints or several devices: host path
+            pass    # e.g. a callable constraint or several devices
 
     evaluator = None
 

@@ -1,7 +1,8 @@
 """Device-resident fold (repro.core.device_stream): bit-equality with the
 host merge/state_dict protocol under arbitrary chunk partitions across all
-three backends, capacity-overflow fallback, per-stage profile attribution,
-and the persistent compilation cache."""
+three backends, the feasibility mask inside the fused step,
+capacity-overflow fallback, per-stage profile attribution, and the
+persistent compilation cache."""
 import math
 import pathlib
 import random
@@ -16,6 +17,7 @@ from repro.core import DDR4_1866, DDR4_2666, LsuType
 from repro.core import device_stream as dev
 from repro.core.stream import (ParetoReducer, StatsReducer, TopKReducer,
                                default_reducers, make_range_folder)
+from repro.search import AllOf, BoundConstraint, ResourceEnvelope, within
 
 ALL_TYPES = [LsuType.BC_ALIGNED, LsuType.BC_NON_ALIGNED,
              LsuType.BC_WRITE_ACK, LsuType.ATOMIC_PIPELINED]
@@ -168,6 +170,52 @@ class TestPartitionProperty:
         _check_partition([0, 400, 600, 864])
 
 
+ENV = ResourceEnvelope(lsu_ports=5, interconnect_bytes=256,
+                       buffer_bytes=20000)
+#: Only the first 216 ids (bc_aligned) have the type code 0.
+NOT_ALIGNED = BoundConstraint("lsu_type_code", 1, ">=")
+
+#: case -> (constraints, folded range, what the range keeps)
+MASKED = {
+    "everything_feasible": ((within(ENV),), (0, 100), "all"),
+    "nothing_feasible": ((within(ResourceEnvelope(
+        lsu_ports=1, interconnect_bytes=1, buffer_bytes=1)),),
+        (0, N), "none"),
+    "padded_final_chunk": ((BoundConstraint("simd", 4, "<="), NOT_ALIGNED),
+                           (800, N), "some"),
+    "all_of": ((AllOf((within(ENV), BoundConstraint("simd", 4, "<="),
+                       NOT_ALIGNED)),), (0, N), "some"),
+}
+
+
+class TestMaskedFold:
+    """Envelope and bound constraints are masked inside the fused step;
+    the fold keeps exactly the host's points, and its reducers are
+    bit-equal to the host's ``run_range`` (stats partials, min and its id,
+    top-k rows, front rows, feasible count)."""
+
+    @multi_device
+    @pytest.mark.parametrize("case", list(MASKED))
+    def test_masked_fold_matches_host(self, case):
+        constraints, (lo, hi), keeps = MASKED[case]
+        plan = Session(backend="jax-jit").plan(
+            Space.grid(**GRID), chunk_size=100, constraints=constraints)
+        drv = dev.DeviceSweep.build(plan)
+        assert drv.mask_sig
+        device, prof = default_reducers(10), {}
+        drv.fold_range(lo, hi, device, profile=prof)
+        assert prof["path"] == "device-fused"
+
+        host = default_reducers(10)
+        Session().plan(Space.grid(**GRID), chunk_size=100,
+                       constraints=constraints).run_range(lo, hi, host)
+        assert _canon(device) == _canon(host)
+        kept = int(plan.feasible_mask(np.arange(lo, hi)).sum())
+        assert prof["feasible"] == kept == host[2].n_points
+        assert {"all": kept == hi - lo, "none": kept == 0,
+                "some": 0 < kept < hi - lo}[keeps]
+
+
 class TestOverflowFallback:
     @multi_device
     def test_fold_range_raises_and_leaves_reducers_untouched(
@@ -208,6 +256,15 @@ class TestEligibility:
             Space.grid(**GRID), chunk_size=100,
             constraints=(lambda cols: np.asarray(cols["n_ga"]) > 1,))
         with pytest.raises(dev.DeviceIneligible, match="constrained"):
+            dev.DeviceSweep.build(plan)
+
+    @multi_device
+    def test_categorical_bound_is_ineligible(self):
+        plan = Session(backend="jax-jit").plan(
+            Space.grid(**GRID), chunk_size=100,
+            constraints=(BoundConstraint("dram", 1, "<="),))
+        with pytest.raises(dev.DeviceIneligible,
+                           match="constrained plan: a bound on column 'dram'"):
             dev.DeviceSweep.build(plan)
 
     @multi_device

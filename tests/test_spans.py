@@ -19,6 +19,7 @@ from repro.core import LsuType
 from repro.core import device_stream as dev
 from repro.core import spans
 from repro.core.stream import default_reducers
+from repro.search import ResourceEnvelope, within
 
 GRID = dict(
     lsu_type=[LsuType.BC_ALIGNED, LsuType.BC_WRITE_ACK,
@@ -135,7 +136,7 @@ def test_legacy_keys_are_span_sums(path):
         with compat.enable_x64():
             leaves = len(jax.tree_util.tree_leaves(drv._init_carry(sig)))
         assert prof["pulls"] == leaves
-        assert prof["device_calls"] == leaves + prof["chunks"]
+        assert prof["device_calls"] == prof["chunks"]
         assert prof["compile_s"] <= prof["dispatch_s"]
         assert prof["enumerate_s"] == prof["reduce_s"] == 0.0
     children = {"materialized": ("enumerate_s", "score_s"),
@@ -158,6 +159,25 @@ def test_profile_leaves_the_report_unchanged(path):
     a, b = plain.summary(), profiled.summary()
     b.pop("profile")
     assert a == b
+
+
+@multi_device
+def test_constrained_sweep_takes_the_fused_path():
+    """An envelope is masked inside the fused step: the sweep reports the
+    device path, no reason for the host, and the host stream's count of
+    kept points."""
+    env = (within(ResourceEnvelope(lsu_ports=5, interconnect_bytes=256)),)
+    fused = Session(backend="jax-jit").sweep(
+        Space.grid(**GRID), chunk_size=CHUNK, constraints=env, profile=True)
+    host = Session().sweep(Space.grid(**GRID), chunk_size=CHUNK,
+                           constraints=env, profile=True)
+    assert fused.profile["path"] == "device-fused"
+    assert "host_reason" not in fused.profile
+    assert host.profile["path"] == "host-stream"
+    assert fused.profile["feasible"] == host.profile["feasible"] \
+        == fused.n_points
+    assert 0 < fused.n_points < N
+    assert fused.summary()["n_candidates"] == N
 
 
 def test_span_fills_the_profile_and_counts():

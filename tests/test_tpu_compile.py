@@ -4,9 +4,10 @@ The TPU compiler refuses programs that XLA:CPU and Pallas interpret mode
 accept: a float64 -> int64 bitcast in the fused sweep step, kernel blocks
 off the (8, 128) tiling, primitives Mosaic cannot lower.  These tests compile
 for one chip of a described ``v5e:2x2`` topology the fused sweep step (chunk
-2**17, default reducers), the batched estimator core behind ``serve()`` and
-the host-stream path (also sharded over the four chips), and the seven
-``validate()`` kernels at their measurement shapes.  Nothing runs, so results and times are out of scope.
+2**17, default reducers; unconstrained and masked to a board's envelope),
+the batched estimator core behind ``serve()`` and the host-stream path (also
+sharded over the four chips), and the seven ``validate()`` kernels at their
+measurement shapes.  Nothing runs, so results and times are out of scope.
 
 The topology is described inside a fixture, never at import time: only one
 process at a time may load the TPU compiler's library.
@@ -69,8 +70,11 @@ def _specs(tree, sharding):
                                        sharding=sharding), tree)
 
 
-def test_fused_sweep_step_compiles(one_chip):
-    """The device-fused step at the stream_10m grid's chunk and reducers."""
+@pytest.mark.parametrize("constrained", [False, True],
+                         ids=["unconstrained", "within_envelope"])
+def test_fused_sweep_step_compiles(constrained, one_chip):
+    """The device-fused step at the stream_10m grid's chunk and reducers,
+    unconstrained and with the feasibility mask of a board's envelope."""
     import jax
     import jax.numpy as jnp
 
@@ -78,11 +82,17 @@ def test_fused_sweep_step_compiles(one_chip):
     from repro import Session, Space, compat
     from repro.core import device_stream as dev
     from repro.core import stream as st
+    from repro.hw import get as hw_get
+    from repro.search import within
 
     chunk = 1 << 17
+    constraints = ((within(hw_get("stratix10_ddr4_1866").envelope),)
+                   if constrained else ())
     plan = Session(backend="jax-jit").plan(
-        Space.grid(**STREAM_GRIDS["10m"]), chunk_size=chunk)
+        Space.grid(**STREAM_GRIDS["10m"]), chunk_size=chunk,
+        constraints=constraints)
     sweep = dev.DeviceSweep.build(plan)
+    assert bool(sweep.mask_sig) == constrained
     sig = sweep._sig(st.default_reducers())
     step = dev._get_step(chunk, sig)
     with compat.enable_x64():
