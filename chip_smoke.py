@@ -16,7 +16,7 @@ entry points, and checks every answer against the numpy-batch host path:
              preset (train and decode), depth and batch cut to one chip; the
              composed totals must equal the summed parts.
 
-``--four-chips`` runs only the ``stream_10m`` sweep, with its chunks sharded
+``--four-chips`` runs only the ``stream_10m`` sweep, on the fused step split
 over every local chip, and its host comparison.
 
 Agreement: equal bits pass.  Otherwise the largest difference in ulps is
@@ -26,7 +26,7 @@ point ids (front membership, top-k order) must match exactly.
 Usage, from the repository root::
 
     python3 chip_smoke.py                # one chip
-    python3 chip_smoke.py --four-chips   # the sharded sweep on four chips
+    python3 chip_smoke.py --four-chips   # the fused sweep on four chips
 
 Exits non-zero, printing no result, when the first jax device is not a TPU.
 The last line of standard output is one JSON object:
@@ -172,15 +172,13 @@ def phase_sweep(n_chips: int) -> dict:
                     profile=True)
     profiled_s = time.perf_counter() - t0
     prof = rep.profile
-    want_path = "device-fused" if n_chips == 1 else "host-stream"
     log(f"  profile: {json.dumps(prof, default=str)}")
-    check(prof["path"] == want_path,
+    check(prof["path"] == "device-fused",
           f"sweep took the {prof['path']!r} path "
-          f"({prof.get('host_reason', '')}), not {want_path!r}")
-    if n_chips > 1:
-        check(prof.get("devices") == n_chips,
-              f"chunks spread over {prof.get('devices')} devices, "
-              f"not {n_chips}")
+          f"({prof.get('host_reason', '')}), not 'device-fused'")
+    check(prof.get("devices") == n_chips,
+          f"the sweep's ranges spread over {prof.get('devices')} devices, "
+          f"not {n_chips}")
     t0 = time.perf_counter()
     rep = dev.sweep(space, chunk_size=CHUNK, reducers=default_reducers(TOP_K))
     warm_s = time.perf_counter() - t0
@@ -437,8 +435,8 @@ def phase_model() -> dict:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only the stream_10m sweep sharded over four "
-                         "local chips, and its host comparison")
+                    help="run only the stream_10m sweep on the fused step "
+                         "over four local chips, and its host comparison")
     args = ap.parse_args(argv)
 
     import jax

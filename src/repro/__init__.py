@@ -23,9 +23,10 @@ and are removed — use ``repro.hw.get(name)`` views instead.
 
 Million-point design spaces stream instead of materializing:
 ``sess.sweep(repro.Space.grid(...).stream(), chunk_size=65536)`` enumerates
-points lazily, evaluates fixed-shape chunks (sharded across local devices
-on the ``jax-jit`` backend) and folds them into online Pareto/top-k/stats
-reducers, so peak memory is O(chunk + front + k) at any sweep size.
+points lazily, evaluates fixed-shape chunks (on the ``jax-jit`` backend in
+one fused device step, the grid split over every local device) and folds
+them into online Pareto/top-k/stats reducers, so peak memory is
+O(chunk + front + k) at any sweep size.
 
 Streaming sweeps also distribute: ``sess.sweep(space,
 executor="processes", workers=4)`` partitions the grid into chunk-aligned

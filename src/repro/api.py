@@ -953,8 +953,8 @@ class Session:
         Passing ``chunk_size`` (or a ``Space.grid(...).stream()`` space, or
         explicit ``reducers``) switches to **bounded-memory streaming**:
         points are enumerated lazily, evaluated in fixed-shape chunks (the
-        jax-jit estimator compiles exactly once per chunk shape and shards
-        chunks across local devices when there are several), and folded
+        jax-jit backend compiles exactly once per chunk shape and, with
+        several local devices, splits the grid's ids over them), and folded
         into online reducers — by default a running Pareto front, a
         ``top_k(10)`` selection and exact summary stats — so a 10M-point
         grid sweeps in O(chunk + front + k) memory.  ``reducers`` takes
@@ -964,7 +964,7 @@ class Session:
 
         * ``"threads"`` (default) — the in-process pipeline; ``workers``
           sizes the chunk thread pool on the numpy-batch backend (the
-          jax-jit backend already shards chunks across devices, and the
+          jax-jit backend already uses every local device, and the
           scalar reference loop is GIL-bound — both reject ``workers > 1``
           here);
         * ``"processes"`` — the coordinator/worker process pool
@@ -1169,8 +1169,13 @@ class Session:
         constraints, Eqs. 1-10 scoring and the reducer folds fuse into one
         jit-compiled chunk step, with reducer state pulled to the host once
         at the end — bit-equal to this host pipeline, which remains the
-        fallback (custom reducers, callable constraints, multi-device
-        sharding, capacity overflow).
+        fallback (custom reducers, callable constraints, bounds on
+        categorical columns, non-integer axes, capacity overflow).  On
+        several local devices the fused step splits the grid's ids into one
+        range per device and merges their folds in device order; the host
+        pipeline shards each chunk over the devices only when it runs.  The
+        plan rounds the chunk to a multiple of the device count for that
+        sharding.
         """
         import copy
 

@@ -13,6 +13,9 @@ than into a kernel or model file:
   in interpret mode (everywhere except a real TPU backend).
 * ``data_sharding(n)`` — a 1-D leading-axis ``NamedSharding``; the
   streaming sweep engine shards each fixed-shape chunk batch with it.
+* ``chip_mesh(devices)`` and ``shard_map(f, mesh, in_specs, out_specs)`` —
+  a one-axis mesh over given devices and ``jax.shard_map`` over it; the
+  fused sweep step runs one per-chip body on every chip of a host with it.
 * ``enable_compilation_cache()`` — jax's persistent compilation cache, in
   ``$JAX_COMPILATION_CACHE_DIR`` when set and otherwise in one fixed
   directory of the checkout, so fresh processes (benchmarks, distributed
@@ -27,6 +30,7 @@ import os
 import pathlib
 
 import jax
+import numpy as np
 from jax.sharding import AxisType
 
 #: Where the persistent compilation cache lives when
@@ -76,6 +80,27 @@ def data_sharding(n: int | None = None):
     n = int(n if n is not None else jax.local_device_count())
     mesh = make_mesh((n,), ("data",))
     return NamedSharding(mesh, PartitionSpec("data"))
+
+
+#: The axis of :func:`chip_mesh`.
+CHIP_AXIS = "chip"
+
+
+def chip_mesh(devices):
+    """A one-axis :data:`CHIP_AXIS` mesh over ``devices``, in their order."""
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(devices), (CHIP_AXIS,),
+                axis_types=(AxisType.Auto,))
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` of ``f`` over ``mesh``, each device running ``f``
+    on its own blocks.  Varying-manual-axes checking is off: the bodies
+    mapped here carry per-device state through ``lax`` loops whose initial
+    values are replicated constants, which that check refuses."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -50,6 +50,14 @@ very end:
   compiles the very executable the million-point sweep runs, and
   :func:`repro.compat.enable_compilation_cache` persists it across
   processes.
+* **Every chip of the host** — on several local devices the id range is
+  split into one contiguous, chunk-aligned range per chip
+  (:meth:`DeviceSweep.split`), and one program for the mesh
+  (:func:`_get_mesh_step`, the same step body under ``shard_map``) steps
+  every chip through its range in lockstep, one call a round, with the
+  carry stacked over the chips and the tables replicated.  The chips'
+  carries are pulled once and merged into the reducers in chip order,
+  as the process pool merges its ranges.
 
 Fixed-shape carries mean two *capacity* limits the host fold does not
 have: the Pareto front cap (:data:`FRONT_CAP`) and the exact-sum partial
@@ -61,11 +69,9 @@ host path — never a silently truncated result.
 Everything jax lives inside functions: importing this module is
 numpy-only.  :meth:`DeviceSweep.build` raises :class:`DeviceIneligible`,
 naming the reason, when the plan carries a constraint the device cannot
-evaluate (a callable, or a bound on a categorical column), several local
-devices are visible (the host path shards chunks across them), or the
-plan's axis values fall outside the integer/bool domain the device tables
-mirror bit-exactly; callers record that reason where the path taken is
-reported.
+evaluate (a callable, or a bound on a categorical column), or the plan's
+axis values fall outside the integer/bool domain the device tables mirror
+bit-exactly; callers record that reason where the path taken is reported.
 """
 from __future__ import annotations
 
@@ -526,24 +532,19 @@ def _fold_pareto(st, cols, valid, mask, cap: int, objectives):
     }
 
 
-def _get_step(chunk: int, sig: tuple):
-    """The jit-compiled fused chunk step for (chunk size, reducer config).
+def _step_body(chunk: int, sig: tuple):
+    """The fused chunk step's body, ``sweep_step(carry, tables, start) ->
+    carry``, for (chunk size, reducer config).
 
-    ``step(carry, tables, start) -> carry`` — everything else (grid
-    geometry, axis tables, calibration, caps and bounds) is traced data, so
-    one executable serves every grid whose tables fit the same padded
-    buckets.  A feasibility mask is the last entry of ``sig``, ``("mask",
-    ((column, op), ...))``: its structure is part of the key, and its
-    carry is the count of points kept.  The carry is donated off-CPU (CPU
-    donation is a no-op that warns).
+    Everything else (grid geometry, axis tables, calibration, caps and
+    bounds) is traced data, so one executable serves every grid whose
+    tables fit the same padded buckets.  A feasibility mask is the last
+    entry of ``sig``, ``("mask", ((column, op), ...))``: its structure is
+    part of the key, and its carry is the count of points kept.
     """
     import jax
     import jax.numpy as jnp
 
-    key = (chunk, sig, jax.default_backend())
-    step = _STEP_CACHE.get(key)
-    if step is not None:
-        return step
     mask_sig = next((spec[1] for spec in sig if spec[0] == "mask"), ())
 
     def sweep_step(carry, tables, start):
@@ -571,9 +572,80 @@ def _get_step(chunk: int, sig: tuple):
                 out.append(new)
         return tuple(out)
 
+    return sweep_step
+
+
+def _get_step(chunk: int, sig: tuple):
+    """The jit-compiled fused chunk step of one chip, ``step(carry, tables,
+    start) -> carry`` (:func:`_step_body`).  The carry is donated off-CPU
+    (CPU donation is a no-op that warns)."""
+    import jax
+
+    key = (chunk, sig, jax.default_backend())
+    step = _STEP_CACHE.get(key)
+    if step is not None:
+        return step
     # the trace's XLA Modules line names it jit_sweep_step
     donate = (0,) if jax.default_backend() != "cpu" else ()
-    step = jax.jit(sweep_step, donate_argnums=donate)
+    step = jax.jit(_step_body(chunk, sig), donate_argnums=donate)
+    _STEP_CACHE[key] = step
+    return step
+
+
+def _mesh_shardings(devices: tuple):
+    """The shardings on the chip mesh of ``devices``: stacked over the
+    chips (a leading axis split over them), and replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro import compat as _compat
+
+    mesh = _compat.chip_mesh(devices)
+    return (NamedSharding(mesh, PartitionSpec(_compat.CHIP_AXIS)),
+            NamedSharding(mesh, PartitionSpec()))
+
+
+def _get_mesh_step(chunk: int, sig: tuple, devices: tuple):
+    """The fused chunk step of several chips: one program for the mesh of
+    ``devices``, ``step(carry, tables, starts) -> carry``.
+
+    Every leaf of ``carry`` is stacked over the chips (sharded on the chip
+    axis), ``tables`` are replicated, and ``starts`` is ``int64[chips]``:
+    each chip runs :func:`_step_body` on its own chunk and carry.  A chip
+    whose range is used up steps at ``start = n``, a chunk of padding
+    only, and keeps its carry.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro import compat as _compat
+
+    key = (chunk, sig, jax.default_backend(), tuple(d.id for d in devices))
+    step = _STEP_CACHE.get(key)
+    if step is not None:
+        return step
+    body = _step_body(chunk, sig)
+
+    def chip_step(carry, tables, starts):
+        start = starts[0]
+        st = jax.tree_util.tree_map(lambda a: a[0], carry)
+        new = body(st, tables, start)
+        valid = jnp.minimum(jnp.int64(chunk), tables["n"] - start)
+        return jax.tree_util.tree_map(
+            lambda a, b: jnp.where(valid > 0, a, b)[None], new, st)
+
+    on_chips, replicated = _mesh_shardings(devices)
+    mapped = _compat.shard_map(
+        chip_step, on_chips.mesh,
+        in_specs=(on_chips.spec, replicated.spec, on_chips.spec),
+        out_specs=on_chips.spec)
+
+    def sweep_step(carry, tables, starts):
+        return mapped(carry, tables, starts)
+
+    donate = (0,) if jax.default_backend() != "cpu" else ()
+    step = jax.jit(sweep_step, donate_argnums=donate,
+                   in_shardings=(on_chips, replicated, on_chips),
+                   out_shardings=on_chips)
     _STEP_CACHE[key] = step
     return step
 
@@ -645,13 +717,15 @@ class DeviceSweep:
     """One plan's device-resident fold driver (build via :meth:`build`)."""
 
     def __init__(self, plan: "_stream.SweepPlan", tables: dict,
-                 mask_sig: tuple = ()):
+                 mask_sig: tuple, devices: tuple):
         self.plan = plan
         self.n = plan.enumerator().n
         self.chunk = plan.chunk_size
         self.front_cap = FRONT_CAP
         #: ``(column, op)`` of each comparison of the feasibility mask
         self.mask_sig = mask_sig
+        #: the chips a fold splits its range over, in order
+        self.devices = devices
         self._tables_host = tables
         self._tables_dev = None
 
@@ -659,14 +733,14 @@ class DeviceSweep:
 
     @classmethod
     def build(cls, plan: "_stream.SweepPlan") -> "DeviceSweep":
-        """A driver for ``plan``.
+        """A driver for ``plan`` on every local device: a fold splits its
+        range over them (:meth:`fold_range`).
 
         Raises :class:`DeviceIneligible`, naming the reason, when the host
         path must run instead: non-jax backend, a constraint the device
         cannot evaluate (a callable, or a bound on a column outside
         :data:`MASK_COLUMNS`; envelopes, such bounds and their
-        conjunctions are masked on device), several visible devices (the
-        host path shards chunks across them), an empty grid,
+        conjunctions are masked on device), an empty grid,
         non-integer/bool numeric axis values (the device tables mirror the
         host's gathered dtypes exactly), or axis values the host evaluator
         itself would reject.
@@ -679,10 +753,6 @@ class DeviceSweep:
             raise DeviceIneligible(f"backend {plan.backend!r}")
         terms = _mask_terms(plan.constraints)
         mask_sig = tuple((col, op) for col, op, _ in terms)
-        ndev = jax.local_device_count()
-        if ndev > 1:
-            raise DeviceIneligible(
-                f"{ndev} local devices: chunks are sharded on the host path")
         lists = {k: list(v) for k, v in plan.lists.items()}
         enum = _stream.GridEnumerator(lists)
         if enum.n == 0:
@@ -764,7 +834,7 @@ class DeviceSweep:
                                                dtype=np.float64)
 
         _compat.enable_compilation_cache()
-        return cls(plan, tables, mask_sig)
+        return cls(plan, tables, mask_sig, tuple(jax.local_devices()))
 
     def supports(self, reducers) -> bool:
         return self._sig(reducers) is not None
@@ -824,6 +894,19 @@ class DeviceSweep:
 
     # -- the fold -----------------------------------------------------------
 
+    def split(self, lo: int, hi: int) -> list:
+        """Chunk-aligned ``[lo, hi)`` as one contiguous ``(lo, hi)`` range
+        per device, in device order: sizes differ by at most one chunk, the
+        last range ends at ``hi`` and a device with no chunk gets an empty
+        range.  Each is a range :meth:`SweepPlan.run_range` folds."""
+        chunk, ndev = self.chunk, len(self.devices)
+        base, extra = divmod(-(-(hi - lo) // chunk), ndev)
+        out, c = [], 0
+        for i in range(ndev):
+            first, c = c, c + base + (i < extra)
+            out.append((min(lo + first * chunk, hi), min(lo + c * chunk, hi)))
+        return out
+
     def fold_range(self, lo: int, hi: int, reducers,
                    profile: dict | None = None) -> None:
         """Fold chunk-aligned ``[lo, hi)`` into ``reducers`` on device.
@@ -835,18 +918,28 @@ class DeviceSweep:
         on :class:`DeviceFoldOverflow` the reducers are untouched and the
         caller can refold the identical range on the host path.
 
+        On several devices the range is split (:meth:`split`) and every
+        round is one call of one program for the mesh
+        (:func:`_get_mesh_step`) that steps each chip through its own
+        range, in lockstep; the chips' carries are merged into
+        ``reducers`` in device order, as the process pool merges ranges
+        (every reported number but the variance is bit-equal to one chip's
+        fold of the whole range; the variance combines through
+        :func:`stream._chan_merge`, like any partition's).
+
         Its spans (:mod:`repro.core.spans`), added to ``profile`` when it
         is a dict: ``sweep.open`` (the upload of the carry and, once, of
         the tables: ``sweep.upload``), ``sweep.dispatch`` (the step
         enqueue loop; its first call is ``sweep.compile``), ``sweep.wait``
         (the host blocked on the queued steps, while it pulls the first
         carry leaf) and ``sweep.close`` (the rest of the pull,
-        ``sweep.pull``, the overflow checks and the reducer merge).
-        Counters: ``chunks``, ``lanes``, ``feasible`` (the points kept,
-        counted on device under a mask), ``uploads``/``upload_bytes``
-        (tables, the carry's leaves and each step's start),
-        ``pulls``/``pull_bytes`` (carry leaves) and ``device_calls``
-        (steps).
+        ``sweep.pull``, the overflow checks and the merge of the chips'
+        states into the reducers, ``sweep.merge``).  Counters: ``chunks``,
+        ``lanes`` (real chunks and their lanes), ``feasible`` (the points
+        kept, counted on device under a mask), ``uploads``/``upload_bytes``
+        (tables, the carry's leaves and each round's starts),
+        ``pulls``/``pull_bytes`` (carry leaves), ``device_calls`` (rounds)
+        and ``devices`` (the devices that held a range).
         """
         import jax
 
@@ -868,10 +961,13 @@ class DeviceSweep:
         if sig is None:
             raise ValueError("unsupported reducer set for the device fold; "
                              "check supports() first")
-        step = _get_step(chunk, sig)
         if profile is not None:
             profile.setdefault("path", "device-fused")
-        starts = range(lo, hi, chunk)
+        ranges = self.split(lo, hi)
+        ndev = len(ranges)
+        n_chunks = -(-(hi - lo) // chunk)
+        step, starts, put_tables, put_carry, per_chip = self._layout(
+            sig, lo, hi, ranges)
 
         with _compat.enable_x64():
             with span("sweep.open", profile):
@@ -879,18 +975,19 @@ class DeviceSweep:
                 sent = jax.tree_util.tree_leaves(carry)
                 with span("sweep.upload", profile):
                     if self._tables_dev is None:
-                        self._tables_dev = jax.device_put(self._tables_host)
+                        self._tables_dev = put_tables(self._tables_host)
                         sent += jax.tree_util.tree_leaves(self._tables_host)
-                    carry = jax.device_put(carry)
+                    carry = put_carry(carry)
                 tables = self._tables_dev
                 count(profile, "uploads", len(sent))
+                # every device receives its carry and the tables
                 count(profile, "upload_bytes",
-                      sum(np.asarray(t).nbytes for t in sent))
+                      ndev * sum(np.asarray(t).nbytes for t in sent))
             with span("sweep.dispatch", profile):
                 with span("sweep.compile", profile):
-                    carry = step(carry, tables, np.int64(lo))
+                    carry = step(carry, tables, starts[0])
                 for s in starts[1:]:
-                    carry = step(carry, tables, np.int64(s))
+                    carry = step(carry, tables, s)
             leaves, treedef = jax.tree_util.tree_flatten(carry)
             with span("sweep.wait", profile):
                 # every leaf is an output of the last step
@@ -898,32 +995,79 @@ class DeviceSweep:
             with span("sweep.close", profile):
                 with span("sweep.pull", profile):
                     state = [first] + [np.asarray(x) for x in leaves[1:]]
-                tree = jax.tree_util.tree_unflatten(treedef, state)
+                chips = [jax.tree_util.tree_unflatten(treedef, st)
+                         for st in per_chip(state)]
                 for key, n_add in (
-                        ("chunks", len(starts)),
-                        ("lanes", len(starts) * chunk),
+                        ("chunks", n_chunks),
+                        ("lanes", n_chunks * chunk),
                         # the mask's count is the carry's last entry
                         ("feasible",
-                         int(tree[-1]) if self.mask_sig else hi - lo),
-                        ("uploads", len(starts)),       # each step's start
-                        ("upload_bytes", 8 * len(starts)),
+                         sum(int(t[-1]) for t in chips) if self.mask_sig
+                         else hi - lo),
+                        ("uploads", len(starts)),       # each round's starts
+                        ("upload_bytes", 8 * ndev * len(starts)),
                         ("pulls", len(state)),
                         ("pull_bytes", sum(x.nbytes for x in state)),
                         ("device_calls", len(starts))):
                     count(profile, key, n_add)
-                self._merge(reducers, sig, tree)
+                if profile is not None:
+                    profile["devices"] = sum(r1 > r0 for r0, r1 in ranges)
+                with span("sweep.merge", profile):
+                    self._merge(reducers, sig, chips)
 
-    def _merge(self, reducers, sig, state) -> None:
-        # Validate every capacity flag before touching any reducer — a
-        # partial merge would double-count when the host refolds the range.
-        for spec, st in zip(sig, state):
-            if spec[0] == "stats" and bool(st["ovf"]):
-                raise DeviceFoldOverflow(
-                    f"exact-sum partial count exceeded {N_PARTIALS}")
-            if spec[0] == "pareto" and bool(st["ovf"]):
-                raise DeviceFoldOverflow(
-                    f"pareto front exceeded the device cap {spec[1]}")
+    def _layout(self, sig: tuple, lo: int, hi: int, ranges: list):
+        """How a fold of ``[lo, hi)`` split into ``ranges`` (one a device)
+        runs: ``(step, starts, put_tables, put_carry, per_chip)``.
 
+        ``step`` is called once a round with the next entry of ``starts``;
+        ``put_tables`` and ``put_carry`` upload the host tables and the
+        empty host carry; ``per_chip`` turns the pulled carry leaves into
+        one list of leaves a device, in device order.  One device runs the
+        one-chip step, a start a call.  Several run the mesh program: a
+        round's starts are one per device (a used-up device steps at
+        ``n``), the carry is stacked over the devices and the tables are
+        replicated.
+        """
+        import jax
+
+        chunk, n, ndev = self.chunk, self.n, len(ranges)
+        if ndev == 1:
+            return (_get_step(chunk, sig),
+                    [np.int64(s) for s in range(lo, hi, chunk)],
+                    jax.device_put, jax.device_put, lambda state: [state])
+        on_chips, replicated = _mesh_shardings(self.devices)
+        # the first range is the longest
+        rounds = -(-(ranges[0][1] - ranges[0][0]) // chunk)
+        starts = [np.asarray([r0 + i * chunk if r0 + i * chunk < r1 else n
+                              for r0, r1 in ranges], dtype=np.int64)
+                  for i in range(rounds)]
+
+        def put_carry(carry):
+            return jax.device_put(jax.tree_util.tree_map(
+                lambda a: np.stack([a] * ndev), carry), on_chips)
+
+        return (_get_mesh_step(chunk, sig, self.devices), starts,
+                lambda tables: jax.device_put(tables, replicated), put_carry,
+                lambda state: [[x[i] for x in state] for i in range(ndev)])
+
+    def _merge(self, reducers, sig, chips) -> None:
+        """Merge each chip's state (a list, in device order) into
+        ``reducers``."""
+        # Validate every capacity flag of every chip before touching any
+        # reducer — a partial merge would double-count when the host
+        # refolds the range.
+        for state in chips:
+            for spec, st in zip(sig, state):
+                if spec[0] == "stats" and bool(st["ovf"]):
+                    raise DeviceFoldOverflow(
+                        f"exact-sum partial count exceeded {N_PARTIALS}")
+                if spec[0] == "pareto" and bool(st["ovf"]):
+                    raise DeviceFoldOverflow(
+                        f"pareto front exceeded the device cap {spec[1]}")
+        for state in chips:
+            self._merge_one(reducers, sig, state)
+
+    def _merge_one(self, reducers, sig, state) -> None:
         # A masked range that kept nothing leaves each reducer as the host
         # leaves it: untouched.
         for r, spec, st in zip(reducers, sig, state):

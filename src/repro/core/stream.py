@@ -984,13 +984,15 @@ def make_range_folder(plan: SweepPlan) -> Callable:
     """``fold(lo, hi, reducers)`` for chunk-aligned ranges of ``plan``.
 
     The fastest eligible implementation is chosen once per folder: on the
-    single-device jax-jit backend that is the fused device-resident step
+    jax-jit backend that is the fused device-resident step
     (:mod:`repro.core.device_stream` — in-jit enumeration, feasibility
     mask, scoring and reducer folds, one host pull per range), with a
     transparent fall-through to the host ``plan.run_range`` loop for
     unsupported reducer sets or a device-side capacity overflow.  Both
-    paths are bit-equal by the reducer merge contract, so callers (the
-    distributed worker loop) never see which one ran.  The host evaluator
+    paths are bit-equal by the reducer merge contract (on several devices
+    the fused fold splits each range over them, which re-groups only the
+    variance, as any partition does), so callers (the distributed worker
+    loop) never see which one ran.  The host evaluator
     is built lazily — a worker whose every unit folds on-device never pays
     for it.
     """
@@ -1001,7 +1003,7 @@ def make_range_folder(plan: SweepPlan) -> Callable:
         try:
             device = _dev.DeviceSweep.build(plan)
         except _dev.DeviceIneligible:
-            pass    # e.g. a callable constraint or several devices
+            pass    # e.g. a callable constraint
 
     evaluator = None
 

@@ -1,8 +1,8 @@
 """Device-resident fold (repro.core.device_stream): bit-equality with the
 host merge/state_dict protocol under arbitrary chunk partitions across all
 three backends, the feasibility mask inside the fused step,
-capacity-overflow fallback, per-stage profile attribution, and the
-persistent compilation cache."""
+capacity-overflow fallback, per-stage profile attribution, the fold split
+over four devices, and the persistent compilation cache."""
 import math
 import pathlib
 import random
@@ -36,7 +36,7 @@ N = 864
 
 multi_device = pytest.mark.skipif(
     jax.local_device_count() > 1,
-    reason="device fold defers to host chunk sharding on multi-device")
+    reason="one-device folds; TestMultiChip splits folds over several")
 
 
 def _plan(backend: str, chunk: int):
@@ -325,3 +325,208 @@ class TestProfileAndCache:
                 pathlib.Path(compat.__file__).resolve().parents[2]
         assert compat.enable_compilation_cache() == want
         assert seen["jax_compilation_cache_dir"] == want
+
+
+#: Runs under four forced host devices (a fresh process: the device count
+#: is fixed when jax starts) and prints one JSON line, keyed by case.
+_MULTI_CHIP = r"""
+import json, sys
+import numpy as np
+import jax
+sys.path.insert(0, TESTS)
+from test_device_stream import GRID, N, _canon
+from repro import Session, Space
+from repro.core import device_stream as dev
+from repro.core.stream import default_reducers
+from repro.hw import get as hw_get
+from repro.search import within
+
+assert jax.local_device_count() == 4
+S10 = (within(hw_get("stratix10_ddr4_1866").envelope),)
+#: 720 of its 864 points fit the Stratix 10 envelope
+S10_GRID = dict(GRID, n_ga=[1, 16, 128])
+
+
+def canon(reducers):
+    return json.loads(json.dumps(_canon(reducers), default=str))
+
+
+def plan(backend, chunk, cons=(), grid=GRID):
+    return Session(backend=backend).plan(Space.grid(**grid),
+                                         chunk_size=chunk, constraints=cons)
+
+
+def by_ranges(fold, ranges):
+    # each range into fresh reducers, merged in order: the process pool's
+    # protocol (an empty range holds nothing to merge)
+    base = default_reducers(10)
+    for lo, hi in (r for r in ranges if r[1] > r[0]):
+        fresh = tuple(r.fresh() for r in base)
+        fold(lo, hi, fresh)
+        for b, r in zip(base, fresh):
+            b.merge(type(b).from_state(r.state_dict()))
+    return canon(base)
+
+
+def report(grid, cons=(), backend="jax-jit"):
+    rep = Session(backend=backend).sweep(Space.grid(**grid), chunk_size=100,
+                                         constraints=cons, profile=True)
+    return {"path": rep.profile["path"],
+            "devices": rep.profile.get("devices"),
+            "host_reason": rep.profile.get("host_reason"),
+            "front": np.sort(np.asarray(rep.point_ids)[rep.pareto()]
+                             ).tolist(),
+            "top_k": json.loads(json.dumps(rep.top_k(10), default=str)),
+            "stats": rep.stats}
+
+
+def case(chunk, lo, hi, cons=(), grid=GRID):
+    four = dev.DeviceSweep.build(plan("jax-jit", chunk, cons, grid))
+    host = plan("numpy-batch", chunk, cons, grid)
+    ranges = four.split(lo, hi)
+    red, prof = default_reducers(10), {}
+    four.fold_range(lo, hi, red, profile=prof)
+    whole = default_reducers(10)
+    host.run_range(lo, hi, whole)
+    return {
+        "ranges": ranges, "profile": prof,
+        "four": canon(red), "host_whole": canon(whole),
+        "host_by_ranges": by_ranges(host.run_range, ranges),
+        "kept": int(host.feasible_mask(np.arange(lo, hi)).sum()),
+    }
+
+
+out = {
+    "unconstrained": case(100, 0, N),
+    "within_s10": case(100, 0, N, S10, S10_GRID),
+    "padded_final_chunk": case(200, 0, N),
+    "fewer_chunks_than_chips": case(100, 600, N),
+}
+out["unconstrained"]["sweep"] = report(GRID)
+out["unconstrained"]["host_sweep"] = report(GRID, backend="numpy-batch")
+out["within_s10"]["sweep"] = report(S10_GRID, S10)
+out["within_s10"]["host_sweep"] = report(S10_GRID, S10, "numpy-batch")
+
+# an overflow: a front cap that exactly one chip's range outgrows
+host = plan("numpy-batch", 100)
+ranges = dev.DeviceSweep.build(plan("jax-jit", 100)).split(0, N)
+fronts = []
+for lo, hi in ranges:
+    red = default_reducers(10)
+    host.run_range(lo, hi, red)
+    fronts.append(len(red[0].cols["id"]))
+dev.FRONT_CAP = sorted(fronts)[-2]
+seeded = default_reducers(10)
+host.run_range(0, 100, seeded)
+before = canon(seeded)
+try:
+    dev.DeviceSweep.build(plan("jax-jit", 100)).fold_range(0, N, seeded)
+    raised = ""
+except dev.DeviceFoldOverflow as e:
+    raised = str(e)
+out["overflow_on_one_chip"] = {
+    "fronts": fronts, "cap": dev.FRONT_CAP, "raised": raised,
+    "untouched": canon(seeded) == before,
+    "sweep": report(GRID), "host_sweep": report(GRID, backend="numpy-batch")}
+print(json.dumps(out))
+"""
+
+
+def _partition_invariant(state: list) -> list:
+    """A canonical state without the Chan moments: ``mean`` and ``m2``
+    depend on how the range was partitioned, in the last ulps."""
+    return [{k: v for k, v in st.items() if k not in ("mean", "m2")}
+            for st in state]
+
+
+@pytest.fixture(scope="module")
+def multi_chip():
+    import json
+    import os
+    import subprocess
+    import sys
+
+    tests = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(tests, "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", f"TESTS = {tests!r}\n" + _MULTI_CHIP],
+        capture_output=True, text=True, timeout=600, env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+class TestMultiChip:
+    """Four forced host devices: one fold's range is split over the four,
+    one program steps them in lockstep, and their carries merge in chip
+    order.  The result is bit-equal to the host folding the same four
+    ranges and merging them (the process pool's protocol); against the
+    host's fold of the whole range, which the tests above hold bit-equal
+    to one chip's, every state is bit-equal but the variance's Chan
+    moments (mean, m2), which merging re-groups, as any partition
+    does."""
+
+    #: case -> (chunk, chip ranges, devices that held a range, rounds)
+    SPLITS = {
+        "unconstrained": (100, [[0, 300], [300, 500], [500, 700],
+                                [700, N]], 4, 3),
+        "within_s10": (100, [[0, 300], [300, 500], [500, 700], [700, N]],
+                       4, 3),
+        "padded_final_chunk": (200, [[0, 400], [400, 600], [600, 800],
+                                     [800, N]], 4, 2),
+        "fewer_chunks_than_chips": (100, [[600, 700], [700, 800], [800, N],
+                                          [N, N]], 3, 1),
+    }
+
+    @pytest.mark.parametrize("case", list(SPLITS))
+    def test_four_chip_fold_matches_one_chip_and_host(self, multi_chip,
+                                                      case):
+        res = multi_chip[case]
+        chunk, ranges, devices, rounds = self.SPLITS[case]
+        assert res["ranges"] == ranges
+        prof = res["profile"]
+        assert prof["path"] == "device-fused"
+        assert prof["devices"] == devices
+        assert prof["device_calls"] == rounds
+        lo, hi = ranges[0][0], ranges[-1][1]
+        assert prof["chunks"] == -(-(hi - lo) // chunk)
+        assert prof["lanes"] == prof["chunks"] * chunk
+        assert prof["feasible"] == res["kept"]
+        assert prof["merge_s"] >= 0.0
+        assert res["four"] == res["host_by_ranges"]
+        assert (_partition_invariant(res["four"])
+                == _partition_invariant(res["host_whole"]))
+        (stats4,) = [st for st in res["four"] if "m2" in st]
+        (stats1,) = [st for st in res["host_whole"] if "m2" in st]
+        assert stats4["m2"] == pytest.approx(stats1["m2"], rel=1e-12)
+        if case == "within_s10":
+            assert 0 < res["kept"] < hi - lo
+
+    @pytest.mark.parametrize("case", ["unconstrained", "within_s10"])
+    def test_session_sweep_runs_fused_on_four(self, multi_chip, case):
+        """``Session.sweep`` takes the fused step on all four chips and
+        reports what the host stream reports (the variance to 1e-12)."""
+        rep, host = (multi_chip[case]["sweep"],
+                     multi_chip[case]["host_sweep"])
+        assert rep["path"] == "device-fused" and rep["devices"] == 4
+        assert rep["host_reason"] is None
+        assert rep["front"] == host["front"]
+        assert rep["top_k"] == host["top_k"]
+        var = rep["stats"].pop("t_exe_var")
+        assert var == pytest.approx(host["stats"].pop("t_exe_var"),
+                                    rel=1e-12)
+        assert rep["stats"] == host["stats"]
+
+    def test_overflow_on_one_chip_leaves_reducers_untouched(self,
+                                                            multi_chip):
+        res = multi_chip["overflow_on_one_chip"]
+        assert sum(f > res["cap"] for f in res["fronts"]) == 1
+        assert res["raised"].startswith("pareto front exceeded")
+        assert res["untouched"]
+        rep, host = res["sweep"], res["host_sweep"]
+        assert rep["path"] == "host-stream"
+        assert rep["host_reason"].startswith("device fold overflow")
+        assert rep["front"] == host["front"]
+        assert rep["top_k"] == host["top_k"]
+        assert rep["stats"] == host["stats"]

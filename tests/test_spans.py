@@ -54,7 +54,7 @@ PATHS = {
 
 multi_device = pytest.mark.skipif(
     jax.local_device_count() > 1,
-    reason="several local devices take the sharded host path")
+    reason="one-device sweeps; several devices are test_device_stream's")
 
 
 def _sweep(path: str, profile: bool = True):
@@ -178,6 +178,25 @@ def test_constrained_sweep_takes_the_fused_path():
         == fused.n_points
     assert 0 < fused.n_points < N
     assert fused.summary()["n_candidates"] == N
+
+
+@multi_device
+def test_fused_merge_span_and_devices(tmp_path):
+    """The fused path merges the chips' states into the reducers inside
+    ``repro.sweep.merge``, a child of ``repro.sweep.close``, and counts the
+    devices that held a range; the host stream reports its devices under
+    the same key."""
+    _sweep("device-fused")
+    with jax.profiler.trace(str(tmp_path)):
+        rep = _sweep("device-fused")
+    events = _host_events(tmp_path)
+    (merge,) = [e for e in events if e[3] == "repro.sweep.merge"]
+    assert any(e[3] == "repro.sweep.close" and e[0] == merge[0]
+               and e[1] <= merge[1] and merge[2] <= e[2] for e in events)
+    prof = rep.profile
+    assert 0.0 <= prof["merge_s"] <= prof["close_s"]
+    assert prof["devices"] == 1
+    assert _sweep("host-stream").profile["devices"] == prof["devices"]
 
 
 def test_span_fills_the_profile_and_counts():

@@ -269,11 +269,23 @@ class TestReducers:
             run_stream(4, 0, lambda ids: {}, [])
 
 
+#: case -> (sweep constraints as Python source, the path the sweep takes)
+MULTI_DEVICE = {
+    "fused": ("()", "device-fused"),
+    "callable_constraint": ("(lambda cols: np.asarray(cols['n_ga']) > 1,)",
+                            "host-stream"),
+}
+
+
 class TestMultiDevice:
-    def test_sharded_chunks_match_single_device(self):
-        """4 forced host devices: the sharded jax-jit streaming sweep folds
-        to the same front/top-k as the numpy materialized path."""
+    @pytest.mark.parametrize("case", list(MULTI_DEVICE))
+    def test_sharded_chunks_match_single_device(self, case):
+        """4 forced host devices: the jax-jit streaming sweep folds to the
+        same front/top-k as the numpy materialized path.  Unconstrained it
+        runs the fused step on all four devices; a callable constraint
+        takes the host stream, each chunk sharded over the four."""
         pytest.importorskip("jax")
+        constraints, path = MULTI_DEVICE[case]
         code = textwrap.dedent("""
             import json
             import numpy as np
@@ -287,22 +299,28 @@ class TestMultiDevice:
                           LsuType.ATOMIC_PIPELINED],
                 n_ga=[1, 2, 4], simd=[1, 4, 16], n_elems=[1 << 14],
                 delta=[1, 7])
-            mat = Session().sweep(sp)
-            st = Session(backend="jax-jit").sweep(sp, chunk_size=50)
-            front_mat = np.asarray(mat.pareto()).tolist()
+            cons = %s
+            mat = Session().sweep(sp, constraints=cons)
+            # a constrained materialized report holds its feasible rows
+            full = Session().sweep(sp)
+            ids = (np.flatnonzero(cons[0](full.points)) if cons
+                   else np.arange(len(full.t_exe)))
+            st = Session(backend="jax-jit").sweep(sp, chunk_size=12,
+                                                  constraints=cons)
+            front_mat = np.sort(ids[np.asarray(mat.pareto())]).tolist()
             front_st = np.sort(
                 np.asarray(st.point_ids)[st.pareto()]).tolist()
             prof = Session(backend="jax-jit").sweep(
-                sp, chunk_size=50, profile=True).profile
+                sp, chunk_size=12, constraints=cons, profile=True).profile
             print(json.dumps({
                 "path": prof["path"], "devices": prof["devices"],
-                "host_reason": prof["host_reason"],
+                "host_reason": prof.get("host_reason"),
                 "front_mat": front_mat, "front_st": front_st,
                 "topk_equal": st.top_k(5) == mat.top_k(5),
                 "summary_equal": st.summary()["t_exe_min_ms"]
                     == mat.summary()["t_exe_min_ms"],
             }))
-        """)
+        """) % constraints
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
@@ -314,6 +332,9 @@ class TestMultiDevice:
         res = json.loads(out.stdout.strip().splitlines()[-1])
         assert res["front_st"] == res["front_mat"]
         assert res["topk_equal"] and res["summary_equal"]
-        # the chunks really spread over all four devices
-        assert res["path"] == "host-stream" and res["devices"] == 4
-        assert res["host_reason"].startswith("4 local devices")
+        # the work really spread over all four devices
+        assert res["path"] == path and res["devices"] == 4
+        if path == "device-fused":
+            assert res["host_reason"] is None
+        else:
+            assert res["host_reason"].startswith("constrained plan")

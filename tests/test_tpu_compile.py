@@ -4,7 +4,8 @@ The TPU compiler refuses programs that XLA:CPU and Pallas interpret mode
 accept: a float64 -> int64 bitcast in the fused sweep step, kernel blocks
 off the (8, 128) tiling, primitives Mosaic cannot lower.  These tests compile
 for one chip of a described ``v5e:2x2`` topology the fused sweep step (chunk
-2**17, default reducers; unconstrained and masked to a board's envelope),
+2**17, default reducers; unconstrained and masked to a board's envelope;
+on one chip and as one program for all four),
 the batched estimator core behind ``serve()`` and the host-stream path (also
 sharded over the four chips), and the seven ``validate()`` kernels at their
 measurement shapes.  Nothing runs, so results and times are out of scope.
@@ -70,36 +71,95 @@ def _specs(tree, sharding):
                                        sharding=sharding), tree)
 
 
-@pytest.mark.parametrize("constrained", [False, True],
-                         ids=["unconstrained", "within_envelope"])
-def test_fused_sweep_step_compiles(constrained, one_chip):
-    """The device-fused step at the stream_10m grid's chunk and reducers,
-    unconstrained and with the feasibility mask of a board's envelope."""
-    import jax
-    import jax.numpy as jnp
+#: sha256 of the one-chip fused step's lowered text at chunk 2**17 on the
+#: stream_10m grid, as the sharded step's introduction found it: a change to
+#: this program changes what the one-chip cells run.
+ONE_CHIP_STEP_SHA256 = {
+    False: "aa5dd5aef791e08bdfa4168571722665f7bff77cd22cd31d50eb16b7320f8474",
+    True: "3a097eff6e5136ba3ec4eb2810258deb54622fd3290cc569d3a39f6338a67dc7",
+}
 
+
+def _fused_sweep(constrained: bool):
+    """The stream_10m grid's device driver at chunk 2**17 and its reducer
+    signature, unconstrained or within a board's envelope."""
     from benchmarks.sweep_bench import STREAM_GRIDS
-    from repro import Session, Space, compat
+    from repro import Session, Space
     from repro.core import device_stream as dev
     from repro.core import stream as st
     from repro.hw import get as hw_get
     from repro.search import within
 
-    chunk = 1 << 17
     constraints = ((within(hw_get("stratix10_ddr4_1866").envelope),)
                    if constrained else ())
     plan = Session(backend="jax-jit").plan(
-        Space.grid(**STREAM_GRIDS["10m"]), chunk_size=chunk,
+        Space.grid(**STREAM_GRIDS["10m"]), chunk_size=1 << 17,
         constraints=constraints)
     sweep = dev.DeviceSweep.build(plan)
     assert bool(sweep.mask_sig) == constrained
-    sig = sweep._sig(st.default_reducers())
-    step = dev._get_step(chunk, sig)
+    return sweep, sweep._sig(st.default_reducers())
+
+
+def _lower_one_chip(constrained, one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from repro import compat
+    from repro.core import device_stream as dev
+
+    sweep, sig = _fused_sweep(constrained)
+    step = dev._get_step(sweep.chunk, sig)
     with compat.enable_x64():
-        compiled = step.lower(
+        return step.lower(
             _specs(sweep._init_carry(sig), one_chip),
             _specs(sweep._tables_host, one_chip),
-            jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)).compile()
+            jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip))
+
+
+@pytest.mark.parametrize("constrained", [False, True],
+                         ids=["unconstrained", "within_envelope"])
+def test_fused_sweep_step_compiles(constrained, one_chip):
+    """The device-fused step at the stream_10m grid's chunk and reducers,
+    unconstrained and with the feasibility mask of a board's envelope."""
+    compiled = _lower_one_chip(constrained, one_chip).compile()
+    assert compiled.as_text()
+
+
+@pytest.mark.parametrize("constrained", [False, True],
+                         ids=["unconstrained", "within_envelope"])
+def test_one_chip_step_is_unchanged(constrained, one_chip):
+    """The one-chip step lowers to the program it lowered to before the
+    step learned to run on several chips."""
+    import hashlib
+
+    text = _lower_one_chip(constrained, one_chip).as_text()
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == ONE_CHIP_STEP_SHA256[constrained])
+
+
+@pytest.mark.parametrize("constrained", [False, True],
+                         ids=["unconstrained", "within_envelope"])
+def test_sharded_fused_sweep_step_compiles(constrained, four_chips):
+    """The fused step as one program for the four chips of the described
+    v5e:2x2: the carry stacked over the chips, the tables replicated, one
+    start per chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import compat
+    from repro.core import device_stream as dev
+
+    devices = tuple(four_chips.mesh.devices.flat)
+    sweep, sig = _fused_sweep(constrained)
+    step = dev._get_mesh_step(sweep.chunk, sig, devices)
+    chips, replicated = dev._mesh_shardings(devices)
+    with compat.enable_x64():
+        carry = jax.tree_util.tree_map(
+            lambda a: np.stack([a] * len(devices)), sweep._init_carry(sig))
+        compiled = step.lower(
+            _specs(carry, chips), _specs(sweep._tables_host, replicated),
+            jax.ShapeDtypeStruct((len(devices),), jnp.int64,
+                                 sharding=chips)).compile()
     assert compiled.as_text()
 
 
