@@ -1001,8 +1001,10 @@ class Session:
         ``chunks``, ``lanes`` (points scored, padding included),
         ``feasible`` (points kept by the constraints), ``uploads`` /
         ``upload_bytes`` / ``pulls`` / ``pull_bytes`` (arrays moved each
-        way) and ``device_calls`` (jitted calls), and ``path``,
-        ``devices`` and ``host_reason``.
+        way), ``device_calls`` (jitted calls) and, fused, ``lookups`` /
+        ``lookup_gathers`` (the step's table reads by one-hot lookup and by
+        gather, times the steps run), and ``path``, ``devices`` and
+        ``host_reason``.
         The older keys are sums of spans: ``enumerate_s`` = ``decode_s``
         (materialized: the enumeration), ``reduce_s`` = ``fold_s``,
         ``transfer_s`` = ``upload_s + pull_s``, ``score_s`` = ``pack_s +

@@ -75,6 +75,8 @@ bit-exactly; callers record that reason where the path taken is reported.
 """
 from __future__ import annotations
 
+import contextvars
+
 import numpy as np
 
 from repro.core import model_batch as _mb
@@ -96,7 +98,8 @@ FRONT_CAP = 4096
 N_PARTIALS = 16
 
 #: Axis value tables are padded (edge-replicated) to multiples of this, so
-#: every grid whose axes fit one bucket shares a single compiled step.
+#: every grid whose axes fit one bucket shares a single compiled step.  A
+#: table of at most one bucket is read by a one-hot lookup (:func:`_lookup`).
 _TABLE_BUCKET = 128
 
 _NUM_AXES = tuple(a for a in _sweep.AXES if a not in _sweep._CATEGORICAL)
@@ -114,6 +117,15 @@ _USAGE_AXES = ("lsu_type", "n_ga", "simd", "elem_bytes", "include_write",
                "dram", "bsp", "hardware")
 
 _STEP_CACHE: dict = {}
+
+#: The table reads of one step, counted as it is traced, by (chunk size,
+#: reducer config, table shapes): ``{"lookups": one-hot reads,
+#: "lookup_gathers": reads that fell back to a gather}``.
+_STEP_LOOKUPS: dict = {}
+
+#: The tally the step being traced counts its table reads into.
+_TALLY: contextvars.ContextVar = contextvars.ContextVar("lookup_tally",
+                                                        default=None)
 
 
 class DeviceFoldOverflow(RuntimeError):
@@ -187,6 +199,50 @@ def _lexmin(live, keys):
     return jnp.argmax(cand), jnp.any(cand)
 
 
+def _lookup(table, code):
+    """``table[code]`` for a vector of codes into the true prefix of
+    ``table``, bit for bit.
+
+    A table of at most :data:`_TABLE_BUCKET` entries is read by a one-hot
+    select over its static length, which fuses into elementwise work: the
+    TPU runs a per-lane gather as one gather of 32-bit words per lane and
+    word, the longest op of the step.  Exactly one entry is hit, so the
+    reduce is exact: a sum for integers, ``any`` for bools, and for floats
+    a reduce that keeps the value of the hit entry by select, so every bit
+    stays (an arithmetic max would flush subnormals on the CPU).  A longer
+    table is gathered.  The step being traced counts each read
+    (:data:`_TALLY`).
+    """
+    import jax.numpy as jnp
+    from jax import lax
+
+    m = table.shape[0]
+    tally = _TALLY.get()
+    if m > _TABLE_BUCKET:
+        if tally is not None:
+            tally["lookup_gathers"] += 1
+        return table[code]
+    if tally is not None:
+        tally["lookups"] += 1
+    hit = code[:, None] == jnp.arange(m, dtype=code.dtype)[None, :]
+    if table.dtype == jnp.bool_:
+        return jnp.any(hit & table[None, :], axis=1)
+    if jnp.issubdtype(table.dtype, jnp.floating):
+        def keep_hit(a, b):
+            return a[0] | b[0], jnp.where(a[0], a[1], b[1])
+
+        return lax.reduce((hit, jnp.broadcast_to(table[None, :], hit.shape)),
+                          (False, jnp.zeros((), table.dtype)), keep_hit,
+                          (1,))[1]
+    return jnp.sum(jnp.where(hit, table[None, :], 0), axis=1,
+                   dtype=table.dtype)
+
+
+def _table_shapes(tables) -> tuple:
+    """The shapes of ``tables`` (host arrays or tracers), by name."""
+    return tuple(sorted((k, tuple(np.shape(v))) for k, v in tables.items()))
+
+
 def _decode(tables, ids, axes=_sweep.AXES):
     """Axis codes and numeric axis values of ``ids`` on ``axes``, from the
     tables."""
@@ -196,7 +252,8 @@ def _decode(tables, ids, axes=_sweep.AXES):
     dec = ids.astype(strides.dtype)
     code = {name: (dec // strides[i]) % mods[i]
             for i, name in enumerate(_sweep.AXES) if name in axes}
-    num = {k: tables["num_" + k][code[k]] for k in _NUM_AXES if k in code}
+    num = {k: _lookup(tables["num_" + k], code[k])
+           for k in _NUM_AXES if k in code}
     return code, num
 
 
@@ -230,9 +287,10 @@ def _feasible(tables, code, num, mask_sig: tuple):
     cols = dict(num)
     need = {col for col, _ in mask_sig}
     if need & {"lsu_type_code", *USAGE_COLUMNS}:
-        cols["lsu_type_code"] = tables["lsu_code"][code["lsu_type"]]
+        cols["lsu_type_code"] = _lookup(tables["lsu_code"],
+                                        code["lsu_type"])
     if need & set(USAGE_COLUMNS):
-        own = tables["hw_own"][code["hardware"]]
+        own = _lookup(tables["hw_own"], code["hardware"])
         d_code = jnp.where(own, code["dram"],
                            tables["len_d"] + code["hardware"])
         b_code = jnp.where(own, code["bsp"],
@@ -241,7 +299,8 @@ def _feasible(tables, code, num, mask_sig: tuple):
             type_codes=cols["lsu_type_code"], n_ga=num["n_ga"],
             simd=num["simd"], elem_bytes=num["elem_bytes"],
             include_write=num["include_write"],
-            max_txn=tables["max_txn"][d_code * tables["len_bx"] + b_code],
+            max_txn=_lookup(tables["max_txn"],
+                            d_code * tables["len_bx"] + b_code),
             xp=jnp))
     keep = None
     for i, (col, op) in enumerate(mask_sig):
@@ -290,9 +349,9 @@ def _score_ids(tables, ids, code, num):
     chunk = ids.shape[0]
     iota = jnp.arange(chunk, dtype=jnp.int64)
 
-    type_codes = tables["lsu_code"][code["lsu_type"]]
-    own = tables["hw_own"][code["hardware"]]
-    hw_scale = jnp.where(own, 1.0, tables["hw_hf"][code["hardware"]])
+    type_codes = _lookup(tables["lsu_code"], code["lsu_type"])
+    own = _lookup(tables["hw_own"], code["hardware"])
+    hw_scale = jnp.where(own, 1.0, _lookup(tables["hw_hf"], code["hardware"]))
     d_code = jnp.where(own, code["dram"], tables["len_d"] + code["hardware"])
     b_code = jnp.where(own, code["bsp"], tables["len_b"] + code["hardware"])
 
@@ -311,14 +370,14 @@ def _score_ids(tables, ids, code, num):
     g1_width = jnp.where(is_atomic, elem_bytes, simd * elem_bytes)
     # n_elems // simd from a host-built table: 64-bit division is emulated
     # on the TPU
-    per_simd = tables["acc_per_simd"][code["n_elems"] * tables["len_simd"]
-                                      + code["simd"]]
+    per_simd = _lookup(tables["acc_per_simd"],
+                       code["n_elems"] * tables["len_simd"] + code["simd"])
     g1_acc = jnp.where(is_atomic, n_elems, per_simd)
     g2_count = jnp.where(is_ack & include_write, simd, 0)
 
     vec = lambda a, b: jnp.concatenate([a, b])  # noqa: E731
-    dram_f = {k: tables["dram_" + k][d_code] for k in _DRAM_FIELDS}
-    bsp_f = {k: tables["bsp_" + k][b_code] for k in _BSP_FIELDS}
+    dram_f = {k: _lookup(tables["dram_" + k], d_code) for k in _DRAM_FIELDS}
+    bsp_f = {k: _lookup(tables["bsp_" + k], b_code) for k in _BSP_FIELDS}
     batch = _mb.GroupBatch(
         kernel=vec(iota, iota),
         n_kernels=chunk,
@@ -540,7 +599,8 @@ def _step_body(chunk: int, sig: tuple):
     bounds) is traced data, so one executable serves every grid whose
     tables fit the same padded buckets.  A feasibility mask is the last
     entry of ``sig``, ``("mask", ((column, op), ...))``: its structure is
-    part of the key, and its carry is the count of points kept.
+    part of the key, and its carry is the count of points kept.  Tracing
+    the body records its table reads in :data:`_STEP_LOOKUPS`.
     """
     import jax
     import jax.numpy as jnp
@@ -548,10 +608,17 @@ def _step_body(chunk: int, sig: tuple):
     mask_sig = next((spec[1] for spec in sig if spec[0] == "mask"), ())
 
     def sweep_step(carry, tables, start):
-        if mask_sig:
-            cols, valid, mask = _score_kept(tables, start, chunk, mask_sig)
-        else:
-            cols, valid, mask = _score_chunk(tables, start, chunk)
+        tally = {"lookups": 0, "lookup_gathers": 0}
+        _STEP_LOOKUPS[(chunk, sig, _table_shapes(tables))] = tally
+        token = _TALLY.set(tally)
+        try:
+            if mask_sig:
+                cols, valid, mask = _score_kept(tables, start, chunk,
+                                                mask_sig)
+            else:
+                cols, valid, mask = _score_chunk(tables, start, chunk)
+        finally:
+            _TALLY.reset(token)
         out = []
         with jax.named_scope("fold"):
             for spec, st in zip(sig, carry):
@@ -938,8 +1005,10 @@ class DeviceSweep:
         ``lanes`` (real chunks and their lanes), ``feasible`` (the points
         kept, counted on device under a mask), ``uploads``/``upload_bytes``
         (tables, the carry's leaves and each round's starts),
-        ``pulls``/``pull_bytes`` (carry leaves), ``device_calls`` (rounds)
-        and ``devices`` (the devices that held a range).
+        ``pulls``/``pull_bytes`` (carry leaves), ``device_calls`` (rounds),
+        ``devices`` (the devices that held a range) and ``lookups`` /
+        ``lookup_gathers`` (the step's one-hot table reads and its gathers,
+        counted as the step was traced, times the steps every device ran).
         """
         import jax
 
@@ -1010,6 +1079,10 @@ class DeviceSweep:
                         ("pull_bytes", sum(x.nbytes for x in state)),
                         ("device_calls", len(starts))):
                     count(profile, key, n_add)
+                per_step = _STEP_LOOKUPS.get(
+                    (chunk, sig, _table_shapes(self._tables_host)), {})
+                for key, n_add in per_step.items():
+                    count(profile, key, n_add * len(starts) * ndev)
                 if profile is not None:
                     profile["devices"] = sum(r1 > r0 for r0, r1 in ranges)
                 with span("sweep.merge", profile):

@@ -91,6 +91,48 @@ def materialized():
     return Session().sweep(Space.grid(**GRID))
 
 
+def _lookup_table(case: str) -> np.ndarray:
+    """A step table of ``case``'s dtype: one bucket long, or longer."""
+    rng = np.random.default_rng(16)
+    if case == "bool":
+        return rng.random(dev._TABLE_BUCKET) < 0.5
+    if case == "float64":
+        tiny = np.finfo(np.float64).tiny
+        special = [0.0, -0.0, np.inf, -np.inf, 5e-324, -5e-324, tiny / 3,
+                   tiny, np.finfo(np.float64).max, 1.0, -1.5, np.nan]
+        return np.concatenate([special, rng.standard_normal(
+            dev._TABLE_BUCKET - len(special)) * 1e300])
+    size = dev._TABLE_BUCKET * (2 if case == "int64_long" else 1)
+    return rng.integers(-2 ** 63, 2 ** 63 - 1, size=size, dtype=np.int64)
+
+
+@pytest.mark.parametrize("code_dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("case", ["int64", "bool", "float64", "int64_long"])
+def test_lookup_is_the_gather_bit_for_bit(case, code_dtype):
+    """``_lookup`` reads every code of the table bit for bit as the gather
+    does: by one-hot over one bucket, by the gather itself past it, and
+    counts which it took as the step does while it is traced."""
+    from repro import compat
+
+    table = _lookup_table(case)
+    m = len(table)
+    codes = np.random.default_rng(7).permutation(
+        np.concatenate([np.arange(m)] * 3)).astype(code_dtype)
+    tally = {"lookups": 0, "lookup_gathers": 0}
+    token = dev._TALLY.set(tally)
+    try:
+        with compat.enable_x64():
+            got = np.asarray(jax.jit(dev._lookup)(table, codes))
+    finally:
+        dev._TALLY.reset(token)
+    want = table[codes]
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    gathered = m > dev._TABLE_BUCKET
+    assert tally == {"lookups": int(not gathered),
+                     "lookup_gathers": int(gathered)}
+
+
 class TestDeviceFoldBitEquality:
     @multi_device
     @pytest.mark.parametrize("chunk", [37, 100, 864, 4096])

@@ -90,6 +90,7 @@ def test_trace_nests_the_path_spans_in_the_sweep(path, tmp_path):
     assert args["path"] == path
     assert args["points"] == N
     assert args["lanes"] == rep.profile["lanes"]
+    assert args.get("lookups") == rep.profile.get("lookups")
 
 
 @multi_device
@@ -197,6 +198,40 @@ def test_fused_merge_span_and_devices(tmp_path):
     assert 0.0 <= prof["merge_s"] <= prof["close_s"]
     assert prof["devices"] == 1
     assert _sweep("host-stream").profile["devices"] == prof["devices"]
+
+
+#: case -> (grid widening of the benchmark grid, constrained, one-hot
+#: lookups a step, gathers a step)
+LOOKUP_CASES = {
+    "unconstrained": ({}, False, 19, 0),
+    "within_envelope": ({}, True, 26, 0),
+    # 129 n_ga values pad to two buckets: that table is gathered
+    "long_axis": ({"n_ga": list(range(1, 130))}, False, 18, 1),
+}
+
+
+@multi_device
+@pytest.mark.parametrize("case", list(LOOKUP_CASES))
+def test_fused_profile_counts_table_lookups(case):
+    """On the benchmark grid the fused step reads its tables by 19 one-hot
+    lookups a step, 26 under a board's envelope; a table past one bucket
+    is gathered.  Both are counted as the step is traced, times the steps
+    run."""
+    from benchmarks.sweep_bench import STREAM_GRIDS
+    from repro.hw import get as hw_get
+
+    widen, constrained, lookups, gathers = LOOKUP_CASES[case]
+    constraints = ((within(hw_get("stratix10_ddr4_1866").envelope),)
+                   if constrained else ())
+    plan = Session(backend="jax-jit").plan(
+        Space.grid(**dict(STREAM_GRIDS["10m"], **widen)), chunk_size=CHUNK,
+        constraints=constraints)
+    prof: dict = {}
+    dev.DeviceSweep.build(plan).fold_range(0, 3 * CHUNK, default_reducers(),
+                                           prof)
+    assert prof["device_calls"] == 3
+    assert prof["lookups"] == lookups * prof["device_calls"]
+    assert prof["lookup_gathers"] == gathers * prof["device_calls"]
 
 
 def test_span_fills_the_profile_and_counts():

@@ -14,6 +14,7 @@ The topology is described inside a fixture, never at import time: only one
 process at a time may load the TPU compiler's library.
 """
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -72,11 +73,11 @@ def _specs(tree, sharding):
 
 
 #: sha256 of the one-chip fused step's lowered text at chunk 2**17 on the
-#: stream_10m grid, as the sharded step's introduction found it: a change to
-#: this program changes what the one-chip cells run.
+#: stream_10m grid, since its table reads became one-hot lookups: a change
+#: to this program changes what the one-chip cells run.
 ONE_CHIP_STEP_SHA256 = {
-    False: "aa5dd5aef791e08bdfa4168571722665f7bff77cd22cd31d50eb16b7320f8474",
-    True: "3a097eff6e5136ba3ec4eb2810258deb54622fd3290cc569d3a39f6338a67dc7",
+    False: "edd0a82f86341131ef7073852de5954fa9ed82cf334fea225aed649e318b2e4c",
+    True: "e2d31ed72f1e50116566b0202329a080832edbb91808cd50b9e7bc4de9248730",
 }
 
 
@@ -116,25 +117,59 @@ def _lower_one_chip(constrained, one_chip):
             jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip))
 
 
+@pytest.fixture(scope="module")
+def one_chip_step_text(one_chip):
+    """``constrained -> compiled text`` of the one-chip step, each compiled
+    once for the tests that read it."""
+    texts: dict = {}
+
+    def text(constrained: bool) -> str:
+        if constrained not in texts:
+            texts[constrained] = _lower_one_chip(
+                constrained, one_chip).compile().as_text()
+        return texts[constrained]
+
+    return text
+
+
 @pytest.mark.parametrize("constrained", [False, True],
                          ids=["unconstrained", "within_envelope"])
-def test_fused_sweep_step_compiles(constrained, one_chip):
+def test_fused_sweep_step_compiles(constrained, one_chip_step_text):
     """The device-fused step at the stream_10m grid's chunk and reducers,
     unconstrained and with the feasibility mask of a board's envelope."""
-    compiled = _lower_one_chip(constrained, one_chip).compile()
-    assert compiled.as_text()
+    assert one_chip_step_text(constrained)
 
 
 @pytest.mark.parametrize("constrained", [False, True],
                          ids=["unconstrained", "within_envelope"])
 def test_one_chip_step_is_unchanged(constrained, one_chip):
-    """The one-chip step lowers to the program it lowered to before the
-    step learned to run on several chips."""
+    """The one-chip step lowers to the pinned program: the one shared with
+    the mesh step since it learned to run on several chips, with its table
+    reads as one-hot lookups."""
     import hashlib
 
     text = _lower_one_chip(constrained, one_chip).as_text()
     assert (hashlib.sha256(text.encode()).hexdigest()
             == ONE_CHIP_STEP_SHA256[constrained])
+
+
+#: A per-lane gather of the compiled step outside its reducer folds: a
+#: custom fusion with a 2**17-lane result, from a gather in the ``decode``,
+#: ``mask`` or ``score`` scope.
+LANE_GATHER = re.compile(r'= \w+\[131072\]\S* fusion\(.*kind=kCustom.*'
+                         r'op_name="[^"]*/(decode|mask|score)/[^"]*gather')
+
+
+@pytest.mark.parametrize("constrained", [False, True],
+                         ids=["unconstrained", "within_envelope"])
+def test_one_chip_step_reads_tables_without_lane_gathers(
+        constrained, one_chip_step_text):
+    """The step reads its axis and hardware tables by one-hot lookups: the
+    chip's compiler leaves no 2**17-lane gather where they are read (the
+    lookups' gathers numbered 35 unconstrained and 47 masked)."""
+    text = one_chip_step_text(constrained)
+    assert text.count("kind=kCustom") > 0
+    assert [ln for ln in text.splitlines() if LANE_GATHER.search(ln)] == []
 
 
 @pytest.mark.parametrize("constrained", [False, True],
