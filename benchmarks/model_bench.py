@@ -5,9 +5,8 @@ CPU lowering stays fast), composes train-step and decode-step estimates
 through ``Session.estimate_model`` on two ``repro.hw`` presets, and
 re-sums the per-op estimates through individual ``Session.estimate``
 calls — the ``agree`` column is the composed-total == summed-parts
-invariant the CI gate enforces unconditionally.  ``wall_s`` on the
-``total`` row (lower + compile + walk + compose for everything) feeds the
->30% wall-time ratchet.
+invariant.  ``wall_s`` on the ``total`` row is lower + compile + walk +
+compose for everything.
 """
 from __future__ import annotations
 

@@ -5,23 +5,21 @@ followed by the detailed per-table CSVs.
 
 Usage:
     python -m benchmarks.run [--details] [--roofline-only] [--hw <name>]
-    python -m benchmarks.run --smoke --out json         # fast CI job
+    python -m benchmarks.run --smoke --out json         # fast subset
 
 ``--smoke`` runs only the fast, simulator-free subset (paper Table IV,
 Fig. 5 stride, a reduced design-space sweep, the 1M-point streaming
-sweep whose per-backend points/sec + peak RSS feed the CI perf gate,
-the 10M-point device-vs-host streaming sweep (jax-jit pipeline against
-the numpy-batch fold, agreement-gated),
-the distributed-sweep scaling bench at 1/2/4 process workers,
-the 32-client serving-latency bench whose p50/p99 feed the CI latency
-gate, and the whole-model ``model_e2e`` bench — transformer train +
-decode steps composed through ``Session.estimate_model`` on two hardware
-presets, agreement- and wall-time-gated) and,
-with ``--out``, writes the full results as a JSON artifact for CI upload.  ``--out json``
-resolves to ``BENCH_smoke.json`` at the repository root — the recorded
-perf-trajectory artifact CI uploads.  ``--hw <name>`` re-runs everything
-against a ``repro.hw`` registry spec (e.g. ``stratix10_ddr4_2666``,
-``tpu_v5e``).
+sweep with per-backend points/sec + peak RSS, the 10M-point
+device-vs-host streaming sweep (jax-jit pipeline against the numpy-batch
+fold, with an agreement flag), the distributed-sweep scaling bench at
+1/2/4 process workers, the 32-client serving-latency bench (p50/p99),
+and the whole-model ``model_e2e`` bench — transformer train + decode
+steps composed through ``Session.estimate_model`` on two hardware
+presets, with an agreement flag) and, with ``--out``, writes the full
+results as JSON.  ``--out json`` resolves to ``BENCH_smoke.json`` at the
+repository root (git-ignored; host figures, not the on-chip yardstick of
+``bench/run.py``).  ``--hw <name>`` re-runs everything against a
+``repro.hw`` registry spec (e.g. ``stratix10_ddr4_2666``, ``tpu_v5e``).
 """
 from __future__ import annotations
 
@@ -95,21 +93,21 @@ def main() -> None:
         # accelerator serves one process at a time.
 
         # 1M-point streaming sweep: points/sec + peak RSS per backend vs the
-        # materialize-everything baseline (the perf-gate entry CI watches).
+        # materialize-everything baseline.
         rows, us = PT.timed(lambda: SB.stream_bench(session=session))
         details["stream_1m"] = rows
         summary.append(("stream_1m", us, _derive("stream_1m", rows)))
 
         # 10M-point streaming sweep: the device-resident jax-jit pipeline
         # vs the numpy-batch host fold at a scale too large to materialize
-        # (device==host agreement + per-backend points/sec feed the gate).
+        # (device==host agreement + per-backend points/sec).
         rows, us = PT.timed(lambda: SB.stream10_bench(session=session))
         details["stream_10m"] = rows
         summary.append(("stream_10m", us, _derive("stream_10m", rows)))
 
         # distributed streaming sweep: the same 1M-point grid through the
         # coordinator/worker process pool at 1/2/4 workers (points/sec +
-        # agreement with the single-process fold — the scaling-gate entry).
+        # agreement with the single-process fold).
         rows, us = PT.timed(lambda: SB.stream_dist(session=session))
         details["stream_dist"] = rows
         summary.append(("stream_dist", us, _derive("stream_dist", rows)))
@@ -126,15 +124,14 @@ def main() -> None:
 
         # gradient-based search vs the exhaustive grid: Session.optimize
         # must bit-match the 1M-point optimum and recover the Pareto front
-        # while evaluating <1% of the points (the optimize-gate entry).
+        # while evaluating <1% of the points.
         rows, us = PT.timed(lambda: SB.optimize_1m(session=session))
         details["optimize_1m"] = rows
         summary.append(("optimize_1m", us, _derive("optimize_1m", rows)))
 
         # serving layer: 32 concurrent clients against Session.serve() —
         # hot (cache-warm interactive) p50/p99 latency vs the single-request
-        # baseline, plus cold micro-batched throughput (the latency-gate
-        # entry CI watches).
+        # baseline, plus cold micro-batched throughput.
         from benchmarks import serve_bench as SVB
         rows, us = PT.timed(lambda: SVB.serve_bench(session=session))
         details["serve_smoke"] = rows
@@ -142,8 +139,7 @@ def main() -> None:
 
         # whole-model estimation: transformer train + decode steps composed
         # through Session.estimate_model on two hardware presets; the
-        # composed-total == summed-parts agreement plus a wall-time ratchet
-        # feed the model gate.
+        # composed-total == summed-parts agreement and the wall time.
         from benchmarks import model_bench as MB
         rows, us = PT.timed(lambda: MB.model_e2e(session=session))
         details["model_e2e"] = rows

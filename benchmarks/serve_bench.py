@@ -9,7 +9,7 @@ result, the latency a caller actually observes):
 
 * ``single``     — serial ``Session.estimate`` on one thread: the baseline
   every serving number is judged against (and the in-run machine-speed
-  control the CI gate uses to tell a slow runner from a regression).
+  control that tells a slow runner from a regression).
 * ``serve_hot``  — ``N_CLIENTS`` interactive threads replaying a shared
   design pool (advisor steady state, cache warm) with a short per-request
   think time, as an interactive client has: p50/p99/qps + hit rate, think

@@ -392,7 +392,6 @@ def stream10_bench(*, chunk_size: int = 1 << 17, k: int = 10,
     exactly and top-k rows / ``t_exe_min`` to agree within rtol 1e-6 (the
     folds are bit-equal by contract — tests/test_device_stream.py — so the
     tolerance only absorbs jit fusion reassociation, e.g. FMA contraction).
-    bench_gate.py fails the build unconditionally on a false flag.
     """
     sess0 = session or Session()
     hw_name = sess0.hardware.name if sess0.hardware is not None else "-"
@@ -451,9 +450,9 @@ def optimize_1m(axes: dict | None = None, *, max_evals: int | None = None,
     optimizer in 2-objective mode, and reports whether the optimizer's
     best point *bit-matches* the grid optimum, what fraction of the
     reference front it recovered exactly, and how many model evaluations
-    it paid — the telemetry behind the <1%-of-points claim the CI gate
-    enforces.  Both paths score through the identical plan evaluator, so
-    "match" means float64 bit-equality, not a tolerance.
+    it paid — the telemetry behind the <1%-of-points claim.  Both paths
+    score through the identical plan evaluator, so "match" means float64
+    bit-equality, not a tolerance.
     """
     from repro.core.stream import ParetoReducer, StatsReducer, default_reducers
 
@@ -593,10 +592,9 @@ def stream_dist(axes: dict | None = None, *, chunk_size: int = 1 << 17,
     measurement inherits another's page cache or import state), and every
     run's front ids / top-k rows / stats must agree with the in-process
     single-threaded streaming reference — the distributed path is bit-equal
-    by construction, so ``agree`` failing means a real merge bug, and
-    bench_gate.py fails the build on it.  ``cpus`` records the cores the
-    coordinator could schedule on: scaling claims (and the bench_gate
-    scaling invariant) only mean something when ``cpus >= workers``.
+    by construction, so ``agree`` failing means a real merge bug.
+    ``cpus`` records the cores the coordinator could schedule on: scaling
+    claims only mean something when ``cpus >= workers``.
     """
     sess0 = (session or Session()).with_backend("numpy-batch")
     hw_name = sess0.hardware.name if sess0.hardware is not None else "-"

@@ -583,25 +583,27 @@ def _derive_legacy_keys(prof: dict) -> None:
         prof["transfer_s"] = total("upload_s", "pull_s")
 
 
-def _stream_report(outcome, tables: Mapping[str, list], *,
-                   backend: str,
-                   n_candidates: int | None = None) -> SweepReport:
-    """Fold a :class:`repro.core.stream.StreamOutcome` into a SweepReport.
+def _stream_report(reducers, plan: SweepPlan, *,
+                   backend: str) -> SweepReport:
+    """The SweepReport of ``reducers`` folded over the whole of ``plan``.
 
     Survivors = union of the Pareto reducer's front and the top-k rows,
     deduplicated by point id and held in ascending id order; the front and
-    top-k index into those held rows.  For a constrained sweep
-    (``n_candidates`` set) the reducers only ever saw feasible rows, so the
-    report's ``n_total`` is the stats reducer's exact feasible count, not
-    the enumerated grid size.
+    top-k index into those held rows.  For a constrained sweep the
+    reducers only ever saw feasible rows, so the report's ``n_total`` is
+    the stats reducer's exact feasible count and ``n_candidates`` the
+    enumerated grid size.
     """
     from repro.core import stream as _stream
 
-    front = next((r for r in outcome.reducers
+    tables = plan.tables()
+    n_candidates = plan.n if plan.constraints else None
+
+    front = next((r for r in reducers
                   if isinstance(r, _stream.ParetoReducer)), None)
-    topk = next((r for r in outcome.reducers
+    topk = next((r for r in reducers
                  if isinstance(r, _stream.TopKReducer)), None)
-    stats = next(r for r in outcome.reducers
+    stats = next(r for r in reducers
                  if isinstance(r, _stream.StatsReducer))
 
     pieces = [r.cols for r in (front, topk)
@@ -640,7 +642,7 @@ def _stream_report(outcome, tables: Mapping[str, list], *,
         points=points, estimate=est,
         resource=np.asarray(merged["resource"], dtype=np.float64),
         backend=backend,
-        n_total=(outcome.n_points if n_candidates is None
+        n_total=(plan.n if n_candidates is None
                  else int(stats.n_points)),
         n_candidates=n_candidates, stats=stats.summary(),
         point_ids=ids,
@@ -650,7 +652,7 @@ def _stream_report(outcome, tables: Mapping[str, list], *,
         topk_idx=(np.searchsorted(ids, topk.ids)
                   if topk is not None else None),
         topk_key=topk.key if topk is not None else None,
-        reducers=outcome.reducers)
+        reducers=reducers)
 
 
 class AutotuneReport(Report):
@@ -870,7 +872,7 @@ class Session:
             return []
         if self.backend == "scalar":
             return [self.estimate(d) for d in designs]
-        est = self._estimator()(self._batch_for(designs))
+        est = _mb.batch_estimator(self.backend)(self._batch_for(designs))
         return self._rows_from(est, designs)
 
     def _batch_for(self, designs: Sequence[Design]) -> _mb.GroupBatch:
@@ -926,13 +928,6 @@ class Session:
                             "Space.random materializes its draws")
         chunk = chunk_size if chunk_size is not None else space.chunk_size
         chunk = int(chunk) if chunk is not None else DEFAULT_CHUNK
-        if self.backend == "jax-jit":
-            import jax
-
-            ndev = jax.local_device_count()
-            if ndev > 1:
-                # fixed shapes must tile the device mesh exactly
-                chunk = -(-chunk // ndev) * ndev
         return SweepPlan(
             lists=space.lists(dram=self.dram, bsp=self.bsp),
             backend=self.backend,
@@ -953,8 +948,8 @@ class Session:
         Passing ``chunk_size`` (or a ``Space.grid(...).stream()`` space, or
         explicit ``reducers``) switches to **bounded-memory streaming**:
         points are enumerated lazily, evaluated in fixed-shape chunks (the
-        jax-jit backend compiles exactly once per chunk shape and, with
-        several local devices, splits the grid's ids over them), and folded
+        jax-jit backend's fused step compiles once per chunk shape and,
+        with several local chips, splits the grid's ids over them), and folded
         into online reducers — by default a running Pareto front, a
         ``top_k(10)`` selection and exact summary stats — so a 10M-point
         grid sweeps in O(chunk + front + k) memory.  ``reducers`` takes
@@ -964,7 +959,7 @@ class Session:
 
         * ``"threads"`` (default) — the in-process pipeline; ``workers``
           sizes the chunk thread pool on the numpy-batch backend (the
-          jax-jit backend already uses every local device, and the
+          jax-jit backend's fused step already uses every local chip, and the
           scalar reference loop is GIL-bound — both reject ``workers > 1``
           here);
         * ``"processes"`` — the coordinator/worker process pool
@@ -1033,8 +1028,8 @@ class Session:
             if self.backend == "jax-jit":
                 raise ValueError(
                     "workers > 1 under executor='threads' does not apply to "
-                    "the jax-jit backend (it already shards chunks across "
-                    "local devices); use executor='processes' to fan out "
+                    "the jax-jit backend (its fused step already uses every "
+                    "local chip); use executor='processes' to fan out "
                     "across process workers")
             if self.backend == "scalar":
                 raise ValueError(
@@ -1108,9 +1103,10 @@ class Session:
             if self.backend == "scalar":
                 result = self._sweep_scalar(points, n, cats)
             else:
-                result = _sweep._build(points, n, cats,
-                                       estimator=self._estimator(prof),
-                                       prof=prof)
+                result = _sweep._build(
+                    points, n, cats,
+                    estimator=_mb.batch_estimator(self.backend, prof),
+                    prof=prof)
         est = result.estimate
         if self.calibration_factor != 1.0:
             # The session factor belongs to the *session's* hardware; points
@@ -1157,27 +1153,13 @@ class Session:
                       constraints: tuple, prof: dict) -> SweepReport:
         """Chunked, reducer-folded evaluation of a grid space.
 
-        A thin consumer of :class:`SweepPlan`: the plan carries the
-        normalized axes + backend + calibration + chunk size, its
-        ``evaluator()`` scores chunks (same ``_score`` core and calibration
-        as the materialized path), and the reducers fold them — in this
-        process (``threads``) or across the coordinator/worker pool
-        (``processes``).  Peak memory is O(chunk + front + k); survivor
-        rows (front + top-k) are the only points materialized.
-
-        On the jax-jit backend a sweep with the standard reducers takes
-        the **device-resident fast path** (:mod:`repro.core.device_stream`):
-        enumeration, the feasibility mask of envelope and bound
-        constraints, Eqs. 1-10 scoring and the reducer folds fuse into one
-        jit-compiled chunk step, with reducer state pulled to the host once
-        at the end — bit-equal to this host pipeline, which remains the
-        fallback (custom reducers, callable constraints, bounds on
-        categorical columns, non-integer axes, capacity overflow).  On
-        several local devices the fused step splits the grid's ids into one
-        range per device and merges their folds in device order; the host
-        pipeline shards each chunk over the devices only when it runs.  The
-        plan rounds the chunk to a multiple of the device count for that
-        sharding.
+        A thin consumer of :class:`SweepPlan`: it plans, folds ``[0, n)``
+        into the reducers — in this process through
+        :func:`repro.core.device_stream.make_range_folder`, which picks the
+        fused device step or the host stream and records the path taken, or
+        across the coordinator/worker pool (``processes``) — and builds the
+        report.  Peak memory is O(chunk + front + k); survivor rows (front +
+        top-k) are the only points materialized.
         """
         import copy
 
@@ -1198,39 +1180,26 @@ class Session:
             if not any(isinstance(r, _stream.StatsReducer)
                        for r in reducers):
                 reducers += (_stream.StatsReducer(),)
+            if executor != "processes":
+                from repro.core import device_stream as _dev
 
-        outcome = None
+                if workers is None and self.backend == "numpy-batch":
+                    import os
+
+                    workers = min(4, os.cpu_count() or 1)
+                fold = _dev.make_range_folder(plan, profile=prof,
+                                              workers=workers)
+
         if executor == "processes":
             from repro.core import distributed as _dist
 
             # per-stage spans live in the worker processes
             prof["path"] = "distributed"
-            outcome = _dist.run_distributed(plan, reducers, workers=workers)
+            _dist.run_distributed(plan, reducers, workers=workers)
         else:
-            if self.backend == "jax-jit":
-                from repro.core import device_stream as _dev
-
-                # a device attempt that overflowed leaves its spans and
-                # counters in the profile: its time was spent
-                outcome, why = _dev.try_outcome(plan, reducers, profile=prof)
-                if outcome is None:
-                    prof["host_reason"] = why
-            if outcome is None:
-                w = workers
-                if w is None and self.backend == "numpy-batch":
-                    import os
-
-                    w = min(4, os.cpu_count() or 1)
-                prof["path"] = "host-stream"
-                outcome = _stream.run_stream(
-                    plan.n, plan.chunk_size,
-                    plan.evaluator(stage_times=prof), reducers,
-                    workers=w if self.backend == "numpy-batch" else None,
-                    stage_times=prof)
+            fold(0, plan.n, reducers)
         with span("sweep.close", prof):
-            return _stream_report(
-                outcome, plan.tables(), backend=self.backend,
-                n_candidates=plan.n if plan.constraints else None)
+            return _stream_report(reducers, plan, backend=self.backend)
 
     # -- optimizer-driven search -------------------------------------------
 
@@ -1271,20 +1240,6 @@ class Session:
             self, space, objective=objective, constraints=constraints,
             seed=seed, max_evals=max_evals, n_starts=n_starts,
             steps=steps, screen=screen, chunk_size=chunk_size)
-
-    # -- backend plumbing ---------------------------------------------------
-
-    def _estimator(self, prof: dict | None = None,
-                   ) -> Callable[[_mb.GroupBatch], _mb.BatchEstimate]:
-        """The backend's batch estimator; ``prof`` receives its spans and
-        counters (see :func:`_jax_estimate_batch`)."""
-        if self.backend == "jax-jit":
-            return lambda b: _jax_estimate_batch(b, stage_times=prof)
-
-        def estimate(b):
-            with span("chunk.dispatch", prof):
-                return _mb.estimate_batch(b)
-        return estimate
 
     # -- the rest of the pipeline ------------------------------------------
 
@@ -1559,93 +1514,11 @@ class Session:
                       timeout_ms=timeout_ms)
 
 
-# ---------------------------------------------------------------------------
-# jax-jit backend
-# ---------------------------------------------------------------------------
-
+#: The jax-jit estimator and its jit cache live in
+#: :mod:`repro.core.model_batch` (``_JAX_FN`` there).  ``bench/control.py``
+#: and the program reset of ``bench/tests`` still set this name; jax.jit
+#: retraces on its own when the x64 scope changes.
 _JAX_FN = None
-
-
-def _jax_estimator_fn():
-    """The jit-compiled batched estimator core (built once per process):
-    ``GroupBatch`` of device arrays -> dict of estimate columns."""
-    global _JAX_FN
-    if _JAX_FN is None:
-        import jax
-        import jax.numpy as jnp
-
-        from repro import compat as _compat
-
-        _mb.enable_jax()
-        _compat.enable_compilation_cache()
-
-        def estimate_core(b):
-            with jax.named_scope("score"):
-                est = _mb.estimate_batch(b, xp=jnp)
-            return {"t_exe": est.t_exe, "t_ideal": est.t_ideal,
-                    "t_ovh": est.t_ovh, "bound_ratio": est.bound_ratio,
-                    "memory_bound": est.memory_bound,
-                    "total_bytes": est.total_bytes, "n_lsu": est.n_lsu,
-                    "groups": est.groups}
-        # the trace's XLA Modules line names it jit_estimate_core
-        _JAX_FN = jax.jit(estimate_core)
-    return _JAX_FN
-
-
-def _jax_estimate_batch(batch: _mb.GroupBatch,
-                        sharding=None,
-                        stage_times: dict | None = None) -> _mb.BatchEstimate:
-    """The array core under ``jax.jit`` with x64 — numerically equal to the
-    NumPy path (same ops, same dtype), returned as NumPy arrays.
-
-    ``sharding`` (a ``NamedSharding`` from :func:`repro.compat.data_sharding`)
-    splits every batch array's leading (group) axis across local devices;
-    the jit-compiled core then runs SPMD with XLA inserting the one
-    cross-device reduction the per-kernel segment sums need.  The function
-    is compiled once per input shape, so fixed-shape streaming chunks reuse
-    a single executable for the whole sweep.
-
-    Three spans (:mod:`repro.core.spans`) go to ``stage_times`` when it
-    is a dict: ``chunk.upload`` (the batch's arrays to the device),
-    ``chunk.dispatch`` (the jitted call, which returns once it is
-    enqueued) and ``chunk.pull`` (the columns back: host time blocked on
-    them, the device's wait included).  So do the counters ``uploads``,
-    ``upload_bytes``, ``pulls``, ``pull_bytes`` and ``device_calls``, and
-    ``devices``, the number of devices the batch spans.  Nothing waits
-    for the device beyond what the pull itself needs.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro import compat as _compat
-
-    fn = _jax_estimator_fn()
-    prof = stage_times
-    with _compat.enable_x64():
-        with span("chunk.upload", prof):
-            jb = _mb.GroupBatch(**{
-                f.name: (batch.n_kernels if f.name == "n_kernels"
-                         else jnp.asarray(getattr(batch, f.name)))
-                for f in dataclasses.fields(_mb.GroupBatch)})
-            if sharding is not None:
-                jb = jax.device_put(jb, sharding)
-        with span("chunk.dispatch", prof):
-            dev = fn(jb)
-        with span("chunk.pull", prof):
-            out = jax.tree_util.tree_map(np.asarray, dev)
-    if prof is not None:
-        sent = jax.tree_util.tree_leaves(jb)
-        got = jax.tree_util.tree_leaves(out)
-        for key, n in (("uploads", len(sent)),
-                       ("upload_bytes", sum(x.nbytes for x in sent)),
-                       ("device_calls", 1),
-                       ("pulls", len(got)),
-                       ("pull_bytes", sum(x.nbytes for x in got))):
-            count(prof, key, n)
-        # how many devices each chunk's arrays really span
-        prof["devices"] = len(jb.count.sharding.device_set)
-    groups = out.pop("groups")
-    return _mb.BatchEstimate(**out, groups=groups)
 
 
 # ---------------------------------------------------------------------------

@@ -11,8 +11,6 @@ than into a kernel or model file:
   ``AxisType``s.
 * ``default_interpret(flag)`` — one place deciding when Pallas kernels run
   in interpret mode (everywhere except a real TPU backend).
-* ``data_sharding(n)`` — a 1-D leading-axis ``NamedSharding``; the
-  streaming sweep engine shards each fixed-shape chunk batch with it.
 * ``chip_mesh(devices)`` and ``shard_map(f, mesh, in_specs, out_specs)`` —
   a one-axis mesh over given devices and ``jax.shard_map`` over it; the
   fused sweep step runs one per-chip body on every chip of a host with it.
@@ -65,21 +63,6 @@ def make_mesh(shape, axes, *, explicit: bool = False):
     """``jax.make_mesh`` with every axis Auto (or Explicit)."""
     kind = AxisType.Explicit if explicit else AxisType.Auto
     return jax.make_mesh(shape, axes, axis_types=(kind,) * len(axes))
-
-
-def data_sharding(n: int | None = None):
-    """``NamedSharding`` splitting a leading axis across ``n`` local devices.
-
-    Built on a 1-D ``("data",)`` mesh through :func:`make_mesh`.  This is
-    the sharding the streaming sweep applies to each fixed-shape chunk
-    batch (the leading axis is the LSU-group dimension, ``2 * chunk_size``
-    entries).
-    """
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    n = int(n if n is not None else jax.local_device_count())
-    mesh = make_mesh((n,), ("data",))
-    return NamedSharding(mesh, PartitionSpec("data"))
 
 
 #: The axis of :func:`chip_mesh`.

@@ -72,6 +72,10 @@ naming the reason, when the plan carries a constraint the device cannot
 evaluate (a callable, or a bound on a categorical column), or the plan's
 axis values fall outside the integer/bool domain the device tables mirror
 bit-exactly; callers record that reason where the path taken is reported.
+
+:func:`make_range_folder` is the one place that picks this fused step or
+the host stream (:func:`repro.core.stream.run_stream`) for a range, for
+``Session.sweep`` and the process pool's workers alike.
 """
 from __future__ import annotations
 
@@ -978,7 +982,7 @@ class DeviceSweep:
                    profile: dict | None = None) -> None:
         """Fold chunk-aligned ``[lo, hi)`` into ``reducers`` on device.
 
-        Same alignment contract as :meth:`SweepPlan.run_range`.  The loop
+        Same alignment contract as :meth:`SweepPlan.chunks`.  The loop
         enqueues every chunk step without a host sync (jax async
         dispatch); reducer state is pulled to the host exactly once.
         Overflow flags are validated *before* any reducer is touched, so
@@ -1015,16 +1019,10 @@ class DeviceSweep:
         from repro import compat as _compat
 
         n, chunk = self.n, self.chunk
-        lo, hi = int(lo), min(int(hi), n)
-        if lo % chunk:
-            raise ValueError(f"range start {lo} is not chunk-aligned "
-                             f"(chunk_size={chunk})")
-        if hi % chunk and hi != n:
-            raise ValueError(f"range stop {hi} is not chunk-aligned "
-                             f"(chunk_size={chunk}) and is not the grid "
-                             f"end {n}")
-        if hi <= lo:
+        idx = self.plan.chunks(lo, hi)
+        if not idx:
             return
+        lo, hi = idx.start * chunk, min(idx.stop * chunk, n)
         reducers = tuple(reducers)
         sig = self._sig(reducers)
         if sig is None:
@@ -1179,33 +1177,58 @@ class DeviceSweep:
                 r.merge(tmp)
 
 
-def try_outcome(plan: "_stream.SweepPlan", reducers,
-                profile: dict | None = None,
-                ) -> "tuple[_stream.StreamOutcome | None, str]":
-    """Run the whole grid device-resident: ``(outcome, "")``, or
-    ``(None, reason)`` when the host path must run instead.
+def make_range_folder(plan: "_stream.SweepPlan", *,
+                      profile: dict | None = None,
+                      workers: int | None = None):
+    """``fold(lo, hi, reducers)`` for chunk-aligned ranges of ``plan``: the
+    one place that picks the engine a range folds on.
 
-    Folds ``[0, n)`` into ``reducers`` (which are only touched on success
-    — a capacity overflow returns ``None`` with the reducers pristine) and
-    returns the same :class:`stream.StreamOutcome` ``run_stream`` would.
-    Device, compile and lowering errors propagate: only a declared
-    ineligibility or a capacity overflow selects the host path.
-    ``profile`` receives the spans of :meth:`DeviceSweep.fold_range`, and
-    ``sweep.plan`` around the host tables' build.
+    On the jax-jit backend the fused device step (:class:`DeviceSweep`,
+    built here once) folds every range whose reducer set it supports.
+    Every other range folds on the host stream, :func:`stream.run_stream`
+    over ``plan.evaluator()`` (built on first use): any other backend, a
+    plan the device refuses, a custom reducer set, or a capacity overflow
+    (:class:`DeviceFoldOverflow`, which leaves the reducers untouched, so
+    the same range refolds).  Both are bit-equal by the reducer merge
+    contract (on several devices the fused fold splits a range over them,
+    which re-groups only the variance, as any partition does).  Device,
+    compile and lowering errors propagate.
+
+    ``profile`` (the sweep's, see ``Session.sweep``) receives the spans and
+    counters of the engine that ran, its ``path`` (``"device-fused"`` or
+    ``"host-stream"``) and, when a jax-jit range took the host stream,
+    ``host_reason``; a device attempt that overflowed leaves its spans and
+    counters there, since its time was spent.  ``workers`` sizes the host
+    stream's chunk thread pool (numpy-batch).
     """
-    try:
-        with span("sweep.plan", profile):
-            dev = DeviceSweep.build(plan)
-    except DeviceIneligible as e:
-        return None, str(e)
-    reducers = tuple(reducers)
-    if not dev.supports(reducers):
-        return None, "custom reducer set"
-    n = dev.n
-    try:
-        dev.fold_range(0, n, reducers, profile=profile)
-    except DeviceFoldOverflow as e:
-        return None, f"device fold overflow: {e}"
-    return _stream.StreamOutcome(
-        reducers=reducers, n_points=n,
-        n_chunks=-(-n // plan.chunk_size), chunk_size=plan.chunk_size), ""
+    device, why = None, ""
+    if plan.backend == "jax-jit":
+        try:
+            device = DeviceSweep.build(plan)
+        except DeviceIneligible as e:
+            why = str(e)
+    evaluator = None
+
+    def fold(lo: int, hi: int, reducers) -> None:
+        nonlocal evaluator
+        reducers = tuple(reducers)
+        reason = why
+        if device is not None and not device.supports(reducers):
+            reason = "custom reducer set"
+        elif device is not None:
+            try:
+                device.fold_range(lo, hi, reducers, profile=profile)
+                return
+            except DeviceFoldOverflow as e:
+                reason = f"device fold overflow: {e}"
+        if profile is not None:
+            profile["path"] = "host-stream"
+            if reason:
+                profile["host_reason"] = reason
+        if evaluator is None:
+            evaluator = plan.evaluator(stage_times=profile)
+        _stream.run_stream(plan.n, plan.chunk_size, evaluator, reducers,
+                           workers=workers, chunk_order=plan.chunks(lo, hi),
+                           stage_times=profile)
+
+    return fold

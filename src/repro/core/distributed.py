@@ -25,6 +25,7 @@ import queue
 import time
 import traceback
 
+from repro.core import device_stream as _dev
 from repro.core import stream as _stream
 
 __all__ = ["run_distributed"]
@@ -59,11 +60,11 @@ def _maybe_fault(uid: int) -> None:
 def _worker_main(plan, task_q, result_q) -> None:
     """Worker loop: build the range folder once, fold units until sentinel.
 
-    The folder (:func:`repro.core.stream.make_range_folder`) takes the
-    device-resident fused path on the jax-jit backend when the plan and
-    reducers qualify, and the host ``plan.run_range`` pipeline otherwise —
-    the same bit-equal dispatch ``Session.sweep`` makes in-process, so
-    work units reuse one compiled fused step per worker.
+    The folder is the one ``Session.sweep`` folds with in-process
+    (:func:`repro.core.device_stream.make_range_folder`): the fused device
+    step on the jax-jit backend when the plan and reducers qualify, the
+    host stream otherwise, bit-equal either way — so work units reuse one
+    compiled fused step per worker.
 
     Messages out: ``("start", uid, pid)`` when a unit begins (feeds the
     coordinator's straggler/death bookkeeping), ``("ok", uid, states)``
@@ -71,7 +72,7 @@ def _worker_main(plan, task_q, result_q) -> None:
     on failure (``uid == -1`` if the evaluator itself failed to build).
     """
     try:
-        fold_range = _stream.make_range_folder(plan)
+        fold_range = _dev.make_range_folder(plan)
     except BaseException:
         result_q.put(("err", -1, traceback.format_exc()))
         return

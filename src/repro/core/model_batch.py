@@ -18,6 +18,9 @@ All arithmetic mirrors the scalar reference (`model.lsu_timing`) operation
 for operation, so batched and scalar results agree to float64 round-off.
 The math uses only ops that exist in both NumPy and ``jax.numpy``; pass
 ``xp=jax.numpy`` (and jax arrays) to run the core under ``jit``/``vmap``.
+:func:`batch_estimator` is the one place a backend name picks its batch
+scorer: this NumPy core, or the same core under ``jax.jit`` (jax is
+imported inside functions, so importing this module stays numpy-only).
 """
 from __future__ import annotations
 
@@ -28,6 +31,7 @@ import numpy as np
 
 from repro.core.fpga import BspParams, DramParams
 from repro.core.lsu import Lsu, LsuType
+from repro.core import spans as _spans
 
 # Integer codes for the GMI LSU types (the only ones that touch DRAM).
 ALIGNED, NON_ALIGNED, CACHE, WRITE_ACK, ATOMIC = 0, 1, 2, 3, 4
@@ -375,3 +379,97 @@ def estimate_batch(batch: GroupBatch, xp=np,
         n_lsu=seg(count),
         groups=g,
     )
+
+
+# ---------------------------------------------------------------------------
+# Backends: which batch estimator scores a GroupBatch
+# ---------------------------------------------------------------------------
+
+_JAX_FN = None
+
+
+def _jax_estimator_fn():
+    """The jit-compiled batched estimator core (built once per process):
+    ``GroupBatch`` of device arrays -> dict of estimate columns."""
+    global _JAX_FN
+    if _JAX_FN is None:
+        import jax
+        import jax.numpy as jnp
+
+        from repro import compat as _compat
+
+        enable_jax()
+        _compat.enable_compilation_cache()
+
+        def estimate_core(b):
+            with jax.named_scope("score"):
+                est = estimate_batch(b, xp=jnp)
+            return {"t_exe": est.t_exe, "t_ideal": est.t_ideal,
+                    "t_ovh": est.t_ovh, "bound_ratio": est.bound_ratio,
+                    "memory_bound": est.memory_bound,
+                    "total_bytes": est.total_bytes, "n_lsu": est.n_lsu,
+                    "groups": est.groups}
+        # the trace's XLA Modules line names it jit_estimate_core
+        _JAX_FN = jax.jit(estimate_core)
+    return _JAX_FN
+
+
+def _jax_estimate_batch(batch: GroupBatch,
+                        stage_times: dict | None = None) -> BatchEstimate:
+    """The array core under ``jax.jit`` with x64 on the default device —
+    numerically equal to the NumPy path (same ops, same dtype), returned as
+    NumPy arrays.  It compiles once per input shape, so fixed-shape
+    streaming chunks reuse a single executable for the whole sweep.
+
+    Three spans (:mod:`repro.core.spans`) go to ``stage_times`` when it
+    is a dict: ``chunk.upload`` (the batch's arrays to the device),
+    ``chunk.dispatch`` (the jitted call, which returns once it is
+    enqueued) and ``chunk.pull`` (the columns back: host time blocked on
+    them, the device's wait included).  So do the counters ``uploads``,
+    ``upload_bytes``, ``pulls``, ``pull_bytes`` and ``device_calls``, and
+    ``devices``, the number of devices the batch spans.  Nothing waits
+    for the device beyond what the pull itself needs.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro import compat as _compat
+
+    fn = _jax_estimator_fn()
+    prof = stage_times
+    with _compat.enable_x64():
+        with _spans.span("chunk.upload", prof):
+            jb = GroupBatch(**{
+                f.name: (batch.n_kernels if f.name == "n_kernels"
+                         else jnp.asarray(getattr(batch, f.name)))
+                for f in dataclasses.fields(GroupBatch)})
+        with _spans.span("chunk.dispatch", prof):
+            dev = fn(jb)
+        with _spans.span("chunk.pull", prof):
+            out = jax.tree_util.tree_map(np.asarray, dev)
+    if prof is not None:
+        sent = jax.tree_util.tree_leaves(jb)
+        got = jax.tree_util.tree_leaves(out)
+        for key, n in (("uploads", len(sent)),
+                       ("upload_bytes", sum(x.nbytes for x in sent)),
+                       ("device_calls", 1),
+                       ("pulls", len(got)),
+                       ("pull_bytes", sum(x.nbytes for x in got))):
+            _spans.count(prof, key, n)
+        prof["devices"] = len(jb.count.sharding.device_set)
+    groups = out.pop("groups")
+    return BatchEstimate(**out, groups=groups)
+
+
+def batch_estimator(backend: str, stage_times: dict | None = None):
+    """The batch estimator (``GroupBatch -> BatchEstimate``) of a session
+    or plan ``backend``: the jit core on jax-jit, else the NumPy core.
+    ``stage_times`` receives its spans and counters (the NumPy core's call
+    is ``chunk.dispatch``; see :func:`_jax_estimate_batch`)."""
+    if backend == "jax-jit":
+        return lambda b: _jax_estimate_batch(b, stage_times=stage_times)
+
+    def estimate(b: GroupBatch) -> BatchEstimate:
+        with _spans.span("chunk.dispatch", stage_times):
+            return estimate_batch(b)
+    return estimate

@@ -559,7 +559,7 @@ class Server:
                     batch, self.max_batch + 1,  # +1: a home for padding groups
                     _next_pow2(max(m, self.max_batch)))
         with span("serve.score"):
-            est = self.session._estimator()(batch)
+            est = _mb.batch_estimator(self.session.backend)(batch)
         with span("serve.scatter"):
             return self.session._rows_from(est, designs)
 

@@ -3,7 +3,7 @@
 The sweep engine's materialized path (:func:`repro.core.sweep._build`) holds
 every point, estimate and resource value in memory before any selection
 runs, so a 10M-point sweep is memory-prohibitive by construction.  This
-module supplies the streaming counterpart:
+module supplies the streaming counterpart, the host engine:
 
 * :class:`GridEnumerator` — a lazy Cartesian-product enumerator.  A design
   point is a single integer id in ``[0, n)``; per-axis indices come out of
@@ -27,10 +27,11 @@ module supplies the streaming counterpart:
   size).  ``plan.evaluator()`` reconstructs the chunk-scoring closure from
   that data alone, so a fresh worker process can rebuild the exact same
   evaluation from a pickled (or JSON round-tripped) plan.
-* :func:`run_stream` — the chunk loop: fixed-shape chunks (the last one
-  padded so a jit-compiled estimator compiles exactly once per chunk
-  shape), masked before folding, optionally pipelined through a thread
-  pool for the numpy backend.
+* :func:`run_stream` — the one chunk loop, over the whole grid or any
+  chunk-aligned range of it: fixed-shape chunks (the last one padded so a
+  jit-compiled estimator compiles exactly once per chunk shape), masked
+  before folding, optionally pipelined through a thread pool for the
+  numpy backend.
 
 A *chunk-column* dict is the currency between the evaluator and the
 reducers: ``id`` (global point ids), the normalized numeric axis values,
@@ -52,6 +53,7 @@ from typing import Callable, Iterable, Mapping, Sequence
 
 import numpy as np
 
+from repro.core import model_batch as _mb
 from repro.core.spans import count, span
 
 #: Estimate columns every evaluator must provide per chunk.
@@ -554,8 +556,11 @@ def run_stream(
     loop (the reducers themselves are order-invariant for the Pareto front,
     but top-k tie-breaking and stats argmins rely on ascending ids).
 
-    ``chunk_order`` permutes which chunk is evaluated when (testing hook
-    for the order-invariance property); folding follows that order.
+    ``chunk_order`` lists the indices of the chunks to evaluate, in the
+    order they are evaluated and folded (default: every chunk of ``[0, n)``,
+    ascending) — a chunk-aligned range (:meth:`SweepPlan.chunks`) or a
+    permutation.  A chunk's contents depend only on its index, so a range
+    folds exactly as it does inside the whole pass.
 
     ``stage_times`` (a mutable dict, the sweep's profile) receives the
     ``fold_s`` span of every chunk and the counters ``chunks`` and
@@ -565,9 +570,9 @@ def run_stream(
     if chunk_size < 1:
         raise ValueError("chunk_size must be >= 1")
     reducers = tuple(reducers)
-    starts = list(range(0, n, chunk_size))
-    if chunk_order is not None:
-        starts = [starts[i] for i in chunk_order]
+    if chunk_order is None:
+        chunk_order = range(-(-n // chunk_size))
+    starts = [i * chunk_size for i in chunk_order]
 
     def fold(cols: Mapping[str, np.ndarray], valid: int) -> None:
         # A constrained evaluator returns pre-compacted columns (feasible
@@ -691,7 +696,7 @@ class SweepPlan:
     text for transports that cannot carry pickles.
 
     Build one with ``Session.plan(...)`` rather than by hand — that applies
-    the same axis normalization and chunk rounding ``Session.sweep`` uses.
+    the same axis normalization and default chunk ``Session.sweep`` uses.
     """
 
     lists: Mapping[str, Sequence]
@@ -736,6 +741,26 @@ class SweepPlan:
     def n_chunks(self) -> int:
         return -(-self.n // self.chunk_size)
 
+    def chunks(self, lo: int, hi: int) -> range:
+        """The indices of the chunks covering point ids ``[lo, hi)``.
+
+        ``lo`` (and ``hi``, unless it is ``n``) must sit on chunk
+        boundaries: work units are unions of whole chunks of the *global*
+        chunk grid, so every chunk a worker evaluates is bit-identical to
+        the chunk the serial pass would have evaluated — the foundation of
+        the distributed executor's bit-equality contract.
+        """
+        n, chunk = self.n, self.chunk_size
+        lo, hi = int(lo), min(int(hi), n)
+        if lo % chunk:
+            raise ValueError(f"range start {lo} is not chunk-aligned "
+                             f"(chunk_size={chunk})")
+        if hi % chunk and hi != n:
+            raise ValueError(f"range stop {hi} is not chunk-aligned "
+                             f"(chunk_size={chunk}) and is not the grid "
+                             f"end {n}")
+        return range(lo // chunk, -(-hi // chunk))
+
     def feasible_mask(self, ids: np.ndarray) -> np.ndarray:
         """Boolean keep-mask of the plan's constraints over point ids.
 
@@ -764,9 +789,7 @@ class SweepPlan:
 
         Maps a fixed-shape id block to the chunk-column dict the reducers
         fold.  Call once per process and reuse — the jax-jit backend
-        compiles on first use, and on multi-device hosts shards each chunk
-        across local devices whenever ``chunk_size`` tiles the device
-        count.
+        compiles on first use and scores on the default device.
 
         When the plan carries constraints, each chunk is feasibility-masked
         *before* scoring: the returned columns hold only the feasible rows
@@ -793,24 +816,7 @@ class SweepPlan:
         num_names = [a for a in _sweep.AXES if a not in _sweep._CATEGORICAL]
         c = self.calibration_factor
 
-        estimator = None
-        if backend == "jax-jit":
-            import jax
-
-            from repro import api as _api
-            from repro import compat as _compat
-
-            ndev = jax.local_device_count()
-            sharding = (_compat.data_sharding(ndev)
-                        if ndev > 1 and self.chunk_size % ndev == 0 else None)
-            estimator = (lambda b: _api._jax_estimate_batch(
-                b, sharding=sharding, stage_times=stage_times))
-        elif backend == "numpy-batch":
-            from repro.core import model_batch as _mb
-
-            def estimator(b):
-                with span("chunk.dispatch", stage_times):
-                    return _mb.estimate_batch(b)
+        estimator = _mb.batch_estimator(backend, stage_times)
 
         def score_ids(ids: np.ndarray) -> dict[str, np.ndarray]:
             m = len(ids)
@@ -899,39 +905,12 @@ class SweepPlan:
 
     def run_range(self, lo: int, hi: int, reducers: Iterable[Reducer], *,
                   eval_chunk: Callable | None = None) -> tuple[Reducer, ...]:
-        """Fold the chunks covering point ids ``[lo, hi)`` into ``reducers``.
-
-        ``lo`` (and ``hi``, unless it is ``n``) must sit on chunk
-        boundaries: work units are unions of whole chunks of the *global*
-        chunk grid, so every chunk a worker evaluates is bit-identical to
-        the chunk the serial pass would have evaluated — the foundation of
-        the distributed executor's bit-equality contract.
-        """
-        n = self.n
-        lo, hi = int(lo), min(int(hi), n)
-        if lo % self.chunk_size:
-            raise ValueError(f"range start {lo} is not chunk-aligned "
-                             f"(chunk_size={self.chunk_size})")
-        if hi % self.chunk_size and hi != n:
-            raise ValueError(f"range stop {hi} is not chunk-aligned "
-                             f"(chunk_size={self.chunk_size}) and is not "
-                             f"the grid end {n}")
+        """Fold the chunks covering point ids ``[lo, hi)`` into ``reducers``
+        (:func:`run_stream` over :meth:`chunks` of the range)."""
         if eval_chunk is None:
             eval_chunk = self.evaluator()
-        reducers = tuple(reducers)
-        for start in range(lo, hi, self.chunk_size):
-            ids, valid = _chunk_ids(start, n, self.chunk_size)
-            cols = eval_chunk(ids)
-            # Same rule as run_stream's fold: a constrained evaluator has
-            # already compacted to the feasible rows.
-            if valid != self.chunk_size \
-                    and len(cols["id"]) == self.chunk_size:
-                cols = {k: np.asarray(v)[:valid] for k, v in cols.items()}
-            if len(cols["id"]) == 0:
-                continue
-            for r in reducers:
-                r.update(cols)
-        return reducers
+        return run_stream(self.n, self.chunk_size, eval_chunk, reducers,
+                          chunk_order=self.chunks(lo, hi)).reducers
 
     def run(self, reducers: Iterable[Reducer], *,
             workers: int | None = None) -> StreamOutcome:
@@ -978,47 +957,3 @@ class SweepPlan:
             calibration_factor=float(d["calibration_factor"]),
             chunk_size=int(d["chunk_size"]),
             constraints=constraints)
-
-
-def make_range_folder(plan: SweepPlan) -> Callable:
-    """``fold(lo, hi, reducers)`` for chunk-aligned ranges of ``plan``.
-
-    The fastest eligible implementation is chosen once per folder: on the
-    jax-jit backend that is the fused device-resident step
-    (:mod:`repro.core.device_stream` — in-jit enumeration, feasibility
-    mask, scoring and reducer folds, one host pull per range), with a
-    transparent fall-through to the host ``plan.run_range`` loop for
-    unsupported reducer sets or a device-side capacity overflow.  Both
-    paths are bit-equal by the reducer merge contract (on several devices
-    the fused fold splits each range over them, which re-groups only the
-    variance, as any partition does), so callers (the distributed worker
-    loop) never see which one ran.  The host evaluator
-    is built lazily — a worker whose every unit folds on-device never pays
-    for it.
-    """
-    device = None
-    if plan.backend == "jax-jit":
-        from repro.core import device_stream as _dev
-
-        try:
-            device = _dev.DeviceSweep.build(plan)
-        except _dev.DeviceIneligible:
-            pass    # e.g. a callable constraint
-
-    evaluator = None
-
-    def fold_range(lo: int, hi: int, reducers: Iterable[Reducer]) -> None:
-        nonlocal evaluator
-        reducers = tuple(reducers)
-        if device is not None and device.supports(reducers):
-            from repro.core.device_stream import DeviceFoldOverflow
-            try:
-                device.fold_range(lo, hi, reducers)
-                return
-            except DeviceFoldOverflow:
-                pass        # reducers untouched; refold on the host path
-        if evaluator is None:
-            evaluator = plan.evaluator()
-        plan.run_range(lo, hi, reducers, eval_chunk=evaluator)
-
-    return fold_range

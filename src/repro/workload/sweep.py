@@ -184,11 +184,7 @@ class ModelSweepPlan:
                 kernel_cache[key] = hit
             return hit
 
-        if backend == "jax-jit":
-            from repro import api as _api
-            estimator = _api._jax_estimate_batch
-        else:
-            estimator = _mb.estimate_batch
+        estimator = _mb.batch_estimator(backend)
 
         def eval_chunk(ids: np.ndarray) -> dict[str, np.ndarray]:
             ids = np.asarray(ids, dtype=np.int64)

@@ -15,8 +15,9 @@ jax = pytest.importorskip("jax")
 from repro import Session, Space
 from repro.core import DDR4_1866, DDR4_2666, LsuType
 from repro.core import device_stream as dev
+from repro.core.device_stream import make_range_folder
 from repro.core.stream import (ParetoReducer, StatsReducer, TopKReducer,
-                               default_reducers, make_range_folder)
+                               default_reducers)
 from repro.search import AllOf, BoundConstraint, ResourceEnvelope, within
 
 ALL_TYPES = [LsuType.BC_ALIGNED, LsuType.BC_NON_ALIGNED,

@@ -255,7 +255,7 @@ class TestExecutorErrorMatrix:
             Session().sweep(Space.grid(n_ga=[1]), workers=0)
 
     def test_threads_workers_on_jax_jit(self):
-        with pytest.raises(ValueError, match="shards chunks across"):
+        with pytest.raises(ValueError, match="already uses every local chip"):
             Session(backend="jax-jit").sweep(Space.grid(n_ga=[1]),
                                              workers=2)
 

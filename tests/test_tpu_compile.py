@@ -6,9 +6,10 @@ off the (8, 128) tiling, primitives Mosaic cannot lower.  These tests compile
 for one chip of a described ``v5e:2x2`` topology the fused sweep step (chunk
 2**17, default reducers; unconstrained and masked to a board's envelope;
 on one chip and as one program for all four),
-the batched estimator core behind ``serve()`` and the host-stream path (also
-sharded over the four chips), and the seven ``validate()`` kernels at their
-measurement shapes.  Nothing runs, so results and times are out of scope.
+the batched estimator core behind ``serve()`` and the host-stream path (on
+one chip, and with its batch sharded over the four), and the seven
+``validate()`` kernels at their measurement shapes.  Nothing runs, so
+results and times are out of scope.
 
 The topology is described inside a fixture, never at import time: only one
 process at a time may load the TPU compiler's library.
@@ -56,7 +57,7 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def four_chips(topo):
-    """The sharding the host-stream path gives each chunk on four chips."""
+    """A leading-axis sharding over the four chips."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     return NamedSharding(Mesh(np.asarray(topo.devices), ("data",)),
@@ -204,10 +205,10 @@ def test_estimator_core_compiles(chips, request):
     one chip and with the group axis sharded over four."""
     import jax
 
-    from repro import api, compat
+    from repro import compat
     from repro.core import LsuType
     from repro.core.lsu import Lsu
-    from repro.core.model_batch import GroupBatch
+    from repro.core.model_batch import GroupBatch, _jax_estimator_fn
     from repro.hw import get as hw_get
 
     hw = hw_get("stratix10_ddr4_1866")
@@ -222,7 +223,7 @@ def test_estimator_core_compiles(chips, request):
                 (groups,), np.asarray(getattr(small, f.name)).dtype,
                 sharding=sharding)
             for f in dataclasses.fields(GroupBatch) if f.name != "n_kernels"})
-        compiled = api._jax_estimator_fn().lower(batch).compile()
+        compiled = _jax_estimator_fn().lower(batch).compile()
     assert compiled.as_text()
 
 
